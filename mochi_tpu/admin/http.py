@@ -19,6 +19,7 @@ from __future__ import annotations
 import asyncio
 import json
 import os
+import sys
 from typing import Optional
 
 from ..verifier.spi import verifier_stats
@@ -596,6 +597,10 @@ class AdminServer(HttpJsonServer):
                     # docs/OPERATIONS.md §4e)
                     "shard": r.store.shard_stats(),
                     "verifier": verifier_stats(r.verifier),
+                    # a chip has one owner process: with a remote verifier
+                    # this must stay false, or this replica could be holding
+                    # (or hanging on) the device the service needs
+                    "jax_loaded": "jax" in sys.modules,
                     "batching": {
                         name: h.snapshot()
                         for name, h in sorted(r.metrics.histograms.items())

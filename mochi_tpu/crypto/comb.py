@@ -649,4 +649,4 @@ def warmup(
             jnp.zeros((m, 32), jnp.uint8),
             jnp.zeros((m, 32), jnp.uint8),
         )
-        np.asarray(bm)  # force compile + execute through any relay
+        np.asarray(bm)  # block until the program compiled and ran

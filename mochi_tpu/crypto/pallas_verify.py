@@ -34,7 +34,6 @@ with the XLA path; semantics are bit-identical (differential test:
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -81,24 +80,27 @@ def _kernel(
     out_ref[0, :] = bitmap.astype(jnp.int32)
 
 
-def _use_interpret() -> bool:
-    return jax.default_backend() != "tpu"
-
-
 @functools.partial(jax.jit, static_argnames=("block", "interpret"))
 def verify_prepared_pallas(
     y_a, sign_a, y_r, sign_r, s_bits, h_bits,
     block: int = BLOCK,
-    interpret: Optional[bool] = None,
+    interpret: bool = False,
 ):
     """Drop-in for ``curve.verify_prepared`` running the Pallas kernel.
 
     Accepts the same host-prepared ``(batch, ...)`` tensors; transposes to
     the limbs-leading layout in XLA (one fused transpose each way), pads the
     batch to a multiple of ``block`` and grids over blocks.
+
+    ``interpret`` is explicit: tests pass ``True``; the product path
+    (``MOCHI_VERIFY_IMPL=pallas``) never does, so off a TPU it raises
+    instead of serving traffic from the interpreter.
     """
-    if interpret is None:
-        interpret = _use_interpret()
+    if not interpret and jax.default_backend() != "tpu":
+        raise RuntimeError(
+            "verify_prepared_pallas compiles for TPU only (backend is "
+            f"{jax.default_backend()!r}); pass interpret=True in tests"
+        )
     n = y_a.shape[0]
     m = ((n + block - 1) // block) * block
     pad = m - n

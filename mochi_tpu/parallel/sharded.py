@@ -39,42 +39,8 @@ import numpy as np
 
 import jax
 import jax.numpy as jnp
-
-try:  # newer jax: top-level export
-    from jax import shard_map as _shard_map
-except ImportError:  # older wheels: experimental module
-    from jax.experimental.shard_map import shard_map as _shard_map
-
-# The varying-axis checker kwarg was renamed check_rep -> check_vma, NOT in
-# the same release as the top-level export — detect by signature, never by
-# import location.
-try:
-    import inspect
-
-    # Old name only when the signature demonstrably has it; any other
-    # inspectable shape (including *args/**kwargs wrappers) gets the
-    # modern name, consistent with the uninspectable branch below.
-    _CHECK_KW = (
-        "check_rep"
-        if "check_rep" in inspect.signature(_shard_map).parameters
-        else "check_vma"
-    )
-except (TypeError, ValueError):  # uninspectable wrapper: assume modern name
-    _CHECK_KW = "check_vma"
+from jax import shard_map
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
-
-
-def shard_map(f, *, mesh, in_specs, out_specs, check_vma=True):
-    """Version-bridging shard_map: one call shape for both jax APIs (the
-    replication/varying-axis checker kwarg was renamed check_rep ->
-    check_vma when shard_map left experimental)."""
-    return _shard_map(
-        f,
-        mesh=mesh,
-        in_specs=in_specs,
-        out_specs=out_specs,
-        **{_CHECK_KW: check_vma},
-    )
 
 from ..crypto import curve
 

@@ -50,11 +50,18 @@ def _build_verifier(args, config: ClusterConfig):
             from ..verifier.tpu import TpuBatchVerifier
         except ImportError as exc:
             raise SystemExit(f"TPU verifier unavailable ({exc}); use --verifier cpu") from exc
-        # Warm the XLA cache at boot (first compile is 20-60s; doing it here
-        # keeps it out of the first client's commit latency) — READY is only
-        # printed once the verifier can serve.  The cluster's replica
-        # identities are known signers: their cert signatures take the
-        # doubling-free comb path (crypto/comb.py).
+        from ..utils.runtime import device_info, enable_compile_cache
+
+        # This process becomes the chip's owner (one per chip: every other
+        # replica process must use remote:<host>:<port>).  Refuses to boot
+        # when JAX found no accelerator and JAX_PLATFORMS=cpu was not set.
+        enable_compile_cache()
+        device_info(require_accelerator=True)
+        # Warm both programs at boot (doing it here keeps the compile out of
+        # the first client's commit latency) — READY is only printed once
+        # the verifier can serve.  The cluster's replica identities are
+        # known signers: their cert signatures take the doubling-free comb
+        # path (crypto/comb.py).
         return TpuBatchVerifier(
             warmup_buckets=(16,), signers=list(config.public_keys.values())
         )

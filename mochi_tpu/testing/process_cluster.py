@@ -35,6 +35,7 @@ Lifecycle contract with ``server/__main__.py``:
 from __future__ import annotations
 
 import asyncio
+import hashlib
 import os
 import signal
 import socket
@@ -44,7 +45,7 @@ from typing import Dict, List, Optional
 
 from ..client.client import MochiDBClient
 from ..cluster.config import ClusterConfig
-from ..crypto.keys import KeyPair, generate_keypair
+from ..crypto.keys import KeyPair, generate_keypair, keypair_from_seed
 
 _REPO = os.path.dirname(os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
@@ -110,13 +111,22 @@ class ProcessCluster:
         uds: bool = True,
         # "cpu": inline native host verifier in every replica process.
         # "service": ALSO spawn one shared verifier-service process
-        # (mochi_tpu.verifier.service, cpu backend) and point every replica
-        # at it — the production sidecar posture: the service's cache
-        # collapses the rf duplicate grant checks of one certificate into
-        # ONE verification cluster-wide, which the in-process posture got
-        # for free from its shared module caches and a real multi-process
-        # deployment otherwise loses.
+        # (mochi_tpu.verifier.service) and point every replica at it — the
+        # production sidecar posture: the service's cache collapses the rf
+        # duplicate grant checks of one certificate into ONE verification
+        # cluster-wide, which the in-process posture got for free from its
+        # shared module caches and a real multi-process deployment
+        # otherwise loses.
         verifier: str = "cpu",
+        # The service's --backend / --warmup.  "tpu"/"tpu-sharded" make it
+        # the one process of the cluster that owns the chip (it is handed
+        # the replica identities as --signers-file and never pinned to the
+        # CPU); ``stop_service``/``start_service`` restart it in place.
+        service_backend: str = "cpu",
+        service_warmup: str = "",
+        # Derive the replica identities from this seed instead of drawing
+        # fresh keys (a seeded run is reproducible down to its signatures).
+        seed: Optional[int] = None,
         # Admission control (deterministic load signal, server/admission.py)
         # defaults ON in every posture — the queued-work signal cannot be
         # tripped by replicas sharing a child's loop the way the retired
@@ -161,6 +171,11 @@ class ProcessCluster:
         self.n_processes = n_processes
         self.uds = uds and os.name == "posix"
         self.verifier = verifier
+        self.service_backend = service_backend
+        self.service_warmup = service_warmup
+        self.service_port: Optional[int] = None
+        self.service_admin_port: Optional[int] = None
+        self.seed = seed
         self.admission = admission
         self.admin_base_port = admin_base_port
         self.data_dir = data_dir
@@ -175,6 +190,7 @@ class ProcessCluster:
         self.storage_root: Optional[str] = None
         self._extra_env = dict(env or {})
         self._spawn_env: Optional[Dict[str, str]] = None
+        self._service_env: Optional[Dict[str, str]] = None
         self.config: Optional[ClusterConfig] = None
         self.keypairs: Dict[str, KeyPair] = {}
         self.processes: List[_ServerProcess] = []
@@ -205,7 +221,15 @@ class ProcessCluster:
 
             for spec in self.byzantine.values():
                 make_strategy(spec)
-        self.keypairs = {sid: generate_keypair() for sid in server_ids}
+        if self.seed is None:
+            self.keypairs = {sid: generate_keypair() for sid in server_ids}
+        else:
+            self.keypairs = {
+                sid: keypair_from_seed(
+                    hashlib.sha256(f"mochi-pc:{self.seed}:{sid}".encode()).digest()
+                )
+                for sid in server_ids
+            }
         if self.uds:
             paths = {sid: os.path.join(out, sid + ".sock") for sid in server_ids}
             too_long = [p for p in paths.values() if len(p) > 100]
@@ -240,13 +264,14 @@ class ProcessCluster:
         env["PYTHONPATH"] = _REPO + (
             os.pathsep + env["PYTHONPATH"] if env.get("PYTHONPATH") else ""
         )
-        if self.verifier == "cpu":
-            # Inline host verifier needs no accelerator: pin the children to
-            # the CPU backend so N of them never contend for (or wedge on) a
-            # single-owner TPU plugin.
-            env.setdefault("JAX_PLATFORMS", "cpu")
         env.update(self._extra_env)
-        self._spawn_env = env
+        # A chip has one owner process, and it is never a replica: every
+        # replica child is pinned to the CPU backend so that none of them
+        # can take (or hang on) the device, whatever it ends up importing.
+        # The service keeps the caller's environment.
+        self._service_env = env
+        self._spawn_env = dict(env, JAX_PLATFORMS="cpu")
+        env = self._spawn_env
         if self.storage_dir:
             self.storage_root = (
                 self.storage_dir
@@ -263,20 +288,30 @@ class ProcessCluster:
         replica_verifier = self.verifier
         try:
             if self.verifier == "service":
-                vport = _free_tcp_ports(1)[0]
+                vport, self.service_admin_port = _free_tcp_ports(2)
+                self.service_port = vport
                 sp = _ServerProcess(
                     -1, ["verifier-service"], os.path.join(out, "verifier.log")
                 )
-                log = await loop.run_in_executor(None, open, sp.log_path, "ab")
-                try:
-                    sp.proc = await asyncio.create_subprocess_exec(
-                        sys.executable, "-m", "mochi_tpu.verifier.service",
-                        "--port", str(vport), "--backend", "cpu", "--warmup", "",
-                        env=env, stdout=asyncio.subprocess.PIPE, stderr=log,
-                    )
-                finally:
-                    log.close()
+                sp.argv = [
+                    sys.executable, "-m", "mochi_tpu.verifier.service",
+                    "--port", str(vport),
+                    "--admin-port", str(self.service_admin_port),
+                    "--backend", self.service_backend,
+                    "--warmup", self.service_warmup,
+                ]
+                if self.service_backend != "cpu":
+                    signers_path = os.path.join(out, "signers.txt")
+
+                    def _write_signers() -> None:
+                        with open(signers_path, "w") as fh:
+                            for sid, kp in self.keypairs.items():
+                                fh.write(f"{kp.public_key.hex()}  # {sid}\n")
+
+                    await loop.run_in_executor(None, _write_signers)
+                    sp.argv += ["--signers-file", signers_path]
                 self.service_process = sp
+                await self._spawn(sp, self._service_env)
                 replica_verifier = f"remote:127.0.0.1:{vport}"
             for pi, group in enumerate(groups):
                 sp = _ServerProcess(pi, group, os.path.join(out, f"proc-{pi}.log"))
@@ -304,13 +339,7 @@ class ProcessCluster:
                     if self.storage_engine:
                         argv += ["--storage-engine", self.storage_engine]
                 sp.argv = argv
-                log = await loop.run_in_executor(None, open, sp.log_path, "ab")
-                try:
-                    sp.proc = await asyncio.create_subprocess_exec(
-                        *argv, env=env, stdout=asyncio.subprocess.PIPE, stderr=log,
-                    )
-                finally:
-                    log.close()  # child holds its own descriptor now
+                await self._spawn(sp, env)
                 if self.pin_cores and hasattr(os, "sched_setaffinity"):
                     try:
                         os.sched_setaffinity(
@@ -331,6 +360,50 @@ class ProcessCluster:
             await self.close()
             raise
         return self
+
+    @staticmethod
+    async def _spawn(sp: _ServerProcess, env: Optional[Dict[str, str]]) -> None:
+        """(Re)launch ``sp.argv``: stdout piped for the READY lines, stderr
+        appended to the process's log."""
+        loop = asyncio.get_running_loop()
+        log = await loop.run_in_executor(None, open, sp.log_path, "ab")
+        try:
+            sp.proc = await asyncio.create_subprocess_exec(
+                *sp.argv, env=env, stdout=asyncio.subprocess.PIPE, stderr=log,
+            )
+        finally:
+            log.close()  # child holds its own descriptor now
+        sp.returncode = None
+
+    async def stop_service(self, timeout_s: float = 60.0) -> int:
+        """SIGTERM the verifier service and wait until the process has
+        EXITED (its drain-and-close path releases the chip); returns the
+        exit code.  The next owner must not start before this returns."""
+        sp = self.service_process
+        assert sp is not None and sp.proc is not None, "no service running"
+        if sp.proc.returncode is None:
+            sp.proc.terminate()
+        try:
+            await asyncio.wait_for(sp.proc.wait(), timeout=timeout_s)
+        except asyncio.TimeoutError as exc:
+            raise RuntimeError(
+                f"verifier service ignored SIGTERM for {timeout_s}s: "
+                f"{sp.log_tail()}"
+            ) from exc
+        await self._reap([sp])
+        assert sp.returncode is not None
+        return sp.returncode
+
+    async def start_service(self) -> None:
+        """Start the (stopped) verifier service again with its exact
+        original argv and environment — same port, same signers, same
+        compile cache — and block until it reprints READY."""
+        sp = self.service_process
+        assert sp is not None and sp.proc is not None, "cluster not started"
+        if sp.proc.returncode is None:
+            raise RuntimeError("verifier service still alive; stop_service() first")
+        await self._spawn(sp, self._service_env)
+        await asyncio.wait_for(self._wait_ready(sp), timeout=self.ready_timeout_s)
 
     async def _wait_ready(self, sp: _ServerProcess) -> None:
         """Block until every replica hosted by ``sp`` printed READY; a child
@@ -417,17 +490,8 @@ class ProcessCluster:
                 "alive; kill_replica() first"
             )
         await self._reap([sp])  # collect the corpse + stop its pump
-        loop = asyncio.get_running_loop()
         # mochi-lint: disable=await-races -- sp is identity-stable: host_process is written once in start() and cleared only in close(); the reap cannot remap which process hosts server_id
-        log = await loop.run_in_executor(None, open, sp.log_path, "ab")
-        try:
-            sp.proc = await asyncio.create_subprocess_exec(
-                *sp.argv, env=self._spawn_env,
-                stdout=asyncio.subprocess.PIPE, stderr=log,
-            )
-        finally:
-            log.close()
-        sp.returncode = None
+        await self._spawn(sp, self._spawn_env)
         if self.pin_cores and hasattr(os, "sched_setaffinity"):
             try:
                 os.sched_setaffinity(

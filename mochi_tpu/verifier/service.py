@@ -6,8 +6,11 @@ single JAX process that owns the chip, which returns a validity bitmap.  An
 in-process ``VirtualCluster`` doesn't need this — its replicas share the
 interpreter with the device owner — but a real ``scripts/start_cluster.sh``
 cluster is N separate OS processes, and a TPU has exactly one owner process:
-without this service, N-1 replicas are stuck on the CPU path
-(VERDICT.md round-1 missing #3).
+without this service, N-1 replicas are stuck on the CPU path.  The service
+is therefore the ONLY process of a deployment that imports JAX; it refuses
+to boot a device backend on a host where JAX found no accelerator (unless
+``JAX_PLATFORMS=cpu`` was exported on purpose) and reports the device it
+holds in ``/status``.
 
 Server: :class:`VerifierService` — an ``RpcServer`` (the same length-prefixed
 mcode transport the replicas speak, ``net/transport.py``) in front of a
@@ -40,6 +43,7 @@ from __future__ import annotations
 import argparse
 import asyncio
 import logging
+import signal
 import time
 from pathlib import Path
 from typing import List, Optional, Sequence
@@ -99,8 +103,13 @@ class VerifierService:
         max_items_per_request: int = 65536,
         cache: bool = True,
         secret: Optional[bytes] = None,
+        device: Optional[dict] = None,
     ):
         self.secret = secret
+        # what the boot path learned about the chip this process owns
+        # (platform/device_kind/n_devices, warmup seconds, compile cache
+        # dir); None for the CPU backend, which holds no device
+        self.device = device
         if verifier is None:
             from .tpu import TpuBatchVerifier
 
@@ -136,6 +145,7 @@ class VerifierService:
             "requests": self.requests,
             "items": self.items,
             "authenticated": self.secret is not None,
+            "device": self.device,
             "verifier": verifier_stats(self.verifier),
         }
 
@@ -279,40 +289,42 @@ async def amain(args) -> None:
             "verification runs OpenSSL per item",
         )
     verifier: Optional[SignatureVerifier] = None
+    device: Optional[dict] = None
     if args.backend == "cpu":
         verifier = CpuVerifier()
-    elif args.backend == "tpu":
-        from .tpu import TpuBatchVerifier
+    else:
+        from ..utils.runtime import device_info, enable_compile_cache
 
+        cache_dir = enable_compile_cache()
+        # refuses (SystemExit) when JAX found no accelerator and the CPU
+        # was not asked for: XLA:CPU never serves under the TPU's name
+        device = device_info(require_accelerator=True)
+        from . import tpu
+
+        verifier_cls = (
+            tpu.TpuBatchVerifier if args.backend == "tpu"
+            else tpu.ShardedTpuBatchVerifier
+        )
         t0 = time.time()
-        verifier = TpuBatchVerifier(
+        verifier = verifier_cls(
             warmup_buckets=tuple(int(b) for b in args.warmup.split(",") if b),
             signers=signers,
         )
+        device["warmup_seconds"] = round(time.time() - t0, 1)
+        device["compile_cache_dir"] = cache_dir
         LOG.info(
-            "device warmup took %.1fs (%d known signers)",
-            time.time() - t0,
-            len(signers),
-        )
-    elif args.backend == "tpu-sharded":
-        from .tpu import ShardedTpuBatchVerifier
-
-        t0 = time.time()
-        verifier = ShardedTpuBatchVerifier(
-            warmup_buckets=tuple(int(b) for b in args.warmup.split(",") if b),
-            signers=signers,
-        )
-        LOG.info(
-            "sharded verifier over %d devices (warmup %.1fs, %d known signers)",
-            verifier.backend.n_devices,
-            time.time() - t0,
-            len(signers),
+            "%s backend on %s %r x%d: warmup %.1fs at buckets [%s], "
+            "%d known signers, compile cache %s",
+            args.backend, device["platform"], device["device_kind"],
+            device["n_devices"], device["warmup_seconds"], args.warmup,
+            len(signers), cache_dir,
         )
     secret = None
     if args.secret_file:
         secret = load_secret(args.secret_file)
     service = VerifierService(
-        host=args.host, port=args.port, verifier=verifier, secret=secret
+        host=args.host, port=args.port, verifier=verifier, secret=secret,
+        device=device,
     )
     await service.start()
     admin = None
@@ -320,8 +332,20 @@ async def amain(args) -> None:
         admin = ServiceAdminServer(service, port=args.admin_port)
         await admin.start()
     print(f"READY {SERVICE_ID} {service.bound_port}", flush=True)
+    # SIGTERM/SIGINT close the RPC server, drain the batcher and return, so
+    # the interpreter exits normally and the runtime releases the chip: an
+    # owner that is killed hard can leave the next owner failing or hanging
+    # at backend init.
+    stop = asyncio.Event()
+    loop = asyncio.get_running_loop()
+    for sig in (signal.SIGTERM, signal.SIGINT):
+        try:
+            loop.add_signal_handler(sig, stop.set)
+        except (NotImplementedError, RuntimeError):
+            pass  # non-unix / nested-loop environments
     try:
-        await asyncio.Event().wait()
+        await stop.wait()
+        LOG.info("shutdown signal received; closing")
     finally:
         if admin is not None:
             await admin.close()
@@ -377,8 +401,10 @@ def main(argv=None) -> None:
     )
     parser.add_argument(
         "--warmup",
-        default="16,256",
-        help="comma-separated bucket sizes to pre-compile at boot",
+        default="512,8192",
+        help="comma-separated bucket sizes to pre-compile at boot, both "
+        "programs each (default: the first bucket the 384-item device "
+        "crossover can dispatch, and the largest launch)",
     )
     parser.add_argument(
         "--secret-file",

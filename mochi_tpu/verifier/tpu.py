@@ -15,12 +15,16 @@ batcher feeds the device in-process — one IPC hop less on the hot path.
 
 from __future__ import annotations
 
+import logging
 from typing import Optional, Sequence
 
 import jax
 
 from ..crypto.batch_verify import JaxBatchBackend
 from .spi import BatchingVerifier, SignatureVerifier
+
+
+LOG = logging.getLogger(__name__)
 
 
 class _SignerRegistrationMixin:
@@ -144,15 +148,23 @@ class ShardedJaxBatchBackend(JaxBatchBackend):
         gen = self.registry.generation
         m = ((bucket + self.n_devices - 1) // self.n_devices) * self.n_devices
         table = self.registry.device_table(self._rep_sharding, gen)
-        np.asarray(
-            self._sharded_comb(
-                table,
-                np.zeros((m,), np.int32),
-                np.zeros((m, F.NLIMBS), np.int32),
-                np.zeros((m,), np.int32),
-                np.zeros((m, 32), np.uint8),
-                np.zeros((m, 32), np.uint8),
-            )
+        out = self._sharded_comb(
+            table,
+            np.zeros((m,), np.int32),
+            np.zeros((m, F.NLIMBS), np.int32),
+            np.zeros((m,), np.int32),
+            np.zeros((m, 32), np.uint8),
+            np.zeros((m, 32), np.uint8),
+        )
+        np.asarray(out)
+        # which devices actually hold a slice of the result: the evidence
+        # that a multi-chip host is used, not just its first chip
+        LOG.info(
+            "sharded comb at bucket %d: %d shards of %s on devices %s",
+            bucket,
+            len(out.addressable_shards),
+            out.addressable_shards[0].data.shape,
+            sorted(s.device.id for s in out.addressable_shards),
         )
         with self._lock:
             self._ready_comb[bucket] = max(gen, self._ready_comb.get(bucket, 0))

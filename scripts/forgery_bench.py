@@ -1,6 +1,6 @@
 """Adversarial-load sweep: throughput vs forged-signature fraction.
 
-VERDICT r3 item 8.  The rejected random-linear-combination batch design
+The rejected random-linear-combination batch design
 (batch_verify.py docstring) degrades under attack: one forged signature
 fails the whole combined check and forces bisection retries, so an
 attacker salting f% forgeries multiplies work by O(log n) per forgery.
@@ -19,6 +19,7 @@ Usage: python scripts/forgery_bench.py [batch]
 from __future__ import annotations
 
 import json
+import os
 import sys
 import time
 
@@ -26,10 +27,11 @@ import numpy as np
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", ".jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-sys.path.insert(0, ".")
+from mochi_tpu.utils.runtime import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
 
 from _bench_common import require_tpu  # noqa: E402
 from mochi_tpu.crypto import batch_verify, keys  # noqa: E402

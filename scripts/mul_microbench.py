@@ -13,6 +13,7 @@ Usage:  python scripts/mul_microbench.py [B]   (default 4096)
 
 from __future__ import annotations
 
+import os
 import sys
 import time
 
@@ -20,8 +21,11 @@ import numpy as np
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", ".jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
+
+from mochi_tpu.utils.runtime import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
 
 import jax.numpy as jnp
 

@@ -13,6 +13,13 @@ REPO_DIR=$(cd "$(dirname "$0")/.." && pwd)
 
 export PYTHONPATH="${REPO_DIR}${PYTHONPATH:+:$PYTHONPATH}"
 
+VERIFIER="${MOCHI_VERIFIER:-cpu}"
+if [ "$VERIFIER" = "tpu" ] && [ "$N" -gt 1 ]; then
+  echo "MOCHI_VERIFIER=tpu starts $N processes that each claim the chip;" \
+       "a chip has one owner — use MOCHI_VERIFIER=remote" >&2
+  exit 2
+fi
+
 if [ ! -f "$OUT/cluster_config.json" ]; then
   python -m mochi_tpu.tools.gen_cluster \
     --out-dir "$OUT" --servers "$N" --rf "$RF" --base-port "$BASE_PORT"
@@ -24,8 +31,9 @@ PIDS=()
 # MOCHI_VERIFIER=remote -> boot ONE TPU-owning verifier service and point
 # every replica at it (a chip has a single owner process; this is the only
 # way a multi-process cluster gets TPU-backed verification).  Other values
-# (cpu | tpu | remote:<host>:<port>) pass through per replica.
-VERIFIER="${MOCHI_VERIFIER:-cpu}"
+# (cpu | remote:<host>:<port>) pass through per replica; "tpu" would make
+# every replica process an owner of the one chip, so it is refused for
+# more than one process.
 SECRET_ARGS=()
 if [ "$VERIFIER" = "remote" ]; then
   VPORT=$((BASE_PORT + 2000))
@@ -51,11 +59,22 @@ PYEOF
     --signers-file "$OUT/signers.txt" \
     --admin-port $((VPORT + 1)) \
     >"$OUT/log/verifier.log" 2>&1 &
-  PIDS+=($!)
-  for _ in $(seq 1 120); do
+  VPID=$!
+  PIDS+=("$VPID")
+  # No replica starts before the chip's owner is READY (a cold boot compiles
+  # both programs; later boots load them from the compile cache), and none
+  # starts at all if it died or never got there.
+  for _ in $(seq 1 900); do
     grep -q READY "$OUT/log/verifier.log" 2>/dev/null && break
+    kill -0 "$VPID" 2>/dev/null || break
     sleep 1
   done
+  if ! grep -q READY "$OUT/log/verifier.log" 2>/dev/null; then
+    echo "verifier service is not READY; see $OUT/log/verifier.log:" >&2
+    tail -n 5 "$OUT/log/verifier.log" >&2 || true
+    kill "$VPID" 2>/dev/null || true
+    exit 1
+  fi
   VERIFIER="remote:127.0.0.1:$VPORT"
   SECRET_ARGS=(--verifier-secret-file "$OUT/verifier.secret")
 fi

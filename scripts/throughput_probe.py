@@ -1,11 +1,11 @@
-"""verify_batch throughput probe — the shared body of the battery's
-MAX_BUCKET sweep and kernel-formulation A/B legs (one implementation;
-env knobs select the leg, replacing two copy-pasted battery heredocs).
+"""verify_batch throughput probe — the shared body of the MAX_BUCKET sweep
+and the kernel-formulation A/B legs (one implementation; env knobs select
+the leg).
 
-Output lines are parsed by scripts/ab_report.py — keep the formats:
+Output lines:
 
-  MAX_BUCKET=8192: 91000.0 sigs/s (90.0 ms)          (bucket leg)
-  MOCHI_SELECT_IMPL=stacked: best 91000.0 sigs/s ... (A/B leg, MOCHI_AB_LEG set)
+  MAX_BUCKET=8192: <rate> sigs/s (<ms> ms)          (bucket leg)
+  MOCHI_SELECT_IMPL=stacked: best <rate> sigs/s ... (A/B leg, MOCHI_AB_LEG set)
 
 Usage: [env knobs] python scripts/throughput_probe.py
 """
@@ -18,10 +18,11 @@ import time
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", ".jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-sys.path.insert(0, ".")
+from mochi_tpu.utils.runtime import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
 
 from _bench_common import require_tpu  # noqa: E402
 from mochi_tpu.crypto import batch_verify, keys  # noqa: E402

@@ -9,6 +9,7 @@ Usage: python scripts/unroll_bench.py [batch]   (default 8192)
 
 from __future__ import annotations
 
+import os
 import sys
 import time
 
@@ -16,10 +17,11 @@ import numpy as np
 
 import jax
 
-jax.config.update("jax_compilation_cache_dir", ".jax_cache")
-jax.config.update("jax_persistent_cache_min_compile_time_secs", 5.0)
+sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-sys.path.insert(0, ".")
+from mochi_tpu.utils.runtime import enable_compile_cache  # noqa: E402
+
+enable_compile_cache()
 
 from _bench_common import require_tpu  # noqa: E402
 from mochi_tpu.crypto import batch_verify, curve, keys  # noqa: E402
