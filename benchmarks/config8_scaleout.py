@@ -28,9 +28,9 @@ for this container's measured minute-scale tenancy swings of ±2-3x):
 
 The ``scaling_refit`` section re-derives the BASELINE.json scaling model
 with every host-side constant from this run (dedicated 1-op-txn legs in
-the sidecar posture split replica base+sign from memoized verify work);
-the single inherited anchor — the TPU chip's sigs/s, unmeasurable on this
-host — is flagged in-record.
+the sidecar posture split replica base+sign from memoized verify work).
+It sizes the verify pool in host cores only: no sigs/s has been taken on
+the attached chip, and the record says so.
 """
 
 from __future__ import annotations
@@ -329,9 +329,9 @@ def _refit(
     # Posture A — verifier-offload (MEASURED here; roadmap items 1+3
     # composed): replica cores carry base + sign only.  Verification
     # demand is protocol arithmetic (unique sigs/s = rate x quorum); the
-    # verification pool is sized in host cores from THIS run's verify_us,
-    # or in chips from the r04 capture — that chip rate is the single
-    # inherited anchor and is flagged as such.
+    # verification pool is sized in host cores from THIS run's verify_us.
+    # A pool in chips needs a measured device rate (ROADMAP A0/A4); until
+    # there is one the record says "not measured".
     offload = cores_for(replica_us)
     offload["device_unique_sigs_per_s_at_100k"] = (
         ns["target_ops_s"] * ns["quorum"]
@@ -339,11 +339,9 @@ def _refit(
     offload["verify_pool_host_cores_at_100k"] = round(
         ns["target_ops_s"] * ns["quorum"] * crypto["verify_us"] / 1e6, 0
     )
-    offload["verify_pool_chip_anchor"] = (
-        "chip rate NOT re-measurable on this host: 105,099.5 ladder "
-        "sigs/s/chip (r04 witnessed capture) x comb 2.474x (r07 A/B) "
-        "-> ~17-41 chips replaces the host verify pool; every other "
-        "constant in this refit is r10-measured"
+    offload["verify_pool_chips_at_100k"] = (
+        "not measured: no sigs/s has been taken on the attached chip "
+        "(ROADMAP A0/A4), so the verify pool is sized in host cores only"
     )
     # Posture B — host-inline verify (no sidecar): each replica pays
     # quorum-1 foreign grant verifies on its own core (own grant is the
@@ -372,7 +370,7 @@ def _refit(
         },
         "formula": "replica cores = 100k txn/s x rf x replica_us / 1e6 / "
         "efficiency; verify pool = 100k x quorum x verify_us / 1e6 host "
-        "cores (or the flagged chip anchor); inline posture adds "
+        "cores; inline posture adds "
         "(quorum-1) x verify_us to replica_us instead",
         "posture_verifier_offload_measured": offload,
         "posture_host_inline": inline,

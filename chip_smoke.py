@@ -27,12 +27,14 @@ Phases — each fails the run:
    no replay entry is convicted, and the re-verification ran on the device;
 5. warm boot: the second service boot found its programs in the compile cache.
 
-Exit code 0 and a last stdout line ``{"ok": true, "device": {"platform":
-"tpu", ...}, ...}`` only when every phase held on a TPU.  Without an
-accelerator it exits non-zero and prints no result.  The one exception is a
-debugging run: ``JAX_PLATFORMS=cpu python chip_smoke.py --tiny`` (n=4, bucket
-16, 8 writes), stamped ``"dry_run": true`` — none of its numbers is a device
-figure.
+Standard output is two JSON lines: the full record (phases, counts, set-up
+seconds, verifier counters), then — last, and with exactly these keys, which
+is what the chip check parses — ``{"ok": true, "device": {"platform": "tpu",
+"kind": "...", "count": 1}}``.  Exit code 0 only when every phase held on a
+TPU.  Without an accelerator it exits non-zero and prints no result.  The one
+exception is a debugging run: ``JAX_PLATFORMS=cpu python chip_smoke.py --tiny``
+(n=4, bucket 16, 8 writes), stamped ``"dry_run": true`` — none of its numbers
+is a device figure.
 """
 
 from __future__ import annotations
@@ -45,6 +47,7 @@ import importlib.metadata
 import json
 import os
 import random
+import re
 import shutil
 import sys
 import time
@@ -52,6 +55,13 @@ import urllib.request
 
 REPO = os.path.dirname(os.path.abspath(__file__))
 sys.path.insert(0, REPO)
+
+ADMIN_BASE_PORT = 24000  # replica /status ports count up from here
+SDK_TIMEOUT_S = 60.0  # per request
+# ROADMAP C10: a snapshot's WAL rotation closes the segment under the group
+# tick's fsync.  Every n=64 run logs it; it is counted in the result, and it
+# is the only ERROR a child may log until C10 is done and this set is empty.
+KNOWN_LOG_ERRORS = {"mochi_tpu.storage.durable storage background tick failed"}
 
 
 class SmokeFailure(Exception):
@@ -88,6 +98,19 @@ def cache_entries(cache_dir: str) -> int:
         return sum(1 for e in os.scandir(cache_dir) if e.is_file())
     except FileNotFoundError:
         return 0
+
+
+def child_log_errors(pc) -> dict:
+    """ERROR and CRITICAL records in every child's log (both lifetimes: a
+    restarted child appends to its log), counted by logger and message."""
+    record = re.compile(r"^\d{4}-\d\d-\d\d \S+ (\S+) (?:ERROR|CRITICAL) (.*)$")
+    counts: dict = {}
+    for sp in [pc.service_process, *pc.processes]:
+        with open(sp.log_path, errors="replace") as fh:
+            for m in filter(None, map(record.match, fh)):
+                what = f"{m.group(1)} {m.group(2)}"
+                counts[what] = counts.get(what, 0) + 1
+    return counts
 
 
 def chain_sum(stats: dict, key: str) -> int:
@@ -134,14 +157,14 @@ def key_for(i: int) -> str:
     return f"smoke-{i:05d}"
 
 
-async def per_client(pc, indices, n_clients: int, timeout_s: float, op) -> list:
+async def per_client(pc, indices, n_clients: int, op) -> list:
     """``indices`` split over ``n_clients`` concurrent SDK clients, each a
     closed loop of ``await op(client, i)``.  Returns the failed operations:
     any ``op`` that raised — every one of them fails the run."""
     failures = []
 
     async def worker(share):
-        client = pc.client(timeout_s=timeout_s)
+        client = pc.client(timeout_s=SDK_TIMEOUT_S)
         for i in share:
             try:
                 await op(client, i)
@@ -153,7 +176,7 @@ async def per_client(pc, indices, n_clients: int, timeout_s: float, op) -> list:
     return failures
 
 
-async def write_all(pc, seed: int, indices, n_clients: int, timeout_s: float):
+async def write_all(pc, seed: int, indices, n_clients: int):
     """Single-key signed 1 KiB PUTs.  Returns (acked indices, failures)."""
     from mochi_tpu.client import TransactionBuilder
 
@@ -165,11 +188,11 @@ async def write_all(pc, seed: int, indices, n_clients: int, timeout_s: float):
         )
         acked.append(i)
 
-    failures = await per_client(pc, indices, n_clients, timeout_s, put)
+    failures = await per_client(pc, indices, n_clients, put)
     return sorted(acked), failures
 
 
-async def read_all(pc, seed: int, indices, n_clients: int, quorum: int, timeout_s: float):
+async def read_all(pc, seed: int, indices, n_clients: int, quorum: int):
     """Read back ``indices``: value equal and a >= quorum-grant certificate.
     Returns (ok count, grant signatures in those certificates, failures)."""
     from mochi_tpu.client import TransactionBuilder
@@ -187,7 +210,7 @@ async def read_all(pc, seed: int, indices, n_clients: int, quorum: int, timeout_
         check(n_grants >= quorum, f"{n_grants} grants < {quorum}")
         grants.append(n_grants)
 
-    failures = await per_client(pc, indices, n_clients, timeout_s, get)
+    failures = await per_client(pc, indices, n_clients, get)
     return len(grants), sum(grants), failures
 
 
@@ -269,9 +292,7 @@ async def run(args, out: dict) -> None:
     from mochi_tpu.utils.runtime import compile_cache_dir
 
     n, rf = (4, 4) if args.tiny else (64, 64)
-    writes = args.writes or (8 if args.tiny else 1024)
-    reads = min(writes, args.reads or (8 if args.tiny else 128))
-    n_clients = args.clients or (2 if args.tiny else 8)
+    writes, reads, n_clients = (8, 8, 2) if args.tiny else (1024, 128, 8)
     buckets = [16] if args.tiny else [64, 8192]
     probe_sizes = [8, 16] if args.tiny else [64, 8192]
     cores = os.cpu_count() or 1
@@ -290,7 +311,7 @@ async def run(args, out: dict) -> None:
         verifier="service",
         service_backend=args.service_backend,
         service_warmup=",".join(map(str, buckets)),
-        admin_base_port=args.admin_base_port,
+        admin_base_port=ADMIN_BASE_PORT,
         storage_dir=True,
         seed=args.seed,
         ready_timeout_s=900.0,
@@ -306,9 +327,8 @@ async def run(args, out: dict) -> None:
     out["min_device_batch_forced"] = 0
 
     def replica_statuses() -> list:
-        base = args.admin_base_port
         return [
-            http_json(base + sp.index * n + j)
+            http_json(ADMIN_BASE_PORT + sp.index * n + j)
             for sp in pc.processes
             for j in range(len(sp.server_ids))
         ]
@@ -333,8 +353,6 @@ async def run(args, out: dict) -> None:
             "kind": dev["device_kind"],
             "count": dev["n_devices"],
         }
-        out["platform"], out["device_kind"] = dev["platform"], dev["device_kind"]
-        out["n_devices"] = dev["n_devices"]
         check(dev["compile_cache_dir"] == cache_dir, "the service caches somewhere else")
         if args.tiny:
             check(dev["platform"] == "cpu", "--tiny is the CPU dry run")
@@ -361,11 +379,11 @@ async def run(args, out: dict) -> None:
         # ------------------------------------------------ 2. load + query
         ph = phase("load_query")
         t0 = time.monotonic()
-        acked, failures = await write_all(pc, args.seed, range(writes), n_clients, args.timeout)
+        acked, failures = await write_all(pc, args.seed, range(writes), n_clients)
         seconds["load"] = round(time.monotonic() - t0, 1)
         sample = sorted(rng.sample(acked, min(reads, len(acked))))
         read_ok, _sigs, read_failures = await read_all(
-            pc, args.seed, sample, n_clients, cfg.quorum, args.timeout
+            pc, args.seed, sample, n_clients, cfg.quorum
         )
         failures += read_failures
         ph.update(acked=len(acked), failed=len(failures), reads=len(sample), read_ok=read_ok)
@@ -407,7 +425,7 @@ async def run(args, out: dict) -> None:
         probe2 = await run_probe(pc, args.seed, probe_sizes, "second")
         check(probe2["ok"], f"after restart, verdicts differ: {probe2['batches']}")
         read_ok, grant_sigs, failures = await read_all(
-            pc, args.seed, acked, n_clients, cfg.quorum, args.timeout
+            pc, args.seed, acked, n_clients, cfg.quorum
         )
         out["read_back"], out["distinct_grant_signatures"] = read_ok, grant_sigs
         out["failed_operations"] += len(failures)
@@ -429,11 +447,14 @@ async def run(args, out: dict) -> None:
         life2 = service_summary(http_json(pc.service_admin_port))
         out["verifier"] = {"first_lifetime": life1, "second_lifetime": life2}
         out["probe"] = {"first_lifetime": probe["batches"], "second_lifetime": probe2["batches"]}
+        out["child_log_errors"] = child_log_errors(pc)
         ph.update(read_back=read_ok, service_exit_code=rc, replay_convicted=out["replay"]["convicted"])
         check(len(replicas) == n, "not every replica answered /status")
         check(out["replay"]["convicted"] == 0, f"replay convicted entries: {out['replay']}")
         check(out["replay"]["entries"] > 0, "no replica replayed anything")
         check(out["replicas_with_jax_loaded"] == 0, "a replica process imported jax")
+        check(set(out["child_log_errors"]) <= KNOWN_LOG_ERRORS,
+              f"a child logged an error: {out['child_log_errors']}")
         check(out["replica_fallback_batches"] == 0, "a replica verified locally instead of on the chip")
         for name, life in (("first", life1), ("second", life2)):
             check(life["host_routed_items"] == 0, f"{name} lifetime: items routed to the host")
@@ -496,14 +517,8 @@ def main() -> int:
     parser.add_argument("--seed", type=int, default=21)
     parser.add_argument("--tiny", action="store_true",
                         help="CPU dry run (needs an explicit JAX_PLATFORMS=cpu)")
-    parser.add_argument("--writes", type=int, default=0)
-    parser.add_argument("--reads", type=int, default=0)
-    parser.add_argument("--clients", type=int, default=0)
-    parser.add_argument("--timeout", type=float, default=60.0,
-                        help="per-request SDK timeout, seconds")
     parser.add_argument("--service-backend", default="tpu",
                         choices=("tpu", "tpu-sharded"))
-    parser.add_argument("--admin-base-port", type=int, default=24000)
     args = parser.parse_args()
 
     pinned = os.environ.get("JAX_PLATFORMS", "").strip().lower()
@@ -547,6 +562,8 @@ def main() -> int:
     if "device" not in out:
         return 1  # no device was ever reported: no result to print
     print(json.dumps(out))
+    # the chip check's line: these keys and no others, last on stdout
+    print(json.dumps({"ok": out["ok"], "device": out["device"]}), flush=True)
     return 0 if out["ok"] else 1
 
 

@@ -12,7 +12,7 @@ Output lines:
 
 Every batch is read back (np.asarray) inside the timed region.
 
-Usage: [MOCHI_ALLOW_CPU=1] [COMB_BATCH=8192] [COMB_SIGNERS=16,64]
+Usage: [COMB_BATCH=8192] [COMB_SIGNERS=16,64]
        python scripts/comb_bench.py
 """
 
@@ -24,15 +24,12 @@ import time
 
 import numpy as np
 
-import jax
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from mochi_tpu.utils.runtime import enable_compile_cache  # noqa: E402
+from mochi_tpu.utils.runtime import device_info, enable_compile_cache  # noqa: E402
 
 enable_compile_cache()
 
-from _bench_common import require_tpu  # noqa: E402
 from mochi_tpu.crypto import batch_verify, comb, keys  # noqa: E402
 from mochi_tpu.verifier.spi import VerifyItem  # noqa: E402
 
@@ -58,7 +55,7 @@ def _time_best(fn, reps=3):
 
 
 def main() -> None:
-    require_tpu(jax.devices()[0])
+    device = device_info(require_accelerator=True)
     n = int(os.environ.get("COMB_BATCH", str(batch_verify.MAX_BUCKET)))
     signer_counts = [
         int(k) for k in os.environ.get("COMB_SIGNERS", "16,64").split(",") if k
@@ -75,10 +72,13 @@ def main() -> None:
     ladder_rate = n / ladder_dt
     print(f"LADDER: {ladder_rate:.1f} sigs/s ({ladder_dt * 1e3:.1f} ms)", flush=True)
     results = {
+        "platform": device["platform"],
         "batch": n,
         "ladder_sigs_per_sec": round(ladder_rate, 1),
         "comb_by_signers": {},
     }
+    if device["platform"] != "tpu":
+        results["dry_run"] = True  # explicit CPU run: not a device figure
 
     def checkpoint():
         # Cumulative record after EVERY milestone: the LAST COMB_JSON line

@@ -30,11 +30,10 @@ import jax
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from mochi_tpu.utils.runtime import enable_compile_cache  # noqa: E402
+from mochi_tpu.utils.runtime import device_info, enable_compile_cache  # noqa: E402
 
 enable_compile_cache()
 
-from _bench_common import require_tpu  # noqa: E402
 from mochi_tpu.crypto import batch_verify, keys  # noqa: E402
 from mochi_tpu.verifier.spi import VerifyItem  # noqa: E402
 
@@ -43,9 +42,11 @@ def main() -> None:
     n = int(sys.argv[1]) if len(sys.argv) > 1 else 65536
     depth = int(sys.argv[2]) if len(sys.argv) > 2 else 4
     mb = batch_verify.MAX_BUCKET
+    device = device_info(require_accelerator=True)
     dev = jax.devices()[0]
-    require_tpu(dev)
-    print(f"device: {dev.platform}, n={n}, MAX_BUCKET={mb}, depth={depth}")
+    # explicit CPU run: stamped, not a device figure
+    stamp = {} if device["platform"] == "tpu" else {"dry_run": True}
+    print(f"device: {device['platform']}, n={n}, MAX_BUCKET={mb}, depth={depth}")
 
     kp = keys.generate_keypair()
     t0 = time.perf_counter()
@@ -92,7 +93,8 @@ def main() -> None:
     # line is the record).
     partial = {
         "metric": "e2e_vs_pipelined",
-        "platform": dev.platform,
+        "platform": device["platform"],
+        **stamp,
         "n_items": n,
         "max_bucket": mb,
         "depth": depth,
@@ -133,7 +135,8 @@ def main() -> None:
 
     rec = {
         "metric": "e2e_vs_pipelined",
-        "platform": dev.platform,
+        "platform": device["platform"],
+        **stamp,
         "n_items": n,
         "max_bucket": mb,
         "depth": depth,

@@ -25,23 +25,19 @@ import time
 
 import numpy as np
 
-import jax
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from mochi_tpu.utils.runtime import enable_compile_cache  # noqa: E402
+from mochi_tpu.utils.runtime import device_info, enable_compile_cache  # noqa: E402
 
 enable_compile_cache()
 
-from _bench_common import require_tpu  # noqa: E402
 from mochi_tpu.crypto import batch_verify, keys  # noqa: E402
 from mochi_tpu.verifier.spi import VerifyItem  # noqa: E402
 
 
 def main() -> None:
     batch = int(sys.argv[1]) if len(sys.argv) > 1 else 8192
-    dev = jax.devices()[0]
-    require_tpu(dev)
+    device = device_info(require_accelerator=True)
     kp = keys.generate_keypair()
     base = []
     for i in range(batch):
@@ -87,7 +83,7 @@ def main() -> None:
     vals = list(sweep.values())
     rec = {
         "metric": "forged_fraction_throughput_sweep",
-        "platform": dev.platform,
+        "platform": device["platform"],
         "batch": batch,
         "sigs_per_sec_by_forged_fraction": sweep,
         "flatness_min_over_max": round(min(vals) / max(vals), 3),
@@ -95,6 +91,8 @@ def main() -> None:
         "claim": "per-item bitmap => no throughput cliff under forgery "
         "(batch_verify.py RLC-rejection argument)",
     }
+    if device["platform"] != "tpu":
+        rec["dry_run"] = True  # explicit CPU run: not a device figure
     print("FORGERY_JSON " + json.dumps(rec))
 
 

@@ -23,7 +23,7 @@ import jax
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from mochi_tpu.utils.runtime import enable_compile_cache  # noqa: E402
+from mochi_tpu.utils.runtime import device_info, enable_compile_cache  # noqa: E402
 
 enable_compile_cache()
 
@@ -75,8 +75,10 @@ def bench(name, mul_fn):
 
 
 def main():
-    dev = jax.devices()[0]
-    print(f"device: {dev.platform} {getattr(dev, 'device_kind', '?')}  B={B}")
+    device = device_info(require_accelerator=True)
+    print(f"device: {device['platform']} {device['device_kind']}  B={B}")
+    if device["platform"] != "tpu":
+        print("DRY RUN (JAX_PLATFORMS=cpu): not a device figure", flush=True)
 
     orig_skew = F.SKEW_IMPL
     ref = None

@@ -21,7 +21,7 @@ import jax
 
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from mochi_tpu.utils.runtime import enable_compile_cache  # noqa: E402
+from mochi_tpu.utils.runtime import device_info, enable_compile_cache  # noqa: E402
 
 enable_compile_cache()
 
@@ -32,8 +32,11 @@ from mochi_tpu.verifier.spi import VerifyItem  # noqa: E402
 
 def main():
     batches = [int(a) for a in sys.argv[1:]] or [8192, 16384]
+    device = device_info(require_accelerator=True)
     dev = jax.devices()[0]
-    print(f"device: {dev.platform} {getattr(dev, 'device_kind', '?')}")
+    print(f"device: {device['platform']} {device['device_kind']}")
+    if device["platform"] != "tpu":
+        print("DRY RUN (JAX_PLATFORMS=cpu): not a device figure", flush=True)
     kp = keys.generate_keypair()
     fn = jax.jit(verify_prepared)
 

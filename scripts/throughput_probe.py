@@ -16,21 +16,20 @@ import os
 import sys
 import time
 
-import jax
-
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
-from mochi_tpu.utils.runtime import enable_compile_cache  # noqa: E402
+from mochi_tpu.utils.runtime import device_info, enable_compile_cache  # noqa: E402
 
 enable_compile_cache()
 
-from _bench_common import require_tpu  # noqa: E402
 from mochi_tpu.crypto import batch_verify, keys  # noqa: E402
 from mochi_tpu.verifier.spi import VerifyItem  # noqa: E402
 
 
 def main() -> None:
-    require_tpu(jax.devices()[0])
+    device = device_info(require_accelerator=True)
+    if device["platform"] != "tpu":
+        print("DRY RUN (JAX_PLATFORMS=cpu): not a device figure", flush=True)
     n = batch_verify.MAX_BUCKET
     kp = keys.generate_keypair()
     items = [

@@ -57,9 +57,15 @@ def _smoke(args, env_overrides, timeout):
 def test_chip_smoke_tiny_dry_run_passes_and_is_stamped():
     proc = _smoke(["--tiny"], {"JAX_PLATFORMS": "cpu"}, timeout=600)
     assert proc.returncode == 0, proc.stderr[-3000:]
-    rec = json.loads(proc.stdout.splitlines()[-1])
+    rec, last = map(json.loads, proc.stdout.splitlines()[-2:])
+    # the chip check parses the last line: exactly these keys, nothing else
+    assert last == {"ok": True, "device": rec["device"]}
+    assert set(last["device"]) == {"platform", "kind", "count"}
     assert rec["ok"] is True and rec["dry_run"] is True
-    assert rec["platform"] == "cpu" and rec["device"]["platform"] == "cpu"
+    assert rec["device"]["platform"] == "cpu" and rec["device"]["count"] >= 1
+    assert "platform" not in rec  # the device's identity is written once
+    # every ERROR a child logged is counted (the smoke fails on an unknown one)
+    assert isinstance(rec["child_log_errors"], dict)
     assert rec["claim"] is None
     assert set(rec["phases"]) == {
         "cold_boot", "load_query", "probe", "kill_restart_recover", "warm_boot",
@@ -159,6 +165,21 @@ def test_device_backends_refuse_a_cpu_only_host_without_the_explicit_pin(monkeyp
 
     with pytest.raises(SystemExit, match="no accelerator"):
         bench.main()
+
+
+def test_device_scripts_share_the_one_gate():
+    """Every script under scripts/ that measures the device asks
+    ``device_info(require_accelerator=True)``: one rule, one override
+    (JAX_PLATFORMS=cpu).  ``pallas_retry.py`` is stricter on purpose — its
+    question is the Mosaic compile, which has no CPU form."""
+    scripts = os.path.join(REPO, "scripts")
+    for name in sorted(os.listdir(scripts)):
+        if not name.endswith(".py") or name == "pallas_retry.py":
+            continue
+        src = open(os.path.join(scripts, name)).read()
+        assert "MOCHI_ALLOW_CPU" not in src and "_bench_common" not in src, name
+        if "enable_compile_cache()" in src:  # it compiles, so it measures
+            assert "device_info(require_accelerator=True)" in src, name
 
 
 def test_pallas_kernel_never_interprets_unless_asked():
