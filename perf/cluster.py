@@ -1,0 +1,134 @@
+"""The deployment under test: ``ProcessCluster`` with the service started
+through ``perf/service_launch.py``, and readers of the children's ``/status``.
+
+The product is taken as shipped.  The only changes to what ``ProcessCluster``
+would start: the service's ``python -m mochi_tpu.verifier.service`` becomes
+``python perf/service_launch.py --perf-ctl <dir>`` (which calls the same
+``main()``), and the ``--warmup ""`` pair that ``ProcessCluster`` always passes
+is dropped, so the service's own default applies.
+"""
+
+from __future__ import annotations
+
+import json
+import os
+import re
+import sys
+import urllib.request
+
+from mochi_tpu.testing.process_cluster import ProcessCluster
+
+ADMIN_BASE_PORT = 24000  # replica /status ports count up from here
+SERVICE_MODULE = "mochi_tpu.verifier.service"
+
+
+class PerfCluster(ProcessCluster):
+    def __init__(self, *args, launcher: str, ctl_dir: str, service_argv=(), **kwargs):
+        super().__init__(*args, **kwargs)
+        self.launcher = launcher
+        self.ctl_dir = ctl_dir
+        self.service_argv = list(service_argv)
+
+    async def _spawn(self, sp, env) -> None:
+        if sp.index == -1 and SERVICE_MODULE in sp.argv:
+            rest = sp.argv[sp.argv.index(SERVICE_MODULE) + 1:]
+            i = rest.index("--warmup")
+            del rest[i:i + 2]
+            sp.argv = [sys.executable, self.launcher, "--perf-ctl", self.ctl_dir,
+                       *rest, *self.service_argv]
+        await ProcessCluster._spawn(sp, env)
+
+    def replica_statuses(self) -> list:
+        return [
+            http_json(ADMIN_BASE_PORT + sp.index * self.n_servers + j)
+            for sp in self.processes
+            for j in range(len(sp.server_ids))
+        ]
+
+    def service_status(self) -> dict:
+        return http_json(self.service_admin_port)
+
+    def cluster_config_path(self) -> str:
+        return os.path.join(self._tmpdir.name, "cluster_config.json")
+
+    def log_paths(self) -> list:
+        return [sp.log_path for sp in [self.service_process, *self.processes] if sp is not None]
+
+
+def http_json(port: int, path: str = "/status") -> dict:
+    with urllib.request.urlopen(f"http://127.0.0.1:{port}{path}", timeout=30) as r:
+        return json.loads(r.read())
+
+
+def chain_sum(stats: dict, key: str) -> int:
+    """Sum an integer counter down a verifier_stats ``inner`` chain."""
+    total = 0
+    while stats:
+        total += int(stats.get(key, 0))
+        stats = stats.get("inner")
+    return total
+
+
+def service_counters(status: dict) -> dict:
+    """The service's counters that the checks and the per-layer metrics read
+    (``chip_smoke.py``'s selection)."""
+    v = status["verifier"]  # CachingVerifier -> TpuBatchVerifier
+    tpu = v["inner"]
+    dev, comb = tpu["device"], tpu["comb"]
+    return {
+        "requests": status["requests"],
+        "items": status["items"],
+        "memo_hits": v["hits"],
+        "memo_misses": v["misses"],
+        "batches_flushed": tpu["batches_flushed"],
+        "fallback_batches": chain_sum(v, "fallback_batches"),
+        "device_items": dev["device_items"],
+        "host_routed_items": dev["host_routed_items"],
+        "min_device_items": dev["min_device_items"],
+        "ready_buckets": dev["ready_buckets"],
+        "failed_buckets": dev["failed_buckets"],
+        "comb_ready_buckets": comb["ready_buckets"],
+        "comb_failed_buckets": dev["comb_failed_buckets"],
+        "registered_signers": comb["registered_signers"],
+    }
+
+
+def replica_counters(statuses: list) -> dict:
+    """Sums over the replicas of what the checks and metrics read."""
+    drain = [r["batching"].get("transport.drain-frames", {}) for r in statuses]
+    return {
+        "replicas": len(statuses),
+        "jax_loaded": sum(1 for r in statuses if r["jax_loaded"]),
+        "fallback_batches": sum(chain_sum(r["verifier"], "fallback_batches") for r in statuses),
+        "remote_batches": sum(chain_sum(r["verifier"], "remote_batches") for r in statuses),
+        "fsyncs": sum(int(r["storage"].get("fsyncs", 0)) for r in statuses),
+        "drain_count": sum(int(d.get("count", 0)) for d in drain),
+        "drain_frames": sum(float(d.get("sum", 0.0)) for d in drain),
+        "storage_engines": sorted({r["storage"].get("engine") for r in statuses}),
+        "fsync_policies": sorted({str(r["storage"].get("fsync")) for r in statuses}),
+    }
+
+
+def cache_entries(cache_dir: str) -> int:
+    """Compiled programs in the persistent cache (one file each, flat)."""
+    try:
+        return sum(1 for e in os.scandir(cache_dir) if e.is_file())
+    except FileNotFoundError:
+        return 0
+
+
+_LOG_RECORD = re.compile(r"^\d{4}-\d\d-\d\d \S+ (\S+) (?:ERROR|CRITICAL) (.*)$")
+
+
+def log_errors(paths) -> dict:
+    """ERROR and CRITICAL records in the children's logs, by logger and message."""
+    counts: dict = {}
+    for path in paths:
+        try:
+            with open(path, errors="replace") as fh:
+                for m in filter(None, map(_LOG_RECORD.match, fh)):
+                    what = f"{m.group(1)} {m.group(2)}"[:160]
+                    counts[what] = counts.get(what, 0) + 1
+        except OSError:
+            pass
+    return counts

@@ -1,0 +1,193 @@
+"""The plain reference: a per-key history model of a replicated register.
+
+It shares no code with ``mochi_tpu``.  It is given what the generator
+recorded (every operation of the window, with the time it was issued and the
+time its reply came, on one monotonic clock) and what the harness read back
+and probed after the window, and it says whether the system gave what the
+configuration states:
+
+* every read returned a record that the load or some update of that key
+  wrote, byte for byte (tag and checksum), with at least ``quorum`` grants in
+  its certificate;
+* no read returned a record older than the newest update acknowledged before
+  the read was issued.  "Older" is by real time: a read that returns write W
+  is stale when some other update of the key was issued after W was
+  acknowledged and was itself acknowledged before the read was issued.  An
+  update whose reply never came (failed, outcome unknown) may or may not have
+  been applied: a read may return it, and it makes nothing stale;
+* after the window every key touched reads back the newest acknowledged
+  update or one concurrent with it (same rule, the read issued after the
+  window), again with at least ``quorum`` grants;
+* every bad Write2 of the probe was refused by every replica it was sent to
+  and left its key's record unchanged.
+
+Each number it compares is returned beside its limit; an exact comparison has
+the limit 0.
+"""
+
+from __future__ import annotations
+
+import bisect
+import math
+from dataclasses import dataclass, field
+
+# columns of one recorded operation (perf/ycsb.py writes them in this order)
+KIND, REC, T_ISSUE, T_DONE, OK, WRITER, SEQ, CRC, GRANTS = range(9)
+READ, UPDATE = 0, 1
+
+
+@dataclass
+class Check:
+    """One number compared, with its limit; ``at_least`` for a lower limit."""
+
+    name: str
+    value: float
+    limit: float
+    at_least: bool = False
+
+    @property
+    def ok(self) -> bool:
+        return self.value >= self.limit if self.at_least else self.value <= self.limit
+
+    def line(self) -> str:
+        rel = ">=" if self.at_least else "<="
+        return (f"check {self.name}: {self.value} (limit {rel} {self.limit}) "
+                f"{'ok' if self.ok else 'FAILED'}")
+
+
+def percentile(values, q: float) -> float:
+    """Nearest-rank percentile: the smallest value with at least ``q`` percent
+    of the sample at or below it.  A failed operation is ``math.inf``, so it
+    counts as over any limit."""
+    if not values:
+        return math.nan
+    data = sorted(values)
+    rank = max(1, math.ceil(q / 100.0 * len(data)))
+    return data[rank - 1]
+
+
+@dataclass
+class KeyHistory:
+    """Writes of one key.  ``acked`` holds (t_ack, t_issue) of every
+    acknowledged update, sorted by t_ack, with the running maximum of t_issue,
+    so "the latest issue time among updates acknowledged before t" is one
+    bisection."""
+
+    writes: dict = field(default_factory=dict)  # (writer, seq) -> (t_issue, t_ack, crc)
+    _ack_times: list = field(default_factory=list)
+    _max_issue: list = field(default_factory=list)
+
+    def add_write(self, tag, t_issue, t_ack, crc) -> None:
+        self.writes[tag] = (t_issue, t_ack, crc)
+
+    def seal(self) -> None:
+        acked = sorted((w[1], w[0]) for w in self.writes.values() if w[1] < math.inf)
+        self._ack_times = [a for a, _ in acked]
+        running, best = [], -math.inf
+        for _, issued in acked:
+            best = max(best, issued)
+            running.append(best)
+        self._max_issue = running
+
+    def newest_issue_acked_before(self, t: float) -> float:
+        i = bisect.bisect_left(self._ack_times, t)
+        return self._max_issue[i - 1] if i else -math.inf
+
+    def judge_read(self, tag, crc, t_issue) -> str:
+        """'ok', 'unknown' (no such write of this key, or other bytes), or
+        'stale'."""
+        w = self.writes.get(tag)
+        if w is None or w[2] != crc:
+            return "unknown"
+        if w[1] < self.newest_issue_acked_before(t_issue):
+            return "stale"
+        return "ok"
+
+
+def build_histories(ops, load_writer: int, value_crc) -> dict:
+    """Per-record histories from the recorded operations.  ``value_crc(writer,
+    seq)`` recomputes the checksum of the record an operation wrote, from the
+    seed and not from what the generator says it sent."""
+    hist: dict = {}
+    for op in ops:
+        if op[KIND] != UPDATE:
+            continue
+        h = hist.setdefault(op[REC], KeyHistory())
+        t_ack = op[T_DONE] if op[OK] else math.inf
+        h.add_write((op[WRITER], op[SEQ]), op[T_ISSUE], t_ack, value_crc(op[WRITER], op[SEQ]))
+    for op in ops:
+        hist.setdefault(op[REC], KeyHistory())
+    for rec, h in hist.items():
+        # the load wrote every record before the window opened
+        h.add_write((load_writer, rec), -math.inf, -math.inf, value_crc(load_writer, rec))
+        h.seal()
+    return hist
+
+
+def check_window(ops, hist: dict, quorum: int) -> list:
+    """The window's reads against the histories."""
+    unknown = stale = short = 0
+    for op in ops:
+        if op[KIND] != READ or not op[OK]:
+            continue
+        verdict = hist[op[REC]].judge_read((op[WRITER], op[SEQ]), op[CRC], op[T_ISSUE])
+        unknown += verdict == "unknown"
+        stale += verdict == "stale"
+        short += op[GRANTS] < quorum
+    return [
+        Check("window_reads_of_no_known_write", unknown, 0),
+        Check("window_stale_reads", stale, 0),
+        Check("window_reads_under_quorum_grants", short, 0),
+    ]
+
+
+def check_readback(readback, hist: dict, quorum: int) -> list:
+    """``readback``: {record: (writer, seq, crc, grants, t_issue)} for every
+    record the window touched, read after it closed (writer -1: no tag)."""
+    missing = wrong = short = 0
+    for rec, h in hist.items():
+        got = readback.get(rec)
+        if got is None:
+            missing += 1
+            continue
+        writer, seq, crc, grants, t_issue = got
+        wrong += h.judge_read((writer, seq), crc, t_issue) != "ok"
+        short += grants < quorum
+    return [
+        Check("readback_missing", missing, 0),
+        Check("readback_not_newest_acknowledged", wrong, 0),
+        Check("readback_under_quorum_grants", short, 0),
+    ]
+
+
+def check_probe(probe: list) -> list:
+    """``probe``: one dict per bad Write2 — ``kind``, ``sent`` (replicas it
+    went to), ``accepted`` (replicas that answered it as a commit),
+    ``unchanged`` (the key read back the same record afterwards)."""
+    accepted = sum(p["accepted"] for p in probe)
+    changed = sum(1 for p in probe if not p["unchanged"])
+    return [
+        Check("bad_write2_sent", sum(1 for p in probe if p["sent"] > 0), len(probe), at_least=True),
+        Check("bad_write2_accepted_by_replicas", accepted, 0),
+        Check("bad_write2_changed_a_record", changed, 0),
+    ]
+
+
+def summarize(ops, seconds: float, t_end: float) -> dict:
+    """End-to-end numbers of the window, from the generator's side: every
+    operation issued in the window counts for the tails (a failed one as
+    infinite), and an operation counts for the rate when it completed inside
+    the window and was answered."""
+    lat = {READ: [], UPDATE: []}
+    done_ok = failed = 0
+    for op in ops:
+        ms = (op[T_DONE] - op[T_ISSUE]) * 1e3 if op[OK] else math.inf
+        lat[op[KIND]].append(ms)
+        failed += not op[OK]
+        done_ok += bool(op[OK]) and op[T_DONE] <= t_end
+    return {
+        "attempted": len(ops),
+        "failed": failed,
+        "ops_s": done_ok / seconds,
+        "latency_ms": lat,
+    }

@@ -1,0 +1,577 @@
+#!/usr/bin/env python3
+"""One run of one benchmark cell: boot the deployment as shipped, load it,
+drive it closed loop for the window, decide ``correct``, print the result.
+
+    python perf/run.py --workload <name> --seed <n> --seconds <s> --trace <0|1>
+
+A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a configuration
+(``perf/configs/<config>.json``) under a traffic mix
+(``perf/traffic/<traffic>.json``).  A per-layer metric is a file in
+``perf/layer_metrics/``.  Nothing here names a cell, a mix or a metric.
+
+Processes: replicas are ``python -m mochi_tpu.server`` children, ONE verifier
+service owns the chip (started through ``perf/service_launch.py``, which calls
+the product's ``main()`` unchanged), the load comes from the cell's
+``generator_processes`` workers (``perf/ycsb.py``), and this process stays off
+JAX.  No ``MOCHI_*`` variable and no ``--warmup`` is set.
+
+Without a TPU the run exits non-zero and prints no result.  ``--rehearse``,
+under an exported ``JAX_PLATFORMS=cpu``, runs the cell at a tiny shape on the
+CPU to rehearse the control flow; it prints no device metric.
+
+The last line of standard output is the result object; everything before it
+is commentary (the numbers compared for ``correct`` beside their limits,
+medians, p99s, sample counts).
+"""
+
+from __future__ import annotations
+
+import time
+
+T_PROCESS_START = time.monotonic()
+
+import argparse
+import asyncio
+import json
+import math
+import os
+import shutil
+import sys
+import tempfile
+import zlib
+
+PERF = os.path.dirname(os.path.abspath(__file__))
+REPO = os.path.dirname(PERF)
+for _p in (REPO, PERF):
+    if _p not in sys.path:
+        sys.path.insert(0, _p)
+
+import layer_reader  # noqa: E402
+
+TRACE_SECONDS = 5.0  # the profiler's share of the window, at its end
+BAD_WRITE2S = 4
+WARM_HEADROOM = 2  # flushes pile up: the largest seen was 1.4 x threads x quorum items
+READY_TIMEOUT_S = 1150.0  # a first run compiles; the contract allows it 1200 s
+FAILED_LATENCY_MS = 1e9  # printed where a percentile falls on a failed operation
+
+
+class RunFailure(Exception):
+    """The run cannot produce a result (no chip, a child died, load failed)."""
+
+
+def say(*parts) -> None:
+    print("[perf]", *parts, flush=True)
+
+
+# ------------------------------------------------------------------ the data
+
+
+def load_cell(root: str, workload: str) -> dict:
+    """Resolve a cell by name: its entry, configuration and traffic mix."""
+    import ycsb
+
+    with open(os.path.join(root, "BENCHMARK.json")) as fh:
+        bench = json.load(fh)
+    cells = {w["name"]: w for w in bench["workloads"]}
+    if workload not in cells:
+        raise RunFailure(f"no workload {workload!r} in BENCHMARK.json (has {sorted(cells)})")
+    cell = cells[workload]
+    config_entry = next(c for c in bench["configs"] if c["name"] == cell["config"])
+    with open(os.path.join(root, config_entry["file"])) as fh:
+        config = json.load(fh)
+    # the data directories lie beside the one that holds the configuration
+    data_dir = os.path.join(root, os.path.dirname(os.path.dirname(config_entry["file"])))
+    traffic = ycsb.load_traffic(os.path.join(data_dir, "traffic", cell["traffic"] + ".json"))
+    return {"bench": bench, "cell": cell, "config": config, "traffic": traffic,
+            "layer_dir": os.path.join(data_dir, "layer_metrics")}
+
+
+def metric_applies(entry: dict, workload: str) -> bool:
+    return "workloads" not in entry or workload in entry["workloads"]
+
+
+def read_layer_metrics(layer_dir: str, bench: dict, workload: str, snap: dict) -> dict:
+    """Each per-layer metric of this cell, from its own reader file.  A metric
+    with no ``workloads`` key belongs to every cell that reports the end-to-end
+    metric it moves.  A reader that finds nothing to read returns None and its
+    metric is left out."""
+    reported = {m["name"] for m in bench["end_to_end"] if metric_applies(m, workload)}
+    out = {}
+    for entry in bench["per_layer"]:
+        if not (workload in entry["workloads"] if "workloads" in entry
+                else entry["moves"] in reported):
+            continue
+        path = os.path.join(layer_dir, entry["name"] + ".py")
+        mod = layer_reader.load(path)
+        for key in ("name", "unit", "layer", "moves", "source"):
+            if getattr(mod, key.upper()) != entry[key]:
+                raise RunFailure(f"{path}: {key.upper()} differs from BENCHMARK.json")
+        value = mod.read(snap)
+        if value is not None and not math.isnan(value):
+            out[entry["name"]] = {"value": value, "unit": entry["unit"]}
+    return out
+
+
+# ------------------------------------------------------------------- the run
+
+
+def gate(rehearse: bool) -> None:
+    pinned = os.environ.get("JAX_PLATFORMS", "").strip().lower()
+    if rehearse and pinned != "cpu":
+        raise RunFailure("--rehearse is the CPU rehearsal: export JAX_PLATFORMS=cpu")
+    if not rehearse and pinned and "tpu" not in pinned.split(","):
+        raise RunFailure(f"JAX_PLATFORMS={pinned}: no TPU for the benchmark "
+                         "(the CPU rehearsal is --rehearse)")
+
+
+def build_native() -> None:
+    from mochi_tpu.native import get_hbatch, get_mcode
+
+    built = {"mcode": get_mcode() is not None, "hbatch": get_hbatch() is not None}
+    if not all(built.values()):
+        raise RunFailure(f"native modules did not build here: {built}")
+
+
+class Workers:
+    """The generator processes: spawn, wait for the load, start, collect."""
+
+    def __init__(self, script: str, out_dir: str):
+        self.script = script
+        self.out_dir = out_dir
+        self.procs = []
+        self.specs = []
+
+    async def spawn(self, specs: list) -> None:
+        env = dict(os.environ, JAX_PLATFORMS="cpu")  # a generator never takes the chip
+        for spec in specs:
+            path = os.path.join(self.out_dir, f"worker-{spec['worker']}.spec.json")
+            with open(path, "w") as fh:
+                json.dump(spec, fh)
+            log = open(os.path.join(self.out_dir, f"worker-{spec['worker']}.log"), "ab")
+            try:
+                proc = await asyncio.create_subprocess_exec(
+                    sys.executable, self.script, path, env=env,
+                    stdin=asyncio.subprocess.PIPE, stdout=asyncio.subprocess.PIPE, stderr=log)
+            finally:
+                log.close()
+            self.procs.append(proc)
+            self.specs.append(spec)
+
+    async def _line(self, i: int, timeout_s: float) -> dict:
+        proc = self.procs[i]
+        line = await asyncio.wait_for(proc.stdout.readline(), timeout_s)
+        if not line:
+            rc = await proc.wait()
+            raise RunFailure(f"generator process {i} ended early (rc={rc}); see its log")
+        return json.loads(line)
+
+    async def lines(self, timeout_s: float) -> list:
+        return await asyncio.gather(*(self._line(i, timeout_s) for i in range(len(self.procs))))
+
+    def go(self, t_start: float, seconds: float) -> None:
+        for proc in self.procs:
+            proc.stdin.write(f"GO {t_start!r} {seconds!r}\n".encode())
+
+    async def read_back(self, records: list) -> dict:
+        """Every record read once, by callers whose connections are warm:
+        {record: (writer, seq, crc, grants, t_issue)}."""
+        paths = []
+        for i, proc in enumerate(self.procs):
+            path = os.path.join(self.out_dir, f"worker-{i}.readback.json")
+            with open(path, "w") as fh:
+                json.dump(records[i::len(self.procs)], fh)
+            proc.stdin.write(f"READBACK {path}\n".encode())
+            paths.append(path + ".out")
+        await self.lines(timeout_s=600.0)
+        out = {}
+        for path in paths:
+            with open(path) as fh:
+                out.update({row[0]: tuple(row[1:]) for row in json.load(fh)})
+        return out
+
+    def results(self) -> list:
+        out = []
+        for spec in self.specs:
+            with open(spec["result_path"]) as fh:
+                out.append(json.load(fh))
+        return out
+
+    async def close(self) -> None:
+        for proc in self.procs:
+            if proc.returncode is None:
+                try:
+                    proc.stdin.close()
+                except (OSError, RuntimeError):
+                    pass
+        for proc in self.procs:
+            try:
+                await asyncio.wait_for(proc.wait(), 20.0)
+            except asyncio.TimeoutError:
+                proc.kill()
+                await proc.wait()
+
+
+async def programs_ready(pc, ctl_dir: str, sizes: list, quiet_s: float = 45.0,
+                         timeout_s: float = 1000.0) -> str:
+    """Wait until the service has a program of each kind for every offered
+    size: its ``/status`` lists, for the ladder and for the comb, a ready
+    bucket in [size, 2 x size).  Should the product stop naming its buckets
+    that way, fall back to quiet: no program built for ``quiet_s`` seconds
+    and the idle service under a quarter of a core."""
+    import cluster as cl
+    from service_launch import request
+
+    if not sizes:
+        return "not needed"
+    deadline = time.monotonic() + timeout_s
+    built, changed = -1, time.monotonic()
+    cpu = pc.cpu_seconds().get("verifier-service", 0.0)
+    while time.monotonic() < deadline:
+        c = cl.service_counters(pc.service_status())
+        if all(any(n <= b < 2 * n for b in ready) for n in sizes
+               for ready in (c["ready_buckets"], c["comb_ready_buckets"])):
+            return f"ready (ladder {c['ready_buckets']}, comb {c['comb_ready_buckets']})"
+        now_built = request(ctl_dir, {"op": "stats"})["programs_built"]
+        if now_built != built:
+            built, changed = now_built, time.monotonic()
+        await asyncio.sleep(1.0)
+        now_cpu = pc.cpu_seconds().get("verifier-service", 0.0)
+        if time.monotonic() - changed > quiet_s and now_cpu - cpu < 0.25:
+            return "presumed built (the service went quiet)"
+        cpu = now_cpu
+    raise RunFailure(f"the service was still building programs {timeout_s}s after the load")
+
+
+def replica_processes(config: dict) -> int:
+    want = config["replica_processes"]
+    if want == "cores-2":
+        want = max(1, (os.cpu_count() or 1) - 2)
+    return max(1, min(int(want), config["replicas"]))
+
+
+async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
+    """Everything between process start and the result.  ``launcher`` and
+    ``worker_script`` are the service launcher and the generator worker; the
+    tests under ``perf/tests`` put broken ones in their place."""
+    import cluster as cl
+    import probe
+    import reference as ref
+    import ycsb
+    from service_launch import request
+
+    cell, config, traffic = data["cell"], data["config"], data["traffic"]
+    rehearse = args.rehearse
+    shape = dict(config, **config["rehearsal"]) if rehearse else config
+    n, rf, records = shape["replicas"], shape["rf"], shape["recordcount"]
+    threads = shape["threads"]
+    gen_procs = min(shape["generator_processes"], threads)
+    seed, seconds = args.seed, float(args.seconds)
+
+    out_dir = os.path.join(PERF, "out", f"{cell['name']}-{seed}")
+    shutil.rmtree(out_dir, ignore_errors=True)
+    ctl_dir = os.path.join(out_dir, "ctl")
+    os.makedirs(ctl_dir)
+    removed = [k for k in os.environ if k.startswith("MOCHI_")]
+    for k in removed:
+        del os.environ[k]  # the product as shipped: nothing tuned from outside
+    if removed:
+        say("dropped from the environment:", sorted(removed))
+    uds = len(tempfile.gettempdir()) < 60  # AF_UNIX paths hold ~100 characters
+    say(f"cell {cell['name']}: n={n} rf={rf} records={records} threads={threads} "
+        f"generator_processes={gen_procs} transport={'uds' if uds else 'tcp'} "
+        f"seed={seed} seconds={seconds} trace={args.trace} rehearse={rehearse}")
+
+    pc = cl.PerfCluster(
+        n_servers=n, rf=rf, n_processes=replica_processes(shape),
+        uds=uds, verifier="service", service_backend="tpu",
+        admin_base_port=cl.ADMIN_BASE_PORT, storage_dir=os.path.join(out_dir, "storage"),
+        seed=seed, ready_timeout_s=READY_TIMEOUT_S,
+        # every program the service builds is cached, however quick its
+        # compile, so "the window added no cache entry" is exact
+        env={"JAX_PERSISTENT_CACHE_MIN_COMPILE_TIME_SECS": "0"},
+        launcher=launcher, ctl_dir=ctl_dir,
+        service_argv=["--warmup", "16"] if rehearse else [],
+    )
+    workers = Workers(worker_script, out_dir)
+    result: dict = {}
+    try:
+        t0 = time.monotonic()
+        await pc.start()
+        say(f"cluster READY in {time.monotonic() - t0:.1f}s "
+            f"({pc.n_processes} replica processes, 1 service)")
+        status = pc.service_status()
+        dev = status.get("device")
+        if dev is None:
+            raise RunFailure("the service reports no device")
+        want_platform = "cpu" if rehearse else "tpu"
+        if dev["platform"] != want_platform:
+            raise RunFailure(f"the service runs on {dev['platform']!r}, not {want_platform!r}")
+        quorum = pc.config.quorum
+        if quorum != shape["quorum"] or pc.config.f != shape["f"]:
+            raise RunFailure(f"cluster has f={pc.config.f} quorum={quorum}, the "
+                             f"configuration states f={shape['f']} quorum={shape['quorum']}")
+        cache_dir = dev["compile_cache_dir"]
+        say(f"service warm-up {dev['warmup_seconds']}s on {dev['platform']} "
+            f"{dev['device_kind']!r} x{dev['n_devices']}, cache {cache_dir}")
+        request(ctl_dir, {"op": "stats"})  # starts the count of programs built
+
+        # ---- warm every shape the window can use.  The service builds a
+        # program lazily, in a background thread, for the first batch of a
+        # size it has none for, and a build inside the window halves the
+        # cell's rate.  Where this cell's traffic can reach the routing's
+        # crossover at all (every caller's certificate in flight at once,
+        # doubled for flushes that pile up behind a slow one), no size up to
+        # the largest ready bucket is safe: flushes of over 2,048 items were
+        # seen on the chip.  Offer all of them now, so that the building
+        # overlaps the load and is over before the window.
+        counters = cl.service_counters(status)
+        both_ready = set(counters["ready_buckets"]) & set(counters["comb_ready_buckets"])
+        warmed = {"sizes": [], "mismatches": 0}
+        if both_ready and WARM_HEADROOM * threads * quorum >= counters["min_device_items"] > 0:
+            warmed = await probe.warm_device_buckets(
+                pc, seed, counters["min_device_items"], max(both_ready))
+            say(f"offered warm-up batches of {warmed['sizes']} items")
+
+        # ---- load, by the generator processes
+        callers = list(range(threads))
+        t0 = time.monotonic()
+        await workers.spawn([
+            {
+                "repo": REPO, "worker": w, "seed": seed, "records": records,
+                "cluster_config": pc.cluster_config_path(), "traffic": traffic,
+                "callers": callers[w::gen_procs],
+                "load_callers": max(1, shape["load_threads"] // gen_procs),
+                "load_records": list(range(records))[w::gen_procs],
+                "result_path": os.path.join(out_dir, f"worker-{w}.result.json"),
+            }
+            for w in range(gen_procs)
+        ])
+        loaded = await workers.lines(timeout_s=900.0)
+        n_load_failed = sum(l["n_load_failed"] for l in loaded)
+        say(f"loaded {sum(l['loaded'] for l in loaded)} records in "
+            f"{time.monotonic() - t0:.1f}s, {n_load_failed} failed")
+        if n_load_failed:
+            raise RunFailure(f"load failed: {[l['load_failed'] for l in loaded]}")
+
+        # ---- the window
+        def snapshot() -> dict:
+            cpu = pc.cpu_seconds()
+            return {
+                "service": cl.service_counters(pc.service_status()),
+                "replicas": cl.replica_counters(pc.replica_statuses()),
+                "replica_cpu": sum(v for k, v in cpu.items() if k.startswith("proc-")),
+                "service_cpu": cpu.get("verifier-service", 0.0),
+                "cache_entries": cl.cache_entries(cache_dir),
+                "stats": request(ctl_dir, {"op": "stats"}),
+            }
+
+        t0 = time.monotonic()
+        how = await programs_ready(pc, ctl_dir, warmed["sizes"])
+        say(f"programs for the offered sizes {how} after {time.monotonic() - t0:.1f}s more")
+        before = snapshot()
+        t_start = time.monotonic() + 1.0
+        t_end = t_start + seconds
+        workers.go(t_start, seconds)
+        setup_s = t_start - T_PROCESS_START
+        traces = {}
+        trace_len = min(TRACE_SECONDS, seconds / 2)
+        if args.trace:
+            await asyncio.sleep(max(0.0, t_end - trace_len - time.monotonic()))
+            t_a = request(ctl_dir, {"op": "trace_start", "dir": os.path.join(out_dir, "trace-window")})
+        await asyncio.sleep(max(0.0, t_end - time.monotonic()))
+        if args.trace:
+            t_b = request(ctl_dir, {"op": "trace_stop"}, timeout_s=300.0)
+            traces["window"] = {"dir": os.path.join(out_dir, "trace-window"),
+                                "seconds": t_b["stopped_monotonic"] - t_a["started_monotonic"]}
+        done = await workers.lines(timeout_s=ycsb.SDK_TIMEOUT_S * 3)
+        after = snapshot()
+        gen = workers.results()
+        ops = [op for g in gen for op in g["ops"]]
+        say(f"window closed: {sum(d['done'] for d in done)} operations recorded")
+        for g in gen:
+            if g["errors"]:
+                say(f"generator {g['worker']} failed operations:", json.dumps(g["errors"]))
+            if g.get("retried"):
+                say(f"generator {g['worker']} attempts made again:", json.dumps(g["retried"]))
+
+        # ---- after the window: read back, probe the device, send bad Write2s
+        pool = ycsb.value_pool(seed)
+        hist = ref.build_histories(
+            ops, ycsb.LOAD_WRITER, lambda w, s: zlib.crc32(ycsb.make_value(pool, w, s)))
+        t0 = time.monotonic()
+        readback = await workers.read_back(sorted(hist))
+        await workers.close()
+        say(f"read back {len(readback)} of {len(hist)} touched records in {time.monotonic() - t0:.1f}s")
+
+        ready = set(before["service"]["ready_buckets"]) & set(before["service"]["comb_ready_buckets"])
+        routed = [b for b in ready if b >= before["service"]["min_device_items"]]
+        probe_size = min(routed) if routed else min(ready)
+        idle0 = cl.service_counters(pc.service_status())
+        if args.trace:
+            t_a = request(ctl_dir, {"op": "trace_start", "dir": os.path.join(out_dir, "trace-probe")})
+        dprobe = await probe.device_probe(pc, seed, probe_size)
+        if args.trace:
+            t_b = request(ctl_dir, {"op": "trace_stop"}, timeout_s=300.0)
+            traces["probe"] = {"dir": os.path.join(out_dir, "trace-probe"),
+                               "seconds": t_b["stopped_monotonic"] - t_a["started_monotonic"]}
+        idle1 = cl.service_counters(pc.service_status())
+        say(f"device probe: {json.dumps(dprobe)}")
+
+        touched_keys = sorted(ycsb.key_name(rec) for rec in hist)
+        bad = await probe.bad_write2_probe(pc, seed, touched_keys, BAD_WRITE2S)
+        say(f"bad Write2 probe: {json.dumps(bad)}")
+
+        final = snapshot()
+        errors = cl.log_errors(pc.log_paths())
+        if errors:
+            say("ERROR records in the children's logs:", json.dumps(errors))
+        if args.keep:
+            os.makedirs(os.path.join(out_dir, "logs"), exist_ok=True)
+            for path in pc.log_paths():
+                shutil.copy(path, os.path.join(out_dir, "logs"))
+
+        # ---- the numbers compared, each beside its limit
+        checks = []
+        checks += ref.check_window(ops, hist, quorum)
+        checks += ref.check_readback(readback, hist, quorum)
+        checks += ref.check_probe(bad)
+        C = ref.Check
+        checks += [
+            C("operations_recorded", len(ops), 1, at_least=True),
+            C("device_probe_mismatches", dprobe["mismatches"] + warmed["mismatches"], 0),
+            C("service_fallback_batches", final["service"]["fallback_batches"], 0),
+            C("replica_fallback_batches", final["replicas"]["fallback_batches"], 0),
+            C("failed_buckets", len(final["service"]["failed_buckets"])
+              + len(final["service"]["comb_failed_buckets"]), 0),
+            C("replicas_answering_status", final["replicas"]["replicas"], n, at_least=True),
+            C("processes_with_jax_loaded_besides_the_service",
+              final["replicas"]["jax_loaded"] + sum(1 for g in gen if g["jax_loaded"])
+              + ("jax" in sys.modules), 0),
+            C("programs_built_in_window",
+              after["stats"]["programs_built"] - before["stats"]["programs_built"], 0),
+            C("cache_entries_gained_in_window",
+              after["cache_entries"] - before["cache_entries"], 0),
+        ]
+        if not rehearse:
+            checks += [
+                C("device_probe_items_on_device",
+                  idle1["device_items"] - idle0["device_items"], dprobe["items"], at_least=True),
+                C("programs_built_by_device_probe",
+                  final["stats"]["programs_built"] - after["stats"]["programs_built"], 0),
+            ]
+        for c in checks:
+            say(c.line())
+
+        summary = ref.summarize(ops, seconds, t_end)
+        lat = summary.pop("latency_ms")
+        for kind, name in ((ref.READ, "read"), (ref.UPDATE, "update")):
+            if lat[kind]:
+                say(f"{name}: n={len(lat[kind])} p50={ref.percentile(lat[kind], 50):.3f}ms "
+                    f"p95={ref.percentile(lat[kind], 95):.3f}ms p99={ref.percentile(lat[kind], 99):.3f}ms")
+        late = max(g["busy_until"] for g in gen) - t_end
+        say(f"ops_s={summary['ops_s']:.3f} attempted={summary['attempted']} "
+            f"failed={summary['failed']} drain_after_window={late:.3f}s setup_s={setup_s:.1f} "
+            f"run so far {time.monotonic() - T_PROCESS_START:.1f}s")
+
+        stats = final["stats"]
+        result = {
+            "correct": all(c.ok for c in checks),
+            "attempted": summary["attempted"],
+            "failed": summary["failed"],
+            "device": {
+                "platform": stats["platform"], "kind": stats["kind"], "count": stats["count"],
+                "memory_peak_bytes": max(
+                    (m.get("peak_bytes_in_use", 0) for m in stats["memory"]), default=0),
+            },
+        }
+
+        def pct(values, q):
+            v = ref.percentile(values, q)
+            return FAILED_LATENCY_MS if math.isinf(v) else v
+
+        e2e = {"ops_s": summary["ops_s"], "setup_s": setup_s}
+        if lat[ref.UPDATE]:
+            e2e["update_p95_ms"] = pct(lat[ref.UPDATE], 95)
+        if lat[ref.READ]:
+            e2e["read_p95_ms"] = pct(lat[ref.READ], 95)
+        bench = data["bench"]
+        if not args.trace:
+            result["metrics"] = {
+                m["name"]: {"value": e2e[m["name"]], "unit": m["unit"]}
+                for m in bench["end_to_end"]
+                if metric_applies(m, cell["name"]) and m["name"] in e2e
+            }
+        else:
+            import xplane
+
+            reduced = {k: xplane.reduce_dir(t["dir"], t["seconds"]) for k, t in traces.items()}
+            for k, r in reduced.items():
+                say(f"trace {k}: {r['window_s']:.3f}s traced, device busy {r['busy_s']:.6f}s, "
+                    f"{r['launches']} launches, programs {json.dumps(r['programs'])}")
+            updates_ok = sum(1 for op in ops if op[ref.KIND] == ref.UPDATE and op[ref.OK])
+            snap = {
+                "platform": stats["platform"], "window_s": seconds, "ops_ok": summary["attempted"] - summary["failed"],
+                "updates_ok": updates_ok, "before": before, "after": after,
+                "latency": {k: v for k, v in e2e.items() if k.endswith("_ms")},
+                "generator": {
+                    "processes": len(gen),
+                    "cpu_seconds": sum(g["cpu_seconds"] for g in gen),
+                    "stage_seconds": {
+                        name: [s for g in gen for s in g["stage_seconds"].get(name, [])]
+                        for name in ycsb.STAGE_TIMERS
+                    },
+                },
+                "trace": reduced,
+            }
+            result["metrics"] = read_layer_metrics(data["layer_dir"], bench, cell["name"], snap)
+            if not rehearse:
+                result["device"]["busy_s"] = sum(r["busy_s"] for r in reduced.values())
+                result["device"]["window_s"] = sum(r["window_s"] for r in reduced.values())
+                result["breakdown"] = xplane.breakdown(list(reduced.values()))
+            if args.keep:
+                with open(os.path.join(out_dir, "snapshot.json"), "w") as fh:
+                    json.dump(snap, fh)
+        if rehearse:
+            result["device"].pop("memory_peak_bytes")
+            result["rehearsal"] = True
+    finally:
+        await workers.close()
+        await pc.close()
+        if args.keep:
+            say("kept", out_dir)
+        else:
+            shutil.rmtree(out_dir, ignore_errors=True)
+    return result
+
+
+def main(argv=None, launcher=None, worker_script=None) -> int:
+    parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
+    parser.add_argument("--workload", required=True)
+    parser.add_argument("--seed", type=int, required=True)
+    parser.add_argument("--seconds", type=float, required=True)
+    parser.add_argument("--trace", type=int, choices=(0, 1), default=0)
+    parser.add_argument("--rehearse", action="store_true",
+                        help="CPU rehearsal at a tiny shape (needs JAX_PLATFORMS=cpu)")
+    parser.add_argument("--keep", action="store_true",
+                        help="keep logs, traces and storage under perf/out/")
+    parser.add_argument("--root", default=REPO,
+                        help="where BENCHMARK.json and its data files are read from")
+    args = parser.parse_args(argv)
+    try:
+        gate(args.rehearse)
+        data = load_cell(args.root, args.workload)
+        build_native()
+        result = asyncio.run(run_cell(
+            args, data,
+            launcher or os.path.join(PERF, "service_launch.py"),
+            worker_script or os.path.join(PERF, "ycsb.py"),
+        ))
+    except RunFailure as exc:
+        print(f"[perf] no result: {exc}", file=sys.stderr)
+        return 3
+    print(json.dumps(result), flush=True)
+    return 0
+
+
+if __name__ == "__main__":
+    sys.exit(main())

@@ -1,0 +1,85 @@
+"""The harness's own waiting and sizing logic, without a cluster."""
+
+import asyncio
+
+import pytest
+
+import probe
+import run
+
+
+def status(ladder, comb, min_device=384):
+    return {"requests": 0, "items": 0, "verifier": {"hits": 0, "misses": 0, "inner": {
+        "batches_flushed": 0, "fallback_batches": 0,
+        "device": {"device_items": 0, "host_routed_items": 0, "min_device_items": min_device,
+                   "ready_buckets": ladder, "failed_buckets": [], "comb_failed_buckets": []},
+        "comb": {"ready_buckets": comb, "registered_signers": 64}}}}
+
+
+class FakeCluster:
+    def __init__(self, statuses):
+        self.statuses = list(statuses)
+        self.asked = 0
+
+    def service_status(self):
+        self.asked += 1
+        return self.statuses.pop(0) if len(self.statuses) > 1 else self.statuses[0]
+
+    def cpu_seconds(self):
+        return {"verifier-service": 0.0}
+
+
+@pytest.fixture
+def no_control(monkeypatch):
+    import service_launch
+
+    monkeypatch.setattr(service_launch, "request", lambda ctl, req, **kw: {"programs_built": 7})
+
+
+def test_programs_ready_waits_for_both_programs_of_every_offered_size(no_control, monkeypatch):
+    monkeypatch.setattr(asyncio, "sleep", lambda s: _nothing())
+    pc = FakeCluster([
+        status([512, 8192], [512, 8192]),
+        status([512, 8192], [512, 1024, 8192]),            # the comb came first
+        status([512, 1024, 8192], [512, 1024, 8192]),
+        status([512, 1024, 2048, 8192], [512, 1024, 2048, 8192]),
+    ])
+    how = asyncio.run(run.programs_ready(pc, "ctl", [384, 600, 937, 1376]))
+    assert how.startswith("ready") and pc.asked == 4
+
+
+async def _nothing():
+    return None
+
+
+def test_programs_ready_needs_nothing_where_nothing_was_offered(no_control):
+    assert asyncio.run(run.programs_ready(FakeCluster([status([], [])]), "ctl", [])) == "not needed"
+
+
+def test_programs_ready_falls_back_to_quiet(no_control, monkeypatch):
+    monkeypatch.setattr(asyncio, "sleep", lambda s: _nothing())
+    pc = FakeCluster([status([512, 8192], [512, 8192])])   # never lists a bucket for 600
+    how = asyncio.run(run.programs_ready(pc, "ctl", [600], quiet_s=0.0))
+    assert how.startswith("presumed")
+
+
+def test_warm_sizes_cover_every_doubling_between_the_ends(monkeypatch):
+    sent = []
+
+    async def fake_mismatches(rv, signers, label, size):
+        sent.append(size)
+        return 0
+
+    class RV:
+        async def close(self):
+            pass
+
+    class PC:
+        keypairs = {}
+
+    monkeypatch.setattr(probe, "_verdict_mismatches", fake_mismatches)
+    monkeypatch.setattr(probe, "_no_fallback_verifier", lambda pc: RV())
+    got = asyncio.run(probe.warm_device_buckets(PC(), 1, 384, 1376))
+    assert got == {"sizes": sent, "mismatches": 0}
+    assert sent[0] == 384 and sent[-1] == 1376 and sent == sorted(sent)
+    assert all(b / a <= probe.WARM_STEP + 0.01 for a, b in zip(sent, sent[1:]))
