@@ -522,6 +522,12 @@ class HttpJsonServer:
     def _route(self, path: str):
         raise NotImplementedError
 
+    async def _route_target(self, target: str):
+        """The whole request target (path and query), awaited: a surface
+        with a route that reads its query or takes time overrides this; the
+        rest route synchronously on the path alone."""
+        return self._route(target.split("?")[0])
+
     async def _serve(self, reader: asyncio.StreamReader, writer: asyncio.StreamWriter):
         try:
             request_line = await asyncio.wait_for(reader.readline(), 10.0)
@@ -534,9 +540,10 @@ class HttpJsonServer:
                     line = await asyncio.wait_for(reader.readline(), 10.0)
                     if line in (b"\r\n", b"\n", b""):
                         break
-                status, ctype, body = self._route(parts[1].split("?")[0])
+                status, ctype, body = await self._route_target(parts[1])
             payload = body.encode()
-            reason = {200: "OK", 404: "Not Found", 405: "Method Not Allowed"}[status]
+            reason = {200: "OK", 400: "Bad Request", 404: "Not Found",
+                      405: "Method Not Allowed", 409: "Conflict"}[status]
             writer.write(
                 f"HTTP/1.1 {status} {reason}\r\n"
                 f"Content-Type: {ctype}\r\n"

@@ -38,6 +38,7 @@ serial verification, per-item bitmaps, no degradation under attack.
 
 from __future__ import annotations
 
+import contextlib
 import hashlib
 import logging
 import os
@@ -54,6 +55,9 @@ import jax.numpy as jnp
 
 from . import curve
 from . import field as F
+from ..obs import hostspan
+from ..utils.metrics import Metrics
+from ..verifier import stages
 from ..verifier.spi import VerifyItem
 
 LOG = logging.getLogger(__name__)
@@ -272,8 +276,15 @@ def prepare_packed(items: Sequence[VerifyItem]):
     return y_a, sign_a, y_r, sign_r, s_bytes, h_bytes, pre_ok
 
 
+# The general ladder's device program, under the name a profiler trace and
+# the compile cache know it by.  The name is pinned here, not left to follow
+# whatever the traced function happens to be called.
+LADDER_PROGRAM = "jit_verify_prepared_packed"
+
 _verify_jit = jax.jit(curve.verify_prepared)
-_verify_packed_jit = jax.jit(curve.verify_prepared_packed)
+_verify_packed_jit = jax.jit(
+    curve.named_program(curve.verify_prepared_packed, LADDER_PROGRAM)
+)
 
 
 def verify_batch(
@@ -368,11 +379,14 @@ def verify_batch(
         window: deque = deque()
         out: List[bool] = []
         chunks = [items[i : i + MAX_BUCKET] for i in range(0, len(items), MAX_BUCKET)]
-        prep_fut = _prep_pool().submit(_prepare_padded, chunks[0], None)
+        metrics = _stage_metrics()  # the prepare worker serves this thread's call
+        prep_fut = _prep_pool().submit(_prepare_padded, chunks[0], None, metrics)
         for k, chunk in enumerate(chunks):
             prepared = prep_fut.result()
             if k + 1 < len(chunks):
-                prep_fut = _prep_pool().submit(_prepare_padded, chunks[k + 1], None)
+                prep_fut = _prep_pool().submit(
+                    _prepare_padded, chunks[k + 1], None, metrics
+                )
             window.append((_dispatch(prepared, device), len(chunk)))
             if len(window) >= _PIPELINE_DEPTH:
                 out.extend(_readback(*window.popleft()))
@@ -400,18 +414,42 @@ def _prep_pool() -> ThreadPoolExecutor:
     return _PREP_POOL
 
 
+# Stage timers of a launch (verifier/stages.py) tick in the registry of the
+# backend call the thread is serving: JaxBatchBackend names its registry here
+# before it calls down, and a bare verify_batch() ticks in the module's own.
+_MODULE_METRICS = Metrics()
+_STAGE_LOCK = threading.Lock()  # several flush threads share one registry
+
+
+def _stage_metrics() -> Metrics:
+    return getattr(_tls, "metrics", None) or _MODULE_METRICS
+
+
+def _stage(timer: str, span: str, metrics: Optional[Metrics] = None) -> stages.stage:
+    return stages.stage(metrics or _stage_metrics(), timer, span, lock=_STAGE_LOCK)
+
+
 def _readback(launched, n: int) -> List[bool]:
     """Block on one launched chunk and combine with its host prechecks."""
     bitmap_dev, pre_ok = launched
     if bitmap_dev is None:  # all-rejected chunk: no device work was done
         return [False] * n
-    bitmap = np.asarray(bitmap_dev)[:n]
+    with _stage(stages.READBACK, stages.SPAN_READBACK):
+        bitmap = np.asarray(bitmap_dev)[:n]
     return [bool(b) for b in np.logical_and(bitmap, pre_ok)]
 
 
-def _prepare_padded(items: Sequence[VerifyItem], bucket: Optional[int]):
+def _prepare_padded(
+    items: Sequence[VerifyItem], bucket: Optional[int], metrics: Optional[Metrics] = None
+):
     """Host half of a launch: pack + pad one chunk (pure numpy/hashlib —
-    safe on the prepare worker thread, no JAX calls)."""
+    safe on the prepare worker thread, no JAX calls; ``metrics`` is the
+    submitting thread's registry when this runs on the worker)."""
+    with _stage(stages.PREPARE, stages.SPAN_PREPARE, metrics):
+        return _pack_padded(items, bucket)
+
+
+def _pack_padded(items: Sequence[VerifyItem], bucket: Optional[int]):
     use_pallas = _impl() == "pallas"
     if use_pallas:
         # The (shelved) Pallas kernel consumes the bit-tensor format;
@@ -445,13 +483,14 @@ def _dispatch(prepared, device: Optional[jax.Device] = None):
     if not pre_ok.any():
         return None, pre_ok
     _note_dispatch()
-    if device is not None:
-        args = tuple(jax.device_put(a, device) for a in args)
-    if use_pallas:
-        from . import pallas_verify
+    with _stage(stages.DISPATCH, stages.SPAN_DISPATCH):
+        if device is not None:
+            args = tuple(jax.device_put(a, device) for a in args)
+        if use_pallas:
+            from . import pallas_verify
 
-        return pallas_verify.verify_prepared_pallas(*args), pre_ok
-    return _verify_packed_jit(*args), pre_ok
+            return pallas_verify.verify_prepared_pallas(*args), pre_ok
+        return _verify_packed_jit(*args), pre_ok
 
 
 # Monotone count of real device dispatches.  JaxBatchBackend uses it to
@@ -571,8 +610,11 @@ class JaxBatchBackend:
         min_device_items: Optional[int] = None,
         verify_fn=None,
         registry=None,
+        metrics: Optional[Metrics] = None,
     ):
         self.device = device
+        # stage timers (verifier/stages.py); the service hands in its own
+        self.metrics = metrics if metrics is not None else Metrics()
         # Known-signer comb registry (crypto/comb.py); None = ladder only.
         self.registry = registry
         # Hook for alternative device paths (the mesh-sharded backend in
@@ -612,6 +654,12 @@ class JaxBatchBackend:
         # crossover accounting: items each side of min_device_items served
         self.host_routed_items = 0
         self.device_items = 0
+        # program builds under way, (bucket, program) -> monotonic start, and
+        # how many began and how many ended well: a build inside served
+        # traffic holds the interpreter, and only these fields say so
+        self._building: dict = {}
+        self.builds_started = 0
+        self.builds_finished = 0
         self._lock = threading.Lock()
         self._registry_mutex = threading.Lock()
 
@@ -688,6 +736,47 @@ class JaxBatchBackend:
         st.update(device_info())
         return st
 
+    def build_state(self) -> dict:
+        """Builds under way and the counts of those begun and ended well
+        (begun - ended well - under way = failed; ``stats`` names those)."""
+        now = time.monotonic()
+        with self._lock:
+            return {
+                "building": [
+                    {"bucket": b, "program": prog, "since_s": round(now - t0, 3)}
+                    for (b, prog), t0 in sorted(self._building.items())
+                ],
+                "builds_started": self.builds_started,
+                "builds_finished": self.builds_finished,
+            }
+
+    @contextlib.contextmanager
+    def _build(self, bucket: int, program: str):
+        """One program build (compile or cache load, plus one run), on the
+        calling thread: listed in ``build_state`` while it runs, timed, and
+        a span on the profiler's clock."""
+        _tls.metrics = self.metrics
+        with self._lock:
+            self._building[(bucket, program)] = time.monotonic()
+            self.builds_started += 1
+        try:
+            with stages.stage(
+                self.metrics, stages.BUILD, stages.SPAN_BUILD, lock=_STAGE_LOCK,
+                bucket=bucket, program=program,
+            ):
+                yield
+            with self._lock:
+                self.builds_finished += 1
+        finally:
+            with self._lock:
+                self._building.pop((bucket, program), None)
+
+    def _build_comb(self, bucket: int) -> None:
+        from .comb import COMB_PROGRAM
+
+        with self._build(bucket, COMB_PROGRAM):
+            self._warm_comb(bucket)
+
     def _call_verify(
         self,
         items,
@@ -740,7 +829,8 @@ class JaxBatchBackend:
             items[-1] = VerifyItem(
                 items[-1].public_key, b"mochi-tpu warmup forged", items[-1].signature
             )
-            verdicts = list(self._call_verify(items))
+            with self._build(bucket, LADDER_PROGRAM):
+                verdicts = list(self._call_verify(items))
             if verdicts != [True] * (bucket - 1) + [False]:
                 raise RuntimeError(
                     f"verify program at bucket {bucket} computed wrong "
@@ -756,7 +846,7 @@ class JaxBatchBackend:
                 and self._comb_capable()
                 and comb_enabled()
             ):
-                self._warm_comb(bucket)
+                self._build_comb(bucket)
             LOG.info(
                 "bucket %d warm: ladder %.1fs, comb %.1fs (compile or cache "
                 "load, plus one run)",
@@ -767,7 +857,8 @@ class JaxBatchBackend:
         def run():
             try:
                 items = _dummy_items(bucket)
-                self._call_verify(items)
+                with self._build(bucket, LADDER_PROGRAM):
+                    self._call_verify(items)
                 with self._lock:
                     self._ready.add(bucket)
                 if (
@@ -776,7 +867,7 @@ class JaxBatchBackend:
                     and self._comb_capable()
                     and comb_enabled()
                 ):
-                    self._warm_comb(bucket)
+                    self._build_comb(bucket)
             except Exception:
                 LOG.exception(
                     "background compile of verify bucket %d failed; "
@@ -805,7 +896,7 @@ class JaxBatchBackend:
 
         def run():
             try:
-                self._warm_comb(bucket)
+                self._build_comb(bucket)
             except Exception:
                 LOG.exception(
                     "comb compile (bucket %d) failed; bucket latched — its "
@@ -829,14 +920,33 @@ class JaxBatchBackend:
                 self.host_routed_items += len(items)
             else:
                 self.device_items += len(items)
-        if to_host:
+        _tls.metrics = self.metrics  # where this thread's launch stages tick
+        bucket = 0 if to_host else _bucket_size(len(items))
+        # one tick and one span per backend call, by route, beside the item
+        # counters above: the two sides of the crossover, timed where it is
+        # decided.  epoch_us ties the profiler's clock to the epoch clock of
+        # the clients' and replicas' obs/trace.py spans.
+        with stages.stage(
+            self.metrics,
+            stages.FLUSH_HOST if to_host else stages.FLUSH_DEVICE,
+            stages.SPAN_FLUSH,
+            lock=_STAGE_LOCK,
+            items=len(items),
+            route="host" if to_host else "device",
+            bucket=bucket,
+            epoch_us=time.time_ns() // 1000,
+        ):
+            if not to_host:
+                return self._serve_on_device(items, bucket)
             from . import keys as _keys
 
-            return [
-                _keys.verify(it.public_key, it.message, it.signature)
-                for it in items
-            ]
-        bucket = _bucket_size(len(items))
+            with hostspan.span(stages.SPAN_HOST_VERIFY):
+                return [
+                    _keys.verify(it.public_key, it.message, it.signature)
+                    for it in items
+                ]
+
+    def _serve_on_device(self, items: Sequence[VerifyItem], bucket: int) -> Sequence[bool]:
         registry_active = self.registry is not None and len(self.registry)
         pinned = self._comb_pinned_gen(bucket)
         with self._lock:

@@ -70,6 +70,7 @@ from jax import lax
 
 from . import curve
 from . import field as F
+from ..verifier import stages
 
 LOG = logging.getLogger(__name__)
 
@@ -345,6 +346,8 @@ import os as _os
 
 COMB_IMPL = _os.environ.get("MOCHI_COMB_IMPL", "chain")
 
+SCOPE_COMB = "mochi_comb"  # the comb loop, beside curve.py's phase scopes
+
 # (p+1)/2: multiplying by it halves a field element (Niels y+x/y-x -> x,y)
 _INV2_INT = (F.P_INT + 1) // 2
 # 1/(2d): recovers t = x*y from the Niels xy2d coordinate
@@ -408,13 +411,28 @@ def verify_comb_prepared(
     arg so A/B runs don't collide in the trace cache.
     """
     impl = COMB_IMPL if impl is None else impl
-    s_dig = curve.digits4_from_bits(curve.unpack_bits(s_bytes).T)
-    h_dig = curve.digits4_from_bits(curve.unpack_bits(h_bytes).T)
-    s_mag, s_neg = curve.recode_signed4(s_dig)
-    h_mag, h_neg = curve.recode_signed4(h_dig)
+    with jax.named_scope(curve.SCOPE_UNPACK):
+        s_dig = curve.digits4_from_bits(curve.unpack_bits(s_bytes).T)
+        h_dig = curve.digits4_from_bits(curve.unpack_bits(h_bytes).T)
+        s_mag, s_neg = curve.recode_signed4(s_dig)
+        h_mag, h_neg = curve.recode_signed4(h_dig)
 
-    r_point, ok_r = curve.decompress(y_r.T, sign_r)
+    with jax.named_scope(curve.SCOPE_DECOMPRESS):
+        r_point, ok_r = curve.decompress(y_r.T, sign_r)
     lanes = y_r.shape[:1]
+    with jax.named_scope(SCOPE_COMB):
+        q = _comb_accumulate(
+            table_flat, key_idx, s_mag, s_neg, h_mag, h_neg, lanes, impl
+        )
+    with jax.named_scope(curve.SCOPE_COMPARE):
+        eq_x = F.eq(q.x, F.mul(r_point.x, q.z))
+        eq_y = F.eq(q.y, F.mul(r_point.y, q.z))
+        return ok_r & eq_x & eq_y
+
+
+def _comb_accumulate(table_flat, key_idx, s_mag, s_neg, h_mag, h_neg, lanes, impl):
+    """Q = [S]B + [h](-A) from the signer and basepoint comb tables: the
+    comb loop (or tree) of :func:`verify_comb_prepared`."""
 
     # One upfront row gather for all 64 windows — (64, B, 51) — instead of
     # 64 small in-loop gathers: XLA schedules a single fused gather and the
@@ -460,16 +478,13 @@ def verify_comb_prepared(
         ).astype(jnp.int32)
         b_flat = b_rows.reshape(N_WINDOWS * B, ROW_WIDTH).T
         bypx, bymx, bxy2d = signed_niels(b_flat, s_neg.reshape(-1))
-        q = _tree_accumulate(
+        return _tree_accumulate(
             jnp.concatenate([aypx, bypx], axis=1),
             jnp.concatenate([aymx, bymx], axis=1),
             jnp.concatenate([axy2d, bxy2d], axis=1),
             2 * N_WINDOWS,
             B,
         )
-        eq_x = F.eq(q.x, F.mul(r_point.x, q.z))
-        eq_y = F.eq(q.y, F.mul(r_point.y, q.z))
-        return ok_r & eq_x & eq_y
 
     h_neg_i = h_neg.astype(jnp.int32)
     s_neg_i = s_neg.astype(jnp.int32)
@@ -503,13 +518,16 @@ def verify_comb_prepared(
     q = lax.fori_loop(
         0, N_WINDOWS, body, tuple(curve.identity(lanes)), unroll=curve.LADDER_UNROLL
     )
-    q = curve.Point(*q)
-    eq_x = F.eq(q.x, F.mul(r_point.x, q.z))
-    eq_y = F.eq(q.y, F.mul(r_point.y, q.z))
-    return ok_r & eq_x & eq_y
+    return curve.Point(*q)
 
 
-_verify_comb_jit = jax.jit(verify_comb_prepared, static_argnames=("impl",))
+# The comb's device program, under the name a profiler trace and the compile
+# cache know it by (pinned here; see batch_verify.LADDER_PROGRAM).
+COMB_PROGRAM = "jit_verify_comb_prepared"
+
+_verify_comb_jit = jax.jit(
+    curve.named_program(verify_comb_prepared, COMB_PROGRAM), static_argnames=("impl",)
+)
 
 
 # --------------------------------------------------------------------------
@@ -519,9 +537,16 @@ _verify_comb_jit = jax.jit(verify_comb_prepared, static_argnames=("impl",))
 # pubkey check).
 
 
-def _prepare_comb(items, key_idx: np.ndarray, bucket: Optional[int]):
+def _prepare_comb(items, key_idx: np.ndarray, bucket: Optional[int], metrics=None):
     """Host half: pack + pad one chunk (numpy/hashlib only — safe on the
-    prepare worker thread)."""
+    prepare worker thread; ``metrics`` as in ``batch_verify._prepare_padded``)."""
+    from . import batch_verify as BV
+
+    with BV._stage(stages.PREPARE, stages.SPAN_PREPARE, metrics):
+        return _pack_comb(items, key_idx, bucket)
+
+
+def _pack_comb(items, key_idx: np.ndarray, bucket: Optional[int]):
     from . import batch_verify as BV
 
     _, _, y_r, sign_r, s_sc, h_sc, pre_ok = BV.prepare_packed(items)
@@ -566,12 +591,13 @@ def _dispatch_comb(prepared, registry: SignerRegistry, device, table=None):
     if not pre_ok.any():
         return None, pre_ok
     BV._note_dispatch(comb=True)
-    if table is None:
-        table = registry.device_table(device)
-    if device is not None:
-        args = tuple(jax.device_put(a, device) for a in args)
-    key_idx, y_r, sign_r, s_sc, h_sc = args
-    return _verify_comb_jit(table, key_idx, y_r, sign_r, s_sc, h_sc), pre_ok
+    with BV._stage(stages.DISPATCH, stages.SPAN_DISPATCH):
+        if table is None:
+            table = registry.device_table(device)
+        if device is not None:
+            args = tuple(jax.device_put(a, device) for a in args)
+        key_idx, y_r, sign_r, s_sc, h_sc = args
+        return _verify_comb_jit(table, key_idx, y_r, sign_r, s_sc, h_sc), pre_ok
 
 
 def verify_stream(
@@ -605,12 +631,13 @@ def verify_stream(
             for i in range(0, len(items), BV.MAX_BUCKET)
         ]
         pool = BV._prep_pool()
-        prep_fut = pool.submit(_prepare_comb, chunks[0][0], chunks[0][1], None)
+        metrics = BV._stage_metrics()
+        prep_fut = pool.submit(_prepare_comb, chunks[0][0], chunks[0][1], None, metrics)
         for k, (chunk, _) in enumerate(chunks):
             prepared = prep_fut.result()
             if k + 1 < len(chunks):
                 nxt = chunks[k + 1]
-                prep_fut = pool.submit(_prepare_comb, nxt[0], nxt[1], None)
+                prep_fut = pool.submit(_prepare_comb, nxt[0], nxt[1], None, metrics)
             window.append(
                 (_dispatch_comb(prepared, registry, device, table), len(chunk))
             )

@@ -29,14 +29,40 @@ preamble); this is the north-star TPU verifier path of BASELINE.json.
 
 from __future__ import annotations
 
+import functools
 from typing import NamedTuple, Tuple
 
 import numpy as np
 
+import jax
 import jax.numpy as jnp
 from jax import lax
 
 from . import field as F
+
+# Phases of the device programs, as ``jax.named_scope`` names: they go into
+# each operation's metadata (a profiler trace shows the path under an op's
+# ``tf_op``/``long_name``), so the loops a trace prints as ``while.33`` say
+# which one they are.  Metadata only: the compiled program, its verdicts and
+# its compile-cache key are what they were.
+SCOPE_UNPACK = "mochi_scalar_unpack"
+SCOPE_DECOMPRESS = "mochi_decompress"
+SCOPE_LADDER = "mochi_ladder"
+SCOPE_COMPARE = "mochi_compare"
+
+
+def named_program(fn, program: str):
+    """``fn`` under the name ``jax.jit`` will give its program: ``program``
+    is ``jit_<name>``, pinned by the caller as a constant instead of
+    following whatever the traced function is called today."""
+    assert program.startswith("jit_"), program
+
+    @functools.wraps(fn)
+    def entry(*args, **kwargs):
+        return fn(*args, **kwargs)
+
+    entry.__name__ = entry.__qualname__ = program[len("jit_"):]
+    return entry
 
 # Mosaic-safe mode (set by the Pallas kernel wrapper): Mosaic TC lowering has
 # no dynamic_slice on values, so the two data-dependent indexing sites in the
@@ -449,12 +475,15 @@ def verify_core(
     # (17, 2B) call to halve the pow_p58 sequential depth — 108.7k vs
     # ~110k sigs/s at batch 8192 depth-8; the doubled lane width during
     # decompress cancels the depth win at the production bucket size.)
-    a_point, ok_a = decompress(y_a, sign_a)
-    r_point, ok_r = decompress(y_r, sign_r)
-    q = double_scalar_mul_windowed(s_dig, h_dig, negate(a_point), b_tab=b_tab)
-    eq_x = F.eq(q.x, F.mul(r_point.x, q.z))
-    eq_y = F.eq(q.y, F.mul(r_point.y, q.z))
-    return ok_a & ok_r & eq_x & eq_y
+    with jax.named_scope(SCOPE_DECOMPRESS):
+        a_point, ok_a = decompress(y_a, sign_a)
+        r_point, ok_r = decompress(y_r, sign_r)
+    with jax.named_scope(SCOPE_LADDER):
+        q = double_scalar_mul_windowed(s_dig, h_dig, negate(a_point), b_tab=b_tab)
+    with jax.named_scope(SCOPE_COMPARE):
+        eq_x = F.eq(q.x, F.mul(r_point.x, q.z))
+        eq_y = F.eq(q.y, F.mul(r_point.y, q.z))
+        return ok_a & ok_r & eq_x & eq_y
 
 
 def verify_prepared_packed(
@@ -469,9 +498,9 @@ def verify_prepared_packed(
     little-endian BYTES and are bit-unpacked on device — 32x less
     host->device transfer per scalar (the (B, 256) int32 bit tensors are
     ~8 MB per 8192-chunk each; the byte forms are 256 KB)."""
-    return verify_prepared(
-        y_a, sign_a, y_r, sign_r, unpack_bits(s_bytes), unpack_bits(h_bytes)
-    )
+    with jax.named_scope(SCOPE_UNPACK):
+        s_bits, h_bits = unpack_bits(s_bytes), unpack_bits(h_bytes)
+    return verify_prepared(y_a, sign_a, y_r, sign_r, s_bits, h_bits)
 
 
 def unpack_bits(b: jnp.ndarray) -> jnp.ndarray:
@@ -500,6 +529,7 @@ def verify_prepared(
     (:mod:`mochi_tpu.crypto.batch_verify`).  Internally transposes to the
     limbs-leading layout (one fused transpose each way in XLA).
     """
-    s_dig = digits4_from_bits(s_bits.T)
-    h_dig = digits4_from_bits(h_bits.T)
+    with jax.named_scope(SCOPE_UNPACK):
+        s_dig = digits4_from_bits(s_bits.T)
+        h_dig = digits4_from_bits(h_bits.T)
     return verify_core(y_a.T, sign_a, y_r.T, sign_r, s_dig, h_dig)

@@ -46,6 +46,12 @@ from ..crypto import curve
 
 BATCH_AXIS = "batch"
 
+# The mesh-sharded twins of batch_verify.LADDER_PROGRAM and comb.COMB_PROGRAM
+# (the production multi-chip path), under names of their own: both used to be
+# ``jit_verify``, which a trace could not tell apart.
+SHARDED_LADDER_PROGRAM = "jit_verify_prepared_packed_sharded"
+SHARDED_COMB_PROGRAM = "jit_verify_comb_prepared_sharded"
+
 
 def make_mesh(n_devices: Optional[int] = None, devices: Optional[Sequence] = None) -> Mesh:
     """1-D device mesh over the batch axis.
@@ -118,7 +124,6 @@ def make_sharded_verify_packed(mesh: Mesh):
     spec = P(BATCH_AXIS)
     sharding = NamedSharding(mesh, spec)
 
-    @partial(jax.jit, out_shardings=sharding)
     def verify(y_a, sign_a, y_r, sign_r, s_bytes, h_bytes):
         f = shard_map(
             curve.verify_prepared_packed,
@@ -129,7 +134,9 @@ def make_sharded_verify_packed(mesh: Mesh):
         )
         return f(y_a, sign_a, y_r, sign_r, s_bytes, h_bytes)
 
-    return verify
+    return jax.jit(
+        curve.named_program(verify, SHARDED_LADDER_PROGRAM), out_shardings=sharding
+    )
 
 
 def make_sharded_verify_comb(mesh: Mesh):
@@ -145,7 +152,6 @@ def make_sharded_verify_comb(mesh: Mesh):
     rep = P()
     sharding = NamedSharding(mesh, spec)
 
-    @partial(jax.jit, out_shardings=sharding)
     def verify(table, key_idx, y_r, sign_r, s_bytes, h_bytes):
         f = shard_map(
             comb.verify_comb_prepared,
@@ -156,7 +162,9 @@ def make_sharded_verify_comb(mesh: Mesh):
         )
         return f(table, key_idx, y_r, sign_r, s_bytes, h_bytes)
 
-    return verify
+    return jax.jit(
+        curve.named_program(verify, SHARDED_COMB_PROGRAM), out_shardings=sharding
+    )
 
 
 def make_quorum_step(mesh: Mesh, n_groups: int):

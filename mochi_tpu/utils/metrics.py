@@ -36,12 +36,15 @@ class Timer:
         self.total_count += 1
         self.total_seconds += seconds
 
-    def percentile(self, q: float) -> float:
-        if not self.samples:
+    @staticmethod
+    def _rank(data: list, q: float) -> float:
+        """The q-th percentile of already sorted samples."""
+        if not data:
             return math.nan
-        data = sorted(self.samples)
-        idx = min(len(data) - 1, max(0, int(round(q / 100.0 * (len(data) - 1)))))
-        return data[idx]
+        return data[min(len(data) - 1, max(0, int(round(q / 100.0 * (len(data) - 1)))))]
+
+    def percentile(self, q: float) -> float:
+        return self._rank(sorted(self.samples), q)
 
     @property
     def count(self) -> int:
@@ -52,12 +55,16 @@ class Timer:
         return self.total_seconds / self.total_count if self.total_count else math.nan
 
     def snapshot(self) -> Dict[str, float]:
+        # count and sum_ms are exact lifetime totals: a reader that keeps two
+        # snapshots gets a window as the delta of each
+        data = sorted(self.samples)  # once, not once per percentile
         return {
             "count": self.count,
+            "sum_ms": self.total_seconds * 1e3,
             "mean_ms": self.mean * 1e3,
-            "p50_ms": self.percentile(50) * 1e3,
-            "p95_ms": self.percentile(95) * 1e3,
-            "p99_ms": self.percentile(99) * 1e3,
+            "p50_ms": self._rank(data, 50) * 1e3,
+            "p95_ms": self._rank(data, 95) * 1e3,
+            "p99_ms": self._rank(data, 99) * 1e3,
         }
 
 

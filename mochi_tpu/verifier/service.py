@@ -42,16 +42,22 @@ from __future__ import annotations
 
 import argparse
 import asyncio
+import gc
+import json
 import logging
+import os
 import signal
 import time
+import urllib.parse
 from pathlib import Path
-from typing import List, Optional, Sequence
+from typing import Callable, List, Optional, Sequence
 
 from ..admin.http import HttpJsonServer
 from ..cluster.config import ServerInfo
 from ..crypto import session as session_crypto
 from ..net.transport import RpcServer, _Connection, new_msg_id
+from ..obs import hostspan
+from ..utils.metrics import Metrics
 from ..protocol import (
     Envelope,
     FailType,
@@ -59,6 +65,7 @@ from ..protocol import (
     VerifyBitmapFromServer,
     VerifyRequestToServer,
 )
+from . import stages
 from .spi import (
     BatchingVerifier,
     CachingVerifier,
@@ -71,6 +78,9 @@ from .spi import (
 LOG = logging.getLogger(__name__)
 
 SERVICE_ID = "verifier-service"
+
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
+MAX_PROFILE_SECONDS = 30.0
 
 
 def _seal(env: Envelope, secret: Optional[bytes]) -> Envelope:
@@ -104,33 +114,83 @@ class VerifierService:
         cache: bool = True,
         secret: Optional[bytes] = None,
         device: Optional[dict] = None,
+        metrics: Optional[Metrics] = None,
+        programs_built: Optional[Callable[[], int]] = None,
+        profile_dir: Optional[str] = None,
     ):
         self.secret = secret
         # what the boot path learned about the chip this process owns
         # (platform/device_kind/n_devices, warmup seconds, compile cache
         # dir); None for the CPU backend, which holds no device
         self.device = device
+        # ONE registry of stage timers for everything this service composes
+        # (verifier/stages.py); a verifier built before the service brings
+        # the registry its backend already ticks in
+        if metrics is None:
+            metrics = getattr(verifier, "metrics", None)
+        self.metrics = metrics if metrics is not None else Metrics()
         if verifier is None:
             from .tpu import TpuBatchVerifier
 
-            verifier = TpuBatchVerifier()
+            verifier = TpuBatchVerifier(metrics=self.metrics)
         if cache:
             # Every replica of a set re-checks the same certificate grants;
             # the service-level memo collapses those rf duplicates into one
             # device verification (CachingVerifier docstring).
-            verifier = CachingVerifier(verifier)
+            verifier = CachingVerifier(verifier, metrics=self.metrics)
         self.verifier = verifier
         self.max_items_per_request = max_items_per_request
         self.rpc = RpcServer(host, port, self._handle)
         self.requests = 0
         self.items = 0
+        self._programs_built = programs_built
+        # where /profile writes its captures; None: the route answers 404
+        self.profile_dir = profile_dir
+        self.profiling = False
+        self._gc_started: Optional[float] = None
+        self._gc_span = None
+        self._tick_handle: Optional[asyncio.TimerHandle] = None
 
     async def start(self) -> None:
         await self.rpc.start()
+        gc.callbacks.append(self._on_gc)
+        if hostspan.installed():
+            self._tick()
 
     async def close(self) -> None:
+        if self._tick_handle is not None:
+            self._tick_handle.cancel()
+            self._tick_handle = None
+        if self._on_gc in gc.callbacks:
+            gc.callbacks.remove(self._on_gc)
         await self.rpc.close()
         await self.verifier.close()
+
+    def _on_gc(self, phase: str, info: dict) -> None:
+        """``gc.callbacks``: seconds in the collector, as a timer and as a
+        span on whichever thread it ran (one collection at a time, so one
+        slot holds the pair)."""
+        if phase == "start":
+            self._gc_span = hostspan.span(stages.SPAN_GC, generation=info["generation"])
+            self._gc_span.__enter__()
+            self._gc_started = time.perf_counter()
+        elif self._gc_started is not None:
+            self.metrics.timers[stages.GC].record(time.perf_counter() - self._gc_started)
+            self._gc_started = None
+            self._gc_span.__exit__(None, None, None)
+
+    def _tick(self) -> None:
+        """Once a second on the loop thread while spans are installed: the
+        thread's CPU seconds and the epoch clock, as one instant span, so a
+        profiler capture holds the loop's busy share and the offset between
+        its clock and the one obs/trace.py spans of other processes use."""
+        with hostspan.span(
+            stages.SPAN_TICK,
+            loop_cpu_us=int(time.thread_time() * 1e6),
+            epoch_us=time.time_ns() // 1000,
+        ):
+            pass
+        self._tick_handle = asyncio.get_running_loop().call_later(1.0, self._tick)
 
     @property
     def bound_port(self) -> int:
@@ -140,14 +200,27 @@ class VerifierService:
         """Operational counters for the one process that owns the device
         (served over HTTP via ``--admin-port``; the replica-side analog is
         the admin shell's ``/metrics``)."""
-        return {
+        st = {
             "service_id": SERVICE_ID,
             "requests": self.requests,
             "items": self.items,
             "authenticated": self.secret is not None,
             "device": self.device,
             "verifier": verifier_stats(self.verifier),
+            # stage timers, counters and the flush histogram: exact lifetime
+            # count and sum_ms each, so a window is the delta of two reads
+            "stages": self.metrics.snapshot(),
+            # device programs this process built since boot (JAX's own
+            # backend_compile events: a cache hit still loads, and counts)
+            "programs_built": self._programs_built() if self._programs_built else 0,
+            "building": [],
+            "builds_started": 0,
+            "builds_finished": 0,
         }
+        backend = _device_backend(self.verifier)
+        if backend is not None:
+            st.update(backend.build_state())
+        return st
 
     async def _handle(self, env: Envelope) -> Optional[Envelope]:
         def fail(ft: FailType, detail: str) -> Envelope:
@@ -165,33 +238,97 @@ class VerifierService:
                 self.secret,
             )
 
-        if not isinstance(env.payload, VerifyRequestToServer):
-            return fail(FailType.BAD_REQUEST, "expected VerifyRequestToServer")
-        if self.secret is not None and not (
-            env.mac is not None
-            and session_crypto.mac_ok(self.secret, env.signing_bytes(), env.mac)
+        # Spans cover the synchronous head and tail only: one must not cross
+        # the await (obs/hostspan.py); the awaited stretch is in the timer.
+        started = time.perf_counter()
+        with hostspan.span(stages.SPAN_RPC_ADMIT):
+            if not isinstance(env.payload, VerifyRequestToServer):
+                return fail(FailType.BAD_REQUEST, "expected VerifyRequestToServer")
+            if self.secret is not None and not (
+                env.mac is not None
+                and session_crypto.mac_ok(self.secret, env.signing_bytes(), env.mac)
+            ):
+                return fail(FailType.BAD_SIGNATURE, "verify request MAC missing/invalid")
+            items = env.payload.items
+            if len(items) > self.max_items_per_request:
+                return fail(
+                    FailType.BAD_REQUEST,
+                    f"{len(items)} items > limit {self.max_items_per_request}",
+                )
+            batch = [VerifyItem(pk, msg, sig) for pk, msg, sig in items]
+        bitmap = await self.verifier.verify_batch(batch)
+        with hostspan.span(
+            stages.SPAN_RPC_REPLY, wait_us=int((time.perf_counter() - started) * 1e6)
         ):
-            return fail(FailType.BAD_SIGNATURE, "verify request MAC missing/invalid")
-        items = env.payload.items
-        if len(items) > self.max_items_per_request:
-            return fail(
-                FailType.BAD_REQUEST,
-                f"{len(items)} items > limit {self.max_items_per_request}",
+            self.requests += 1
+            self.items += len(items)
+            reply = _seal(
+                Envelope(
+                    VerifyBitmapFromServer(tuple(bitmap)),
+                    msg_id=new_msg_id(),
+                    sender_id=SERVICE_ID,
+                    reply_to=env.msg_id,
+                ),
+                self.secret,
             )
-        bitmap = await self.verifier.verify_batch(
-            [VerifyItem(pk, msg, sig) for pk, msg, sig in items]
-        )
-        self.requests += 1
-        self.items += len(items)
-        return _seal(
-            Envelope(
-                VerifyBitmapFromServer(tuple(bitmap)),
-                msg_id=new_msg_id(),
-                sender_id=SERVICE_ID,
-                reply_to=env.msg_id,
-            ),
-            self.secret,
-        )
+        self.metrics.timers[stages.SERVICE_RPC].record(time.perf_counter() - started)
+        return reply
+
+    async def capture_profile(self, seconds: float) -> str:
+        """One ``jax.profiler`` trace of ``seconds`` of whatever the service
+        is doing, written under ``profile_dir``; returns its directory.  The
+        options are the benchmark launcher's: host spans and device events,
+        no Python tracer, no HLO protos (tens of megabytes a capture, and
+        nothing reads them)."""
+        import jax
+
+        assert self.profile_dir is not None and not self.profiling
+        self.profiling = True
+        try:
+            out = os.path.join(
+                self.profile_dir, time.strftime("profile-%Y%m%dT%H%M%S", time.gmtime())
+            )
+            options = jax.profiler.ProfileOptions()
+            options.python_tracer_level = 0
+            options.host_tracer_level = 1
+            options.enable_hlo_proto = False
+            # start and stop take tenths of seconds to seconds: off the loop
+            await asyncio.to_thread(
+                jax.profiler.start_trace, out, profiler_options=options
+            )
+            try:
+                await asyncio.sleep(seconds)
+            finally:
+                await asyncio.to_thread(jax.profiler.stop_trace)
+            return out
+        finally:
+            self.profiling = False
+
+
+def _device_backend(verifier):
+    """The device batch backend under a verifier composition (the object
+    with ``build_state``), or None for a composition that holds no device."""
+    while verifier is not None:
+        backend = getattr(verifier, "backend", None)
+        if hasattr(backend, "build_state"):
+            return backend
+        verifier = getattr(verifier, "inner", None)
+    return None
+
+
+def count_programs_built() -> Callable[[], int]:
+    """Count JAX's backend-compile events in this process from now on (call
+    before the first program is built); returns the reader."""
+    import jax.monitoring
+
+    built = [0]
+
+    def on_duration(event: str, duration: float, **kw) -> None:
+        if event == BACKEND_COMPILE_EVENT:
+            built[0] += 1
+
+    jax.monitoring.register_event_duration_secs_listener(on_duration)
+    return lambda: built[0]
 
 
 class RemoteVerifier(SignatureVerifier):
@@ -290,8 +427,14 @@ async def amain(args) -> None:
         )
     verifier: Optional[SignatureVerifier] = None
     device: Optional[dict] = None
+    metrics = Metrics()
+    programs_built = None
+    profile_dir = getattr(args, "profile_dir", None)  # callers build args by hand too
     if args.backend == "cpu":
         verifier = CpuVerifier()
+        if profile_dir is not None:
+            LOG.warning("--profile-dir has no effect with --backend cpu: no device to trace")
+            profile_dir = None
     else:
         from ..utils.runtime import device_info, enable_compile_cache
 
@@ -299,6 +442,12 @@ async def amain(args) -> None:
         # refuses (SystemExit) when JAX found no accelerator and the CPU
         # was not asked for: XLA:CPU never serves under the TPU's name
         device = device_info(require_accelerator=True)
+        # this process holds the device: from here its host spans go on the
+        # profiler's clock, and every program it builds is counted
+        import jax.profiler
+
+        hostspan.install(jax.profiler.TraceAnnotation)
+        programs_built = count_programs_built()
         from . import tpu
 
         verifier_cls = (
@@ -309,6 +458,7 @@ async def amain(args) -> None:
         verifier = verifier_cls(
             warmup_buckets=tuple(int(b) for b in args.warmup.split(",") if b),
             signers=signers,
+            metrics=metrics,
         )
         device["warmup_seconds"] = round(time.time() - t0, 1)
         device["compile_cache_dir"] = cache_dir
@@ -324,7 +474,8 @@ async def amain(args) -> None:
         secret = load_secret(args.secret_file)
     service = VerifierService(
         host=args.host, port=args.port, verifier=verifier, secret=secret,
-        device=device,
+        device=device, metrics=metrics, programs_built=programs_built,
+        profile_dir=profile_dir,
     )
     await service.start()
     admin = None
@@ -361,14 +512,22 @@ class ServiceAdminServer(HttpJsonServer):
         super().__init__(host, port)
         self.service = service
 
-    def _route(self, path: str):
-        import json as _json
+    def _status(self) -> dict:
+        st = self.service.status()
+        # this handler runs on the service's event-loop thread, so this is
+        # the loop's CPU time: its delta over a window is the loop's busy
+        # share, at no cost on the request path
+        st["loop_thread_cpu_s"] = time.thread_time()
+        return st
 
+    def _route(self, path: str):
         if path in ("/", "/status", "/metrics"):
-            return 200, "application/json", _json.dumps(self.service.status())
+            return 200, "application/json", json.dumps(self._status())
         if path == "/metrics.prom":
             # Flatten the status counters into Prometheus samples (numeric
-            # leaves only), same exposition family as the replica shell.
+            # leaves only), same exposition family as the replica shell; the
+            # stage registry follows in the shared mochi_timer/_counter/
+            # _histogram families.
             def walk(prefix, obj, out):
                 for k, v in obj.items():
                     key = f"{prefix}_{k}" if prefix else str(k)
@@ -379,13 +538,36 @@ class ServiceAdminServer(HttpJsonServer):
                     elif isinstance(v, (int, float)):
                         out.append((key, v))
 
+            status = self._status()
+            del status["stages"]
             samples: list = []
-            walk("", self.service.status(), samples)
+            walk("", status, samples)
             body = "".join(
                 f'mochi_verifier_service{{name="{k}"}} {v}\n' for k, v in samples
             )
+            body += self.service.metrics.to_prometheus({"service": SERVICE_ID})
             return 200, "text/plain; version=0.0.4", body
         return 404, "application/json", '{"error": "not found"}'
+
+    async def _route_target(self, target: str):
+        url = urllib.parse.urlsplit(target)
+        if url.path != "/profile":
+            return self._route(url.path)
+        svc = self.service
+        if svc.profile_dir is None:
+            return 404, "application/json", '{"error": "started without --profile-dir"}'
+        if svc.profiling:
+            return 409, "application/json", '{"error": "a capture is running"}'
+        try:
+            seconds = float(urllib.parse.parse_qs(url.query)["seconds"][0])
+        except (KeyError, ValueError):
+            seconds = -1.0
+        if not 0.0 < seconds <= MAX_PROFILE_SECONDS:
+            return 400, "application/json", json.dumps(
+                {"error": f"seconds must be in (0, {MAX_PROFILE_SECONDS:g}]"}
+            )
+        path = await svc.capture_profile(seconds)
+        return 200, "application/json", json.dumps({"path": path, "seconds": seconds})
 
 
 def main(argv=None) -> None:
@@ -426,6 +608,13 @@ def main(argv=None) -> None:
         type=int,
         default=None,
         help="serve service counters as JSON over loopback HTTP (0 = ephemeral)",
+    )
+    parser.add_argument(
+        "--profile-dir",
+        default=None,
+        help="let GET /profile?seconds=N on the admin port write one "
+        "jax.profiler capture (N <= 30) under this directory; without it "
+        "the route answers 404",
     )
     parser.add_argument("--log-level", default="INFO")
     args = parser.parse_args(argv)

@@ -21,10 +21,14 @@ from __future__ import annotations
 import asyncio
 import hashlib
 import logging
+import time
 from dataclasses import dataclass
 from typing import Callable, List, Optional, Sequence, Tuple
 
 from ..crypto import keys as crypto_keys
+from ..obs import hostspan
+from ..utils.metrics import Metrics
+from . import stages
 
 LOG = logging.getLogger(__name__)
 
@@ -271,9 +275,16 @@ class CachingVerifier(SignatureVerifier):
     one check, not rf).
     """
 
-    def __init__(self, inner: SignatureVerifier, max_entries: int = 1 << 16):
+    def __init__(
+        self,
+        inner: SignatureVerifier,
+        max_entries: int = 1 << 16,
+        metrics: Optional[Metrics] = None,
+    ):
         self.inner = inner
         self.max_entries = max_entries
+        # stage timers (verifier/stages.py); the service hands in its own
+        self.metrics = metrics if metrics is not None else Metrics()
         self._cache: "dict[Tuple[bytes, bytes, bytes], bool]" = {}
         # single-flight: key -> future for a verification already dispatched
         # but not yet answered.  All rf replicas of a set check the same
@@ -297,22 +308,27 @@ class CachingVerifier(SignatureVerifier):
         waiting: List[Tuple[int, asyncio.Future]] = []
         new_keys: "dict[Tuple[bytes, bytes, bytes], List[int]]" = {}
         reps: List[VerifyItem] = []
-        for i, it in enumerate(items):
-            k = (bytes(it.public_key), bytes(it.message), bytes(it.signature))
-            cached = self._cache.get(k)
-            if cached is not None:
-                out[i] = cached
-                self.hits += 1
-            elif k in self._inflight:
-                waiting.append((i, self._inflight[k]))
-                self.hits += 1
-            elif k in new_keys:
-                new_keys[k].append(i)
-                self.hits += 1
-            else:
-                new_keys[k] = [i]
-                reps.append(it)
-                self.misses += 1
+        # the synchronous stretch before the first await: one tick per call
+        with stages.stage(
+            self.metrics, stages.MEMO_LOOKUP, stages.SPAN_MEMO, items=len(items)
+        ):
+            for i, it in enumerate(items):
+                k = (bytes(it.public_key), bytes(it.message), bytes(it.signature))
+                cached = self._cache.get(k)
+                if cached is not None:
+                    out[i] = cached
+                    self.hits += 1
+                elif k in self._inflight:
+                    waiting.append((i, self._inflight[k]))
+                    self.hits += 1
+                elif k in new_keys:
+                    new_keys[k].append(i)
+                    self.hits += 1
+                else:
+                    new_keys[k] = [i]
+                    reps.append(it)
+                    self.misses += 1
+        self.metrics.mark(stages.MEMO_ITEMS, len(items))
         if new_keys:
             loop = asyncio.get_running_loop()
             futs = {k: loop.create_future() for k in new_keys}
@@ -378,13 +394,17 @@ class CachingVerifier(SignatureVerifier):
         """
         if not items:
             return True
-        key = bytes(key)
-        cached = self._agg.get(key)
+        with stages.stage(
+            self.metrics, stages.MEMO_LOOKUP, stages.SPAN_MEMO, items=len(items)
+        ):
+            key = bytes(key)
+            cached = self._agg.get(key)
+            fut = None if cached is not None else self._agg_inflight.get(key)
+        self.metrics.mark(stages.MEMO_ITEMS, len(items))
         if cached is not None:
             self.agg_hits += 1
             self.hits += 1
             return cached
-        fut = self._agg_inflight.get(key)
         if fut is not None:
             self.agg_hits += 1
             self.hits += 1
@@ -451,21 +471,30 @@ class BatchingVerifier(SignatureVerifier):
         max_delay_s: float = 0.002,
         fallback: Optional[SignatureVerifier] = None,
         max_inflight: int = 4,
+        metrics: Optional[Metrics] = None,
     ):
         self.backend = backend
+        # stage timers (verifier/stages.py): the backend's registry when it
+        # keeps one, so a composition ticks in one place
+        if metrics is None:
+            metrics = getattr(backend, "metrics", None)
+        self.metrics = metrics if metrics is not None else Metrics()
+        self._flush_items = self.metrics.histogram(
+            stages.FLUSH_ITEMS, stages.FLUSH_ITEMS_BOUNDS
+        )
         self.max_batch = max_batch
         self.max_delay_s = max_delay_s
         self.max_inflight = max(1, max_inflight)
         self._inflight: Optional[asyncio.Semaphore] = None
         self._chunk_tasks: set = set()
         self.fallback = fallback if fallback is not None else CpuVerifier()
-        self._pending: List[Tuple[VerifyItem, asyncio.Future]] = []
+        # (item, its caller's future, when its verify_batch call enqueued it)
+        self._pending: List[Tuple[VerifyItem, asyncio.Future, float]] = []
         self._wakeup: Optional[asyncio.Event] = None
         self._flusher: Optional[asyncio.Task] = None
         self._closed = False
         # simple counters for observability (see mochi_tpu.utils.metrics)
         self.batches_flushed = 0
-        self.items_verified = 0
         self.fallback_batches = 0
 
     def _ensure_flusher(self) -> None:
@@ -482,7 +511,8 @@ class BatchingVerifier(SignatureVerifier):
         self._ensure_flusher()
         loop = asyncio.get_running_loop()
         futures = [loop.create_future() for _ in items]
-        self._pending.extend(zip(items, futures))
+        enqueued = time.perf_counter()  # one reading per call, not per item
+        self._pending.extend((it, fut, enqueued) for it, fut in zip(items, futures))
         assert self._wakeup is not None
         self._wakeup.set()
         return list(await asyncio.gather(*futures))
@@ -515,7 +545,7 @@ class BatchingVerifier(SignatureVerifier):
                 task.add_done_callback(self._chunk_tasks.discard)
 
     async def _run_chunk_guarded(
-        self, chunk: List[Tuple[VerifyItem, asyncio.Future]]
+        self, chunk: List[Tuple[VerifyItem, asyncio.Future, float]]
     ) -> None:
         try:
             await self._run_chunk(chunk)
@@ -523,11 +553,25 @@ class BatchingVerifier(SignatureVerifier):
             assert self._inflight is not None
             self._inflight.release()
 
-    async def _run_chunk(self, chunk: List[Tuple[VerifyItem, asyncio.Future]]) -> None:
-        items = [it for it, _ in chunk]
+    async def _run_chunk(
+        self, chunk: List[Tuple[VerifyItem, asyncio.Future, float]]
+    ) -> None:
+        items = [it for it, _, _ in chunk]
+        oldest = chunk[0][2]  # calls enqueue in order: the first item waited longest
         loop = asyncio.get_running_loop()
+
+        def flush():
+            # on the executor thread: the linger, the _inflight semaphore and
+            # the hand-off are all behind this chunk now
+            waited = time.perf_counter() - oldest
+            with hostspan.span(
+                stages.SPAN_CHUNK, items=len(items), wait_us=int(waited * 1e6)
+            ):
+                return waited, list(self.backend(items))
+
         try:
-            bitmap = await loop.run_in_executor(None, lambda: list(self.backend(items)))
+            waited, bitmap = await loop.run_in_executor(None, flush)
+            self.metrics.timers[stages.QUEUE_WAIT].record(waited)
             if len(bitmap) != len(items):
                 raise ValueError("backend bitmap length mismatch")
         except asyncio.CancelledError:
@@ -537,8 +581,8 @@ class BatchingVerifier(SignatureVerifier):
             self.fallback_batches += 1
             bitmap = await self.fallback.verify_batch(items)
         self.batches_flushed += 1
-        self.items_verified += len(items)
-        for (_, fut), ok in zip(chunk, bitmap):
+        self._flush_items.observe(len(items))
+        for (_, fut, _), ok in zip(chunk, bitmap):
             if not fut.done():
                 fut.set_result(bool(ok))
 
@@ -558,7 +602,7 @@ class BatchingVerifier(SignatureVerifier):
         # backend work is already running in the executor either way).
         if self._chunk_tasks:
             await asyncio.gather(*list(self._chunk_tasks), return_exceptions=True)
-        for _, fut in self._pending:
+        for _, fut, _ in self._pending:
             if not fut.done():
                 fut.cancel()
         self._pending.clear()
@@ -579,7 +623,6 @@ def verifier_stats(verifier) -> dict:
         st["host_crypto_engine"] = crypto_keys.host_crypto_engine()
     for attr in (
         "batches_flushed",
-        "items_verified",
         "remote_batches",
         "fallback_batches",
         "hits",

@@ -64,6 +64,7 @@ class TpuBatchVerifier(_SignerRegistrationMixin, BatchingVerifier):
         min_device_items: Optional[int] = None,
         max_inflight: int = 4,
         signers: Sequence[bytes] = (),
+        metrics=None,
     ):
         registry = None
         if signers:
@@ -72,7 +73,8 @@ class TpuBatchVerifier(_SignerRegistrationMixin, BatchingVerifier):
             registry = SignerRegistry(device=device)
             registry.register_all(signers)
         jax_backend = JaxBatchBackend(
-            device=device, min_device_items=min_device_items, registry=registry
+            device=device, min_device_items=min_device_items, registry=registry,
+            metrics=metrics,
         )
         super().__init__(
             backend=jax_backend,
@@ -80,6 +82,7 @@ class TpuBatchVerifier(_SignerRegistrationMixin, BatchingVerifier):
             max_delay_s=max_delay_s,
             fallback=fallback,
             max_inflight=max_inflight,
+            metrics=jax_backend.metrics,
         )
         self._device = device
         self._warmup_buckets = tuple(warmup_buckets)
@@ -107,7 +110,8 @@ class ShardedJaxBatchBackend(JaxBatchBackend):
     """
 
     def __init__(
-        self, mesh=None, min_device_items: Optional[int] = None, registry=None
+        self, mesh=None, min_device_items: Optional[int] = None, registry=None,
+        metrics=None,
     ):
         from jax.sharding import NamedSharding, PartitionSpec
 
@@ -129,6 +133,7 @@ class ShardedJaxBatchBackend(JaxBatchBackend):
             min_device_items=min_device_items,
             verify_fn=self._sharded_verify,
             registry=registry,
+            metrics=metrics,
         )
 
     def _comb_capable(self) -> bool:
@@ -254,9 +259,10 @@ class ShardedTpuBatchVerifier(_SignerRegistrationMixin, BatchingVerifier):
         min_device_items: Optional[int] = None,
         max_inflight: int = 4,
         signers: Sequence[bytes] = (),
+        metrics=None,
     ):
         backend = ShardedJaxBatchBackend(
-            mesh=mesh, min_device_items=min_device_items
+            mesh=mesh, min_device_items=min_device_items, metrics=metrics
         )
         if signers:
             backend.register_signers(signers)
@@ -266,6 +272,7 @@ class ShardedTpuBatchVerifier(_SignerRegistrationMixin, BatchingVerifier):
             max_delay_s=max_delay_s,
             fallback=fallback,
             max_inflight=max_inflight,
+            metrics=backend.metrics,
         )
         self._warmup_buckets = tuple(warmup_buckets)
         if warmup_buckets:

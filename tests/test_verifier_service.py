@@ -219,7 +219,10 @@ def test_service_status_counters_and_admin_endpoint():
                     f"http://127.0.0.1:{port}/status", timeout=5
                 ).read()
             )
-            assert json.loads(raw) == st
+            served = json.loads(raw)
+            # read in the handler, on the loop thread, so not in status()
+            assert served.pop("loop_thread_cpu_s") > 0
+            assert served == st
 
             prom = (
                 await asyncio.to_thread(
