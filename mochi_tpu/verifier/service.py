@@ -255,7 +255,13 @@ class VerifierService:
                     FailType.BAD_REQUEST,
                     f"{len(items)} items > limit {self.max_items_per_request}",
                 )
-            batch = [VerifyItem(pk, msg, sig) for pk, msg, sig in items]
+            # the memo takes the request's triples as they are (a VerifyItem
+            # is a named 3-tuple) and builds VerifyItems only for what it
+            # sends on; a bare verifier is handed VerifyItems
+            if isinstance(self.verifier, CachingVerifier):
+                batch = items
+            else:
+                batch = [VerifyItem(pk, msg, sig) for pk, msg, sig in items]
         bitmap = await self.verifier.verify_batch(batch)
         with hostspan.span(
             stages.SPAN_RPC_REPLY, wait_us=int((time.perf_counter() - started) * 1e6)

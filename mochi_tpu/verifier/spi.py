@@ -22,8 +22,8 @@ import asyncio
 import hashlib
 import logging
 import time
-from dataclasses import dataclass
-from typing import Callable, List, Optional, Sequence, Tuple
+from collections import deque
+from typing import Callable, Deque, List, NamedTuple, Optional, Sequence, Tuple
 
 from ..crypto import keys as crypto_keys
 from ..obs import hostspan
@@ -33,9 +33,10 @@ from . import stages
 LOG = logging.getLogger(__name__)
 
 
-@dataclass(frozen=True)
-class VerifyItem:
-    """One Ed25519 verification: (public key, message, signature)."""
+class VerifyItem(NamedTuple):
+    """One Ed25519 verification: (public key, message, signature).  A named
+    tuple: built, hashed and compared by value at C speed, so the tuple of a
+    call's items is an exact memo key (:class:`CachingVerifier`)."""
 
     public_key: bytes  # 32 bytes
     message: bytes
@@ -263,6 +264,23 @@ class CoalescingVerifier(SignatureVerifier):
         await self.inner.close()
 
 
+_NOBODY = (None, -1)  # no call owns the key
+
+
+async def _owner_result(owner: asyncio.Future):
+    """A single-flight waiter's wait: what the owner call resolved its future
+    to, or None (the retry sentinel) where the future was cancelled under
+    it.  ``Task.cancel`` cancels the future a task waits on, so one waiter's
+    cancellation would otherwise reach every call parked on the same owner;
+    a waiter that was itself cancelled still raises."""
+    try:
+        return await owner
+    except asyncio.CancelledError:
+        if not owner.cancelled() or asyncio.current_task().cancelling():
+            raise
+        return None
+
+
 class CachingVerifier(SignatureVerifier):
     """LRU memo over any verifier — verification is a pure function of
     (public key, message, signature), so caching is sound.
@@ -272,7 +290,9 @@ class CachingVerifier(SignatureVerifier):
     milliseconds (each replica independently checks the certificate, as BFT
     requires) — one device/CPU verification serves all rf of them.  Negative
     results are cached too (a forged grant replayed across replicas costs
-    one check, not rf).
+    one check, not rf).  Two layers, each with its single-flight table: a
+    whole call (the tuple of its items: the other rf-1 replicas' request is
+    one hash and one lookup) and, behind it, each item.
     """
 
     def __init__(
@@ -286,12 +306,25 @@ class CachingVerifier(SignatureVerifier):
         # stage timers (verifier/stages.py); the service hands in its own
         self.metrics = metrics if metrics is not None else Metrics()
         self._cache: "dict[Tuple[bytes, bytes, bytes], bool]" = {}
-        # single-flight: key -> future for a verification already dispatched
-        # but not yet answered.  All rf replicas of a set check the same
-        # certificate within one batching window, so without this the
+        # single-flight: key -> (the owner call's ONE future, the key's index
+        # in the verdicts it resolves to) for a verification already
+        # dispatched but not yet answered.  All rf replicas of a set check the
+        # same certificate within one batching window, so without this the
         # duplicates race past the cache (observed: 0 service cache hits
         # under concurrent cluster load) and each costs a real verification.
-        self._inflight: "dict[Tuple[bytes, bytes, bytes], asyncio.Future]" = {}
+        # A waiting call parks on one future per distinct owner (for a
+        # certificate: one), not on one per key.
+        self._inflight: "dict[Tuple[bytes, bytes, bytes], Tuple[asyncio.Future, int]]" = {}
+        # Whole-call memo and single-flight, in front of the per-item ones:
+        # tuple(items) -> the call's bitmap / the future of the call that is
+        # verifying it.  All rf replicas of a set send the SAME item list, so
+        # the rf-1 that are not first cost one tuple hash and one lookup
+        # instead of one key and one lookup per item.  The key is the items
+        # themselves (the dict compares by value on a hit), no digest; bounded
+        # by items held, like the per-item cache.
+        self._calls: "dict[Tuple[VerifyItem, ...], Tuple[bool, ...]]" = {}
+        self._calls_items = 0
+        self._calls_inflight: "dict[Tuple[VerifyItem, ...], asyncio.Future]" = {}
         self.hits = 0
         self.misses = 0
         # Aggregate memo (round 18): cert-hash -> all-valid verdict.  Kept
@@ -304,80 +337,150 @@ class CachingVerifier(SignatureVerifier):
         self.agg_misses = 0
 
     async def verify_batch(self, items: Sequence[VerifyItem]) -> List[bool]:
-        out: List[Optional[bool]] = [None] * len(items)
-        waiting: List[Tuple[int, asyncio.Future]] = []
-        new_keys: "dict[Tuple[bytes, bytes, bytes], List[int]]" = {}
-        reps: List[VerifyItem] = []
+        """``items`` may also be bare (public key, message, signature) triples,
+        as a verify request carries them: they equal the VerifyItems they would
+        make, so they find the same memo entries, and only an item that has to
+        go to the inner verifier is made into one."""
+        call = tuple(items)
+        plan = None
         # the synchronous stretch before the first await: one tick per call
         with stages.stage(
-            self.metrics, stages.MEMO_LOOKUP, stages.SPAN_MEMO, items=len(items)
+            self.metrics, stages.MEMO_LOOKUP, stages.SPAN_MEMO, items=len(call)
         ):
-            for i, it in enumerate(items):
-                k = (bytes(it.public_key), bytes(it.message), bytes(it.signature))
-                cached = self._cache.get(k)
-                if cached is not None:
-                    out[i] = cached
-                    self.hits += 1
-                elif k in self._inflight:
-                    waiting.append((i, self._inflight[k]))
-                    self.hits += 1
-                elif k in new_keys:
-                    new_keys[k].append(i)
-                    self.hits += 1
-                else:
-                    new_keys[k] = [i]
-                    reps.append(it)
-                    self.misses += 1
-        self.metrics.mark(stages.MEMO_ITEMS, len(items))
+            answered = self._calls.get(call)
+            asked = None if answered is not None else self._calls_inflight.get(call)
+            if asked is not None and asked.cancelled():
+                asked = None  # cancelled under its owner: this call verifies for itself
+            if answered is None and asked is None:
+                plan = self._plan(items)
+        self.metrics.mark(stages.MEMO_ITEMS, len(call))
+        if answered is not None:
+            self.hits += len(call)
+            return list(answered)
+        if asked is not None:
+            self.hits += len(call)
+            verdicts = await _owner_result(asked)
+            if verdicts is None:  # that call failed: verify ourselves (re-enters)
+                return await self.verify_batch(items)
+            return list(verdicts)
+        out, waiting, new_keys, reps = plan
+        if not new_keys and not waiting:
+            self._remember(call, out)  # every item was a hit: nothing to wait for
+            return out
+        mine = asyncio.get_running_loop().create_future()
+        self._calls_inflight[call] = mine
+        verdicts = None
+        try:
+            await self._settle(items, out, waiting, new_keys, reps)
+            self._remember(call, out)
+            verdicts = out
+        finally:
+            # None on the way out of a failure or a cancellation: the retry
+            # sentinel, as for the per-item future in _settle (a call parked
+            # on this one must not inherit either)
+            if self._calls_inflight.get(call) is mine:
+                del self._calls_inflight[call]
+            if not mine.done():
+                mine.set_result(verdicts)
+        return list(out)
+
+    def _remember(self, call: Tuple[VerifyItem, ...], out: List[bool]) -> None:
+        if call not in self._calls:
+            self._calls_items += len(call)
+        self._calls[call] = tuple(out)
+        while self._calls_items > self.max_entries and self._calls:
+            # drop the oldest insertion (dict preserves order)
+            oldest = next(iter(self._calls))
+            self._calls_items -= len(oldest)
+            del self._calls[oldest]
+
+    def _plan(self, items: Sequence[VerifyItem]):
+        """The per-item lookup (synchronous): verdicts already known, the
+        items other calls are verifying, and the keys this call must."""
+        out: List[Optional[bool]] = [None] * len(items)
+        # owner call's future -> [(index here, index in the owner's verdicts)]
+        waiting: "dict[asyncio.Future, List[Tuple[int, int]]]" = {}
+        new_keys: "dict[Tuple[bytes, bytes, bytes], List[int]]" = {}
+        reps: List[VerifyItem] = []
+        for i, it in enumerate(items):
+            pk, msg, sig = it  # a VerifyItem, or the bare triple it is made from
+            k = (bytes(pk), bytes(msg), bytes(sig))
+            cached = self._cache.get(k)
+            if cached is not None:
+                out[i] = cached
+                self.hits += 1
+                continue
+            owner, j = self._inflight.get(k, _NOBODY)
+            # (a future cancelled under its owner answers nobody: this
+            # call verifies the key itself)
+            if owner is not None and not owner.cancelled():
+                waiting.setdefault(owner, []).append((i, j))
+                self.hits += 1
+            elif k in new_keys:
+                new_keys[k].append(i)
+                self.hits += 1
+            else:
+                new_keys[k] = [i]
+                reps.append(it if type(it) is VerifyItem else VerifyItem._make(it))
+                self.misses += 1
+        return out, waiting, new_keys, reps
+
+    async def _settle(self, items, out, waiting, new_keys, reps) -> None:
+        """Fill ``out``: verify ``reps`` (this call's new keys) on the inner
+        verifier, then collect what other calls were verifying."""
         if new_keys:
-            loop = asyncio.get_running_loop()
-            futs = {k: loop.create_future() for k in new_keys}
-            self._inflight.update(futs)
+            mine = asyncio.get_running_loop().create_future()
+            for j, k in enumerate(new_keys):
+                self._inflight[k] = (mine, j)
             try:
                 bitmap = await self.inner.verify_batch(reps)
                 if len(bitmap) != len(reps):
                     # A short/long bitmap would silently truncate the zip
-                    # below, leaving the tail keys' futures unresolved forever
-                    # (concurrent waiters would hang).  Route through the same
-                    # cleanup path as a dispatch failure.
+                    # below, leaving the tail keys' verdicts unset.  Route
+                    # through the same cleanup path as a dispatch failure.
                     raise RuntimeError(
                         f"inner verifier returned {len(bitmap)} verdicts "
                         f"for {len(reps)} items"
                     )
             except BaseException:
-                # Dispatch failed (or owner cancelled): resolve the futures
+                # Dispatch failed (or owner cancelled): resolve the future
                 # with a retry sentinel rather than an exception — a
                 # concurrent waiter must not inherit THIS caller's failure
                 # (it would have verified independently before single-flight
                 # existed), and a sentinel can't trigger "exception never
                 # retrieved" warnings when nobody is waiting.
-                for k, fut in futs.items():
-                    # mochi-lint: disable=await-races -- single-flight owner: only the caller that registered futs[k] ever pops it (waiters see `k in _inflight` and never mutate), so the entry cannot have been replaced across the await
-                    self._inflight.pop(k, None)
-                    if not fut.done():
-                        fut.set_result(None)
+                self._release(new_keys, mine)
+                if not mine.done():
+                    mine.set_result(None)
                 raise
-            for (k, idxs), ok in zip(new_keys.items(), bitmap):
-                ok = bool(ok)
+            verdicts = [bool(ok) for ok in bitmap]
+            for (k, idxs), ok in zip(new_keys.items(), verdicts):
                 for i in idxs:
                     out[i] = ok
                 if len(self._cache) >= self.max_entries:
                     # drop the oldest insertion (dict preserves order)
                     self._cache.pop(next(iter(self._cache)))
                 self._cache[k] = ok
-                fut = futs[k]
-                # mochi-lint: disable=await-races -- single-flight owner (same contract as the failure path above)
-                self._inflight.pop(k, None)
-                if not fut.done():
-                    fut.set_result(ok)
-        for i, fut in waiting:
-            ok = await fut
-            if ok is None:
+            self._release(new_keys, mine)
+            if not mine.done():
+                mine.set_result(verdicts)
+        for owner, pairs in waiting.items():
+            verdicts = await _owner_result(owner)
+            if verdicts is None:
                 # the dispatching caller failed before producing a verdict —
-                # verify this item ourselves (re-enters cache/single-flight)
-                (ok,) = await self.verify_batch([items[i]])
-            out[i] = bool(ok)
-        return [bool(b) for b in out]
+                # verify its items ourselves (re-enters cache/single-flight)
+                verdicts = await self.verify_batch([items[i] for i, _ in pairs])
+                pairs = [(i, j) for j, (i, _) in enumerate(pairs)]
+            for i, j in pairs:
+                out[i] = verdicts[j]
+
+    def _release(self, keys, mine: asyncio.Future) -> None:
+        """The owner call takes its keys out of the single-flight table: only
+        the entries that are still its own (a call that found ``mine``
+        cancelled has since made itself the owner of that key)."""
+        for k in keys:
+            if self._inflight.get(k, _NOBODY)[0] is mine:
+                del self._inflight[k]
 
     async def verify_aggregate(
         self, key: bytes, items: Sequence[VerifyItem]
@@ -400,6 +503,8 @@ class CachingVerifier(SignatureVerifier):
             key = bytes(key)
             cached = self._agg.get(key)
             fut = None if cached is not None else self._agg_inflight.get(key)
+            if fut is not None and fut.cancelled():
+                fut = None  # cancelled under its owner: verify here (see verify_batch)
         self.metrics.mark(stages.MEMO_ITEMS, len(items))
         if cached is not None:
             self.agg_hits += 1
@@ -408,7 +513,7 @@ class CachingVerifier(SignatureVerifier):
         if fut is not None:
             self.agg_hits += 1
             self.hits += 1
-            ok = await fut
+            ok = await _owner_result(fut)
             if ok is None:  # dispatcher failed: verify ourselves (re-enters)
                 return await self.verify_aggregate(key, items)
             return bool(ok)
@@ -428,7 +533,8 @@ class CachingVerifier(SignatureVerifier):
             # same retry-sentinel contract as verify_batch's failure path
             # (single-flight owner: only the caller that registered the
             # future ever pops it)
-            self._agg_inflight.pop(key, None)
+            if self._agg_inflight.get(key) is fut:
+                del self._agg_inflight[key]
             if not fut.done():
                 fut.set_result(None)
             raise
@@ -436,7 +542,8 @@ class CachingVerifier(SignatureVerifier):
         if len(self._agg) >= self.max_entries:
             self._agg.pop(next(iter(self._agg)))
         self._agg[key] = verdict
-        self._agg_inflight.pop(key, None)
+        if self._agg_inflight.get(key) is fut:
+            del self._agg_inflight[key]
         if not fut.done():
             fut.set_result(verdict)
         return verdict
@@ -448,19 +555,45 @@ class CachingVerifier(SignatureVerifier):
 BatchBackend = Callable[[Sequence[VerifyItem]], Sequence[bool]]
 
 
+class _Call:
+    """One ``verify_batch`` call inside :class:`BatchingVerifier`: its items,
+    the ONE future its caller awaits, and the bitmap its chunks fill in."""
+
+    __slots__ = ("items", "future", "out", "left", "enqueued")
+
+    def __init__(self, items: Sequence[VerifyItem], future: asyncio.Future, enqueued: float):
+        self.items = items
+        self.future = future
+        self.out: List[bool] = [False] * len(items)
+        self.left = len(items)  # items no chunk has answered yet
+        self.enqueued = enqueued
+
+
+# (call, first item, one past the last): the part of a call that rides one chunk
+_Segment = Tuple[_Call, int, int]
+
+
 class BatchingVerifier(SignatureVerifier):
     """Micro-batching front for a (possibly device-backed) batch backend.
 
-    Requests enqueue items and await their bitmap slice; a single flusher task
-    drains the queue in backend-sized batches.  ``max_delay_s`` bounds how
-    long a lone item waits for co-batching (latency/throughput knob); each
-    flush runs in a thread executor so the event loop keeps serving traffic
-    while the device crunches.  Up to ``max_inflight`` batches run
-    concurrently: JAX dispatch is async, so in-flight batches overlap the
-    host->device round trip with device execution
-    (scripts/pipeline_bench.py measures the effect).  A backend exception
-    re-verifies the chunk on the CPU fallback — never skipped, and counted
-    in ``fallback_batches`` so a device path that quietly stopped carrying
+    A call enqueues its items and awaits ONE future for its whole bitmap.
+    Work changes hands by callback and by call, never by task: the enqueuing
+    call arms one loop timer (``max_delay_s``, the longest a lone item waits
+    for co-batching; ``call_soon`` when that is 0 or ``max_batch`` items
+    wait), the timer's callback cuts ``max_batch``-sized chunks off the queue
+    while an in-flight slot is free and hands each straight to the loop's
+    thread executor, so the event loop keeps serving traffic while the
+    device crunches.  The executor thread hands a finished chunk back with
+    ``call_soon_threadsafe``; that callback writes each call's slice of the
+    bitmap, resolves the calls whose last slice it was (in item order,
+    whichever chunk finishes first), frees the slot and takes the next chunk.
+    Up to ``max_inflight`` chunks run concurrently: JAX dispatch is async, so
+    in-flight batches overlap the host->device round trip with device
+    execution (scripts/pipeline_bench.py measures the effect); the loop
+    thread does all the counting, so the cap needs no semaphore.  A backend
+    exception re-verifies the chunk on the CPU fallback (a coroutine, so
+    that path alone spends a task) — never skipped, and counted in
+    ``fallback_batches`` so a device path that quietly stopped carrying
     traffic shows in ``verifier_stats``.
     """
 
@@ -482,130 +615,196 @@ class BatchingVerifier(SignatureVerifier):
         self._flush_items = self.metrics.histogram(
             stages.FLUSH_ITEMS, stages.FLUSH_ITEMS_BOUNDS
         )
+        self._calls_per_flush = self.metrics.histogram(
+            stages.CALLS_PER_FLUSH, stages.CALLS_PER_FLUSH_BOUNDS
+        )
         self.max_batch = max_batch
         self.max_delay_s = max_delay_s
         self.max_inflight = max(1, max_inflight)
-        self._inflight: Optional[asyncio.Semaphore] = None
-        self._chunk_tasks: set = set()
         self.fallback = fallback if fallback is not None else CpuVerifier()
-        # (item, its caller's future, when its verify_batch call enqueued it)
-        self._pending: List[Tuple[VerifyItem, asyncio.Future, float]] = []
-        self._wakeup: Optional[asyncio.Event] = None
-        self._flusher: Optional[asyncio.Task] = None
+        # All of the below is touched on the loop thread only.
+        # calls with items no chunk has taken yet, oldest first; of the first
+        # one, items [:_head] are already in a chunk
+        self._pending: Deque[_Call] = deque()
+        self._head = 0
+        self._queued = 0  # items in _pending past _head
+        self._running = 0  # chunks at the backend or on the fallback: <= max_inflight
+        self._timer: Optional[asyncio.Handle] = None  # the one armed _flush_due
+        self._soon = False  # ... armed with call_soon, not the linger
+        # the linger is over and what is queued waits only for a slot
+        self._backlog = False
+        self._fallbacks: set = set()  # the tasks of chunks on the fallback
+        self._drained: Optional[asyncio.Future] = None  # close() waiting for _running == 0
         self._closed = False
         # simple counters for observability (see mochi_tpu.utils.metrics)
         self.batches_flushed = 0
         self.fallback_batches = 0
-
-    def _ensure_flusher(self) -> None:
-        if self._flusher is None or self._flusher.done():
-            self._wakeup = asyncio.Event()
-            self._inflight = asyncio.Semaphore(self.max_inflight)
-            self._flusher = asyncio.get_running_loop().create_task(self._flush_loop())
 
     async def verify_batch(self, items: Sequence[VerifyItem]) -> List[bool]:
         if self._closed:
             raise RuntimeError("verifier closed")
         if not items:
             return []
-        self._ensure_flusher()
         loop = asyncio.get_running_loop()
-        futures = [loop.create_future() for _ in items]
-        enqueued = time.perf_counter()  # one reading per call, not per item
-        self._pending.extend((it, fut, enqueued) for it, fut in zip(items, futures))
-        assert self._wakeup is not None
-        self._wakeup.set()
-        return list(await asyncio.gather(*futures))
-
-    async def _flush_loop(self) -> None:
-        assert self._wakeup is not None
-        while not self._closed:
-            await self._wakeup.wait()
-            self._wakeup.clear()
-            if not self._pending:
-                continue
+        # one future and one clock reading per call, not per item
+        call = _Call(items, loop.create_future(), time.perf_counter())
+        self._pending.append(call)
+        self._queued += len(items)
+        if self._backlog:
+            pass  # a finishing chunk takes it: every slot is busy
+        elif self._queued >= self.max_batch or self.max_delay_s <= 0:
+            if not self._soon:
+                if self._timer is not None:
+                    self._timer.cancel()
+                self._timer = loop.call_soon(self._flush_due)
+                self._soon = True
+        elif self._timer is None:
             # Micro-batching window: let concurrent requests pile on.
-            if len(self._pending) < self.max_batch and self.max_delay_s > 0:
-                await asyncio.sleep(self.max_delay_s)
-            while self._pending:
-                # Acquire BEFORE popping: if close() cancels us at this
-                # await, the items are still in _pending and get cancelled
-                # by the close() sweep instead of hanging their callers.
-                assert self._inflight is not None
-                await self._inflight.acquire()
-                if not self._pending:
-                    self._inflight.release()
-                    break
-                chunk = self._pending[: self.max_batch]
-                del self._pending[: len(chunk)]
-                task = asyncio.get_running_loop().create_task(
-                    self._run_chunk_guarded(chunk)
-                )
-                self._chunk_tasks.add(task)
-                task.add_done_callback(self._chunk_tasks.discard)
+            self._timer = loop.call_later(self.max_delay_s, self._flush_due)
+        return await call.future
 
-    async def _run_chunk_guarded(
-        self, chunk: List[Tuple[VerifyItem, asyncio.Future, float]]
-    ) -> None:
-        try:
-            await self._run_chunk(chunk)
-        finally:
-            assert self._inflight is not None
-            self._inflight.release()
+    def _flush_due(self) -> None:
+        self._timer = None
+        self._soon = False
+        self._take()
 
-    async def _run_chunk(
-        self, chunk: List[Tuple[VerifyItem, asyncio.Future, float]]
-    ) -> None:
-        items = [it for it, _, _ in chunk]
-        oldest = chunk[0][2]  # calls enqueue in order: the first item waited longest
+    def _take(self) -> None:
+        """Loop thread: chunks off the queue into the executor while a slot
+        is free.  The only place ``_running`` grows."""
         loop = asyncio.get_running_loop()
+        while self._queued and self._running < self.max_inflight:
+            segments: List[_Segment] = []
+            items: List[VerifyItem] = []
+            room = min(self.max_batch, self._queued)
+            oldest = self._pending[0].enqueued  # calls enqueue in order: the first waited longest
+            while room:
+                call = self._pending[0]
+                lo = self._head
+                hi = min(len(call.items), lo + room)
+                segments.append((call, lo, hi))
+                items.extend(call.items[lo:hi])
+                room -= hi - lo
+                if hi == len(call.items):
+                    self._pending.popleft()
+                    self._head = 0
+                else:
+                    self._head = hi
+            self._queued -= len(items)
+            self._running += 1
+            loop.run_in_executor(None, self._flush_chunk, loop, segments, items, oldest)
+        self._backlog = self._queued > 0
 
-        def flush():
-            # on the executor thread: the linger, the _inflight semaphore and
-            # the hand-off are all behind this chunk now
-            waited = time.perf_counter() - oldest
+    def _flush_chunk(
+        self, loop: asyncio.AbstractEventLoop, segments: List[_Segment],
+        items: List[VerifyItem], oldest: float,
+    ) -> None:
+        # on the executor thread: the linger, the wait for a slot and the
+        # hand-off are all behind this chunk now
+        waited = time.perf_counter() - oldest
+        bitmap, failure = None, None
+        try:
             with hostspan.span(
                 stages.SPAN_CHUNK, items=len(items), wait_us=int(waited * 1e6)
             ):
-                return waited, list(self.backend(items))
-
-        try:
-            waited, bitmap = await loop.run_in_executor(None, flush)
-            self.metrics.timers[stages.QUEUE_WAIT].record(waited)
+                bitmap = list(self.backend(items))
             if len(bitmap) != len(items):
                 raise ValueError("backend bitmap length mismatch")
-        except asyncio.CancelledError:
-            raise
-        except Exception:
-            LOG.exception("batch backend failed; falling back to CPU verify")
-            self.fallback_batches += 1
+        except Exception as exc:
+            bitmap, failure = None, exc
+        # straight onto the loop, not through the executor future's state
+        # copy and done-callback: each of those is a turn of the loop
+        loop.call_soon_threadsafe(
+            self._chunk_done, segments, items, waited, bitmap, failure, time.perf_counter()
+        )
+
+    def _chunk_done(
+        self, segments: List[_Segment], items: List[VerifyItem], waited: float,
+        bitmap: Optional[List[bool]], failure: Optional[Exception], returned: float,
+    ) -> None:
+        self.metrics.timers[stages.QUEUE_WAIT].record(waited)
+        if failure is None:
+            self._resolve(segments, bitmap, None, returned)
+            return
+        LOG.error("batch backend failed; falling back to CPU verify", exc_info=failure)
+        self.fallback_batches += 1
+        # the fallback is a coroutine and a callback cannot await
+        task = asyncio.get_running_loop().create_task(
+            self._fall_back(segments, items, returned)
+        )
+        self._fallbacks.add(task)
+        task.add_done_callback(self._fallbacks.discard)
+
+    async def _fall_back(
+        self, segments: List[_Segment], items: List[VerifyItem], returned: float
+    ) -> None:
+        try:
             bitmap = await self.fallback.verify_batch(items)
+            if len(bitmap) != len(items):
+                raise ValueError("fallback bitmap length mismatch")
+        except asyncio.CancelledError:
+            raise  # the loop is going down, and the callers with it
+        except Exception as exc:
+            # nothing has verified these items: their callers hear why
+            LOG.exception("CPU fallback failed after the batch backend did")
+            self._resolve(segments, None, exc, returned)
+        else:
+            self._resolve(segments, bitmap, None, returned)
+
+    def _resolve(
+        self, segments: List[_Segment], bitmap: Optional[Sequence[bool]],
+        failure: Optional[Exception], returned: float,
+    ) -> None:
+        """Loop thread, once a chunk: each call's slice written, the calls
+        this chunk completes resolved (all of its calls, with ``failure``,
+        where nothing verified it), its slot freed, the next chunk taken."""
+        done: List[_Call] = []
+        pos = 0
+        for call, lo, hi in segments:
+            if failure is None:
+                call.out[lo:hi] = [bool(ok) for ok in bitmap[pos : pos + hi - lo]]
+            pos += hi - lo
+            call.left -= hi - lo
+            if (call.left == 0 or failure is not None) and not call.future.done():
+                done.append(call)
+        with hostspan.span(
+            stages.SPAN_RESOLVE, items=pos, calls=len(done),
+            wait_us=int((time.perf_counter() - returned) * 1e6),
+        ):
+            for call in done:
+                if failure is None:
+                    call.future.set_result(call.out)
+                else:
+                    call.future.set_exception(failure)
+        self.metrics.timers[stages.RESOLVE_WAIT].record(time.perf_counter() - returned)
         self.batches_flushed += 1
-        self._flush_items.observe(len(items))
-        for (_, fut, _), ok in zip(chunk, bitmap):
-            if not fut.done():
-                fut.set_result(bool(ok))
+        self._flush_items.observe(pos)
+        self._calls_per_flush.observe(len(done))
+        self._running -= 1
+        if self._closed:
+            if not self._running and self._drained is not None and not self._drained.done():
+                self._drained.set_result(None)
+        elif self._backlog:
+            self._take()
 
     async def close(self) -> None:
         self._closed = True
-        if self._wakeup is not None:
-            self._wakeup.set()
-        if self._flusher is not None:
-            self._flusher.cancel()
-            try:
-                await self._flusher
-            except asyncio.CancelledError:
-                pass  # the cancellation we just requested
-            except Exception:
-                pass
-        # Let in-flight chunks finish so their futures resolve (their
-        # backend work is already running in the executor either way).
-        if self._chunk_tasks:
-            await asyncio.gather(*list(self._chunk_tasks), return_exceptions=True)
-        for _, fut, _ in self._pending:
-            if not fut.done():
-                fut.cancel()
+        if self._timer is not None:
+            self._timer.cancel()
+            self._timer = None
+            self._soon = False
+        # Let in-flight chunks finish so their calls resolve (their backend
+        # work is already running in the executor either way); what is only
+        # queued is cancelled.
+        if self._running:
+            if self._drained is None or self._drained.done():
+                self._drained = asyncio.get_running_loop().create_future()
+            await self._drained
+        for call in self._pending:
+            if not call.future.done():
+                call.future.cancel()
         self._pending.clear()
+        self._head = self._queued = 0
+        self._backlog = False
 
 
 def verifier_stats(verifier) -> dict:

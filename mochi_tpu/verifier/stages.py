@@ -20,6 +20,7 @@ from ..obs import hostspan
 SERVICE_RPC = "service.rpc"  # envelope in hand -> sealed reply, awaited verify included
 MEMO_LOOKUP = "service.memo-lookup"  # CachingVerifier's synchronous key-build + lookup loop
 QUEUE_WAIT = "verifier.queue-wait"  # oldest item of a chunk enqueued -> its backend started
+RESOLVE_WAIT = "verifier.resolve-wait"  # a chunk's backend returned -> its calls resolved on the loop
 FLUSH_HOST = "verifier.flush-host"  # one backend call routed to the host engine
 FLUSH_DEVICE = "verifier.flush-device"  # one backend call routed to the device
 PREPARE = "verifier.prepare"  # host packing of one launch
@@ -31,6 +32,8 @@ GC = "service.gc"  # one pass of the collector in the service process
 MEMO_ITEMS = "service.memo-items"  # items through the MEMO_LOOKUP loops
 FLUSH_ITEMS = "verifier.flush-items"  # items per flushed chunk
 FLUSH_ITEMS_BOUNDS = (16, 32, 64, 128, 256, 512, 1024, 2048, 4096, 8192)  # the bucket edges
+CALLS_PER_FLUSH = "verifier.calls-per-flush"  # verify_batch calls a flushed chunk resolved
+CALLS_PER_FLUSH_BOUNDS = (1, 2, 4, 8, 16, 32, 64, 128, 256, 512)
 
 # ---- spans: constants, all under one prefix
 SPAN_PREFIX = "mochi."
@@ -39,6 +42,7 @@ SPAN_RPC_REPLY = "mochi.service.rpc.reply"  # _handle's synchronous tail; wait_u
 SPAN_TICK = "mochi.service.tick"  # once a second on the loop thread: loop_cpu_us, epoch_us
 SPAN_MEMO = "mochi.verifier.memo"  # items
 SPAN_CHUNK = "mochi.verifier.chunk"  # executor thread, one flushed chunk: items, wait_us
+SPAN_RESOLVE = "mochi.verifier.resolve"  # loop thread, one flushed chunk: items, calls, wait_us
 SPAN_FLUSH = "mochi.verifier.flush"  # one backend call: items, route, bucket, epoch_us
 SPAN_HOST_VERIFY = "mochi.verifier.host_verify"
 SPAN_PREPARE = "mochi.verifier.prepare"

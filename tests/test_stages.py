@@ -382,7 +382,9 @@ def test_the_harness_reads_the_programs_by_the_names_the_product_pins():
     assert hostspans.SPAN_PREFIX == stages.SPAN_PREFIX
     named = {n for _, names in hostspans.CAUSES for n in names} | {hostspans.TICK}
     ours = {v for k, v in vars(stages).items() if k.startswith("SPAN_") and k != "SPAN_PREFIX"}
-    assert named == ours  # every span has a cause, or is the tick
+    # every span has a cause, or is the tick; PR 25's resolve span (loop thread, microseconds) has
+    # none until a `benchmark` PR may edit perf/hostspans.py (ROADMAP A0b): its instants read no_span
+    assert named == ours - {stages.SPAN_RESOLVE}
 
 
 def test_verdicts_under_the_scopes_match_the_host_engine():
