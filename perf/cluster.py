@@ -14,6 +14,7 @@ import json
 import os
 import re
 import sys
+import time
 import urllib.request
 
 from mochi_tpu.testing.process_cluster import ProcessCluster
@@ -44,6 +45,20 @@ class PerfCluster(ProcessCluster):
             for sp in self.processes
             for j in range(len(sp.server_ids))
         ]
+
+    def replica_status(self, server_id: str, wait_s: float = 0.0):
+        """One replica's ``/status``, None where it does not answer within
+        ``wait_s`` (it is down, or its admin shell is not up yet)."""
+        sp = self.host_process[server_id]
+        port = ADMIN_BASE_PORT + sp.index * self.n_servers + sp.server_ids.index(server_id)
+        deadline = time.monotonic() + wait_s
+        while True:
+            try:
+                return http_json(port)
+            except OSError:
+                if time.monotonic() >= deadline:
+                    return None
+                time.sleep(0.05)
 
     def service_status(self) -> dict:
         return http_json(self.service_admin_port)
@@ -106,7 +121,24 @@ def replica_counters(statuses: list) -> dict:
         "drain_frames": sum(float(d.get("sum", 0.0)) for d in drain),
         "storage_engines": sorted({r["storage"].get("engine") for r in statuses}),
         "fsync_policies": sorted({str(r["storage"].get("fsync")) for r in statuses}),
+        "admission": sorted({str(r["overload"].get("enabled")) for r in statuses}),
+        # per replica: what its last boot replayed, and its own verifier chain
+        "replay": {r["server_id"]: r["storage"].get("replay") for r in statuses},
+        "verifier_chains": {r["server_id"]: r["verifier"] for r in statuses},
     }
+
+
+# the counters of ``replica_counters`` that add up over replicas and time
+ADDITIVE = ("fallback_batches", "remote_batches", "fsyncs", "drain_count", "drain_frames")
+
+
+def replica_view(status) -> dict | None:
+    """What a fault event's record keeps of the replica it acted on."""
+    if status is None:
+        return None
+    counters = replica_counters([status])
+    return {"store": status["store"], "storage": status["storage"], "verifier": status["verifier"],
+            "counters": {k: counters[k] for k in ADDITIVE}}
 
 
 def cache_entries(cache_dir: str) -> int:

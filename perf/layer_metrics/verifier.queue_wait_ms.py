@@ -1,7 +1,7 @@
 """Mean wait of a flushed chunk in the service's batcher, its oldest item
 enqueued to its backend call started (the 2 ms linger, the in-flight semaphore,
 the executor hand-off): ``wait_us`` of the ``mochi.verifier.chunk`` spans
-(``BatchingVerifier._run_chunk``) in the window trace."""
+(``BatchingVerifier._flush_chunk``) in the window trace."""
 
 import hostspans
 

@@ -9,6 +9,10 @@
   quarter of its items forged: the verdicts have to equal the validity each
   item was made with.  It guarantees that every run drives the device path.
 
+For a cell with a fault schedule it also reads, from a restarted replica
+alone, every record that replica owns and the window updated
+(``direct_reads``).
+
 Before the load it also offers the service the batch sizes the cell's traffic
 can flush (``warm_device_buckets``), so that nothing is built inside the window.
 """
@@ -23,6 +27,9 @@ from mochi_tpu.net.transport import new_msg_id
 from mochi_tpu.protocol import (
     Action,
     Operation,
+    ReadFromServer,
+    ReadToServer,
+    Status,
     Transaction,
     Write1OkFromServer,
     Write1ToServer,
@@ -32,7 +39,7 @@ from mochi_tpu.protocol import (
     transaction_hash,
 )
 
-from ycsb import SDK_TIMEOUT_S, sdk_read
+from ycsb import SDK_TIMEOUT_S, parse_tag, sdk_read
 
 BAD_WRITE2_KINDS = ("altered-signature", "under-quorum")
 
@@ -106,6 +113,40 @@ async def bad_write2_probe(pc, seed: int, keys: list, count: int) -> list:
     out = []
     for n, key in enumerate(picks):
         out.append(await bad_write2(pc, rng, key, BAD_WRITE2_KINDS[n % len(BAD_WRITE2_KINDS)]))
+    return out
+
+
+DIRECT_READ_KEYS = 64  # keys a frame of the direct read-back
+
+
+async def direct_reads(pc, server_id: str, keys: dict) -> dict:
+    """Read ``keys`` ({record: key}, all owned by ``server_id``) from THAT
+    replica alone: one frame to one replica, as the SDK envelopes a read, no
+    quorum behind the answer.  {record: (writer, seq, crc, grants), or None
+    where the replica gave no record}."""
+    import zlib
+
+    client = pc.client(timeout_s=SDK_TIMEOUT_S)
+    info = client.config.servers[server_id]
+    await client._ensure_session(server_id, info)
+    out = {}
+    records = sorted(keys)
+    for i in range(0, len(records), DIRECT_READ_KEYS):
+        batch = records[i:i + DIRECT_READ_KEYS]
+        txn = Transaction(tuple(Operation(Action.READ, keys[r]) for r in batch))
+        nonce = new_msg_id()
+        (answer,) = await _send_to_all(client, [info], ReadToServer(client.client_id, txn, nonce))
+        results = (answer.result.operations
+                   if isinstance(answer, ReadFromServer) and answer.nonce == nonce else ())
+        for k, rec in enumerate(batch):
+            res = results[k] if k < len(results) else None
+            if res is None or res.status != Status.OK or not res.existed or res.value is None:
+                out[rec] = None
+                continue
+            value = bytes(res.value)
+            writer, seq = parse_tag(value) or (-1, -1)
+            cert = res.current_certificate
+            out[rec] = (writer, seq, zlib.crc32(value), len(cert.grants) if cert is not None else 0)
     return out
 
 

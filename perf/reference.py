@@ -191,3 +191,96 @@ def summarize(ops, seconds: float, t_end: float) -> dict:
         "ops_s": done_ok / seconds,
         "latency_ms": lat,
     }
+
+
+# what a configuration file states -> what a replica's /status reports for it
+ENGINE_REPORTED = {"wal": "durable", "paged": "paged"}
+
+
+def check_deployment(config: dict, replicas: dict) -> list:
+    """The deployment the configuration STATES against what the replicas
+    report they run: ``replicas`` has the sorted distinct values over all
+    replicas of the storage engine, the fsync policy and admission control.
+    Each number is how many distinct values differ from the stated one."""
+    want = {
+        "storage_engines": ENGINE_REPORTED.get(config["storage_engine"], config["storage_engine"]),
+        "fsync_policies": config["wal_fsync"],
+        "admission": str(config["admission"] == "on"),
+    }
+    return [
+        Check(f"replicas_reporting_other_{key}", sum(1 for v in replicas[key] if v != stated), 0)
+        for key, stated in want.items()
+    ]
+
+
+def check_recovery(records: list) -> list:
+    """The fault schedule's records (``perf/schedule.py``): a replica that was
+    started again replayed its own log with nothing convicted, and holds at
+    READY at least as many live keys as just before it was killed (the load
+    wrote every key before the window, so the count was not rising).  The WAL
+    engine counts them as replay entries too (every key it held is in its
+    snapshot or in its log after it); the paged engine maps its pages in
+    without replaying them or making them resident and counts their
+    ``pages.live_entries``: the largest of the three counts is compared."""
+    held: dict = {}
+    convicted = short = restarted = 0
+    for rec in records:
+        if rec["before"]["replica"] is not None:
+            held[rec["server_id"]] = rec["before"]["replica"]["store"]["keys_live"]
+        after = rec["after"]["replica"]
+        if rec["before"]["replica"] is None and after is not None:  # it came back
+            restarted += 1
+            replay = after["storage"].get("replay") or {}
+            convicted += int(replay.get("convicted", 0))
+            back = max(int(replay.get("entries", 0)), int(after["store"]["keys_live"]),
+                       int((after["storage"].get("pages") or {}).get("live_entries", 0)))
+            short += back < held.get(rec["server_id"], math.inf)
+        elif rec["before"]["replica"] is None:
+            short += 1  # started again and not answering
+    return [
+        Check("replicas_restarted", restarted, 1, at_least=True),
+        Check("replay_entries_convicted", convicted, 0),
+        Check("replicas_back_with_fewer_keys_than_held_before_the_kill", short, 0),
+    ]
+
+
+def check_direct(direct: dict, hist: dict, records: list, slack_s: float, quorum: int) -> list:
+    """``direct``: {server id: {record: (writer, seq, crc, grants) or None}},
+    every record that replica owns and the window updated, read from THAT
+    replica alone after the window.  A restarted replica answers each with a
+    write of that record that the history knows, under a quorum certificate,
+    and no older than the newest update acknowledged ``slack_s`` seconds or
+    more before it was killed: a Write2 goes to the whole replica set, the
+    caller is answered by the first quorum, and the last replica's write to
+    its log may trail that answer, so an update acknowledged inside the slack
+    may have died with the process.  What was committed while it was down it
+    learns only from a later write or a nudge (the product starts it without
+    ``--resync-on-boot``): those records are counted (``behind``), not compared.
+    Returns the checks, and the counts for the commentary and the readers.
+    ``records`` give the time of each kill, on the generators' clock."""
+    killed_at = {r["server_id"]: r["t_mono"] for r in records if r["after"]["replica"] is None
+                 and r["before"]["replica"] is not None}
+    missing = unknown = short = lost = behind = asked = 0
+    for sid, answers in direct.items():
+        horizon = killed_at.get(sid, math.inf) - slack_s
+        for rec, got in answers.items():
+            asked += 1
+            if got is None:
+                missing += 1
+                continue
+            writer, seq, crc, grants = got
+            h = hist[rec]
+            w = h.writes.get((writer, seq))
+            if w is None or w[2] != crc:
+                unknown += 1
+                continue
+            short += grants < quorum
+            lost += w[1] < h.newest_issue_acked_before(horizon)
+            behind += w[1] < h.newest_issue_acked_before(math.inf)
+    return [
+        Check("direct_reads_sent", asked, 1, at_least=True),
+        Check("direct_reads_unanswered_or_empty", missing, 0),
+        Check("direct_reads_of_no_known_write", unknown, 0),
+        Check("direct_reads_under_quorum_grants", short, 0),
+        Check("direct_reads_older_than_acknowledged_before_the_kill", lost, 0),
+    ], {"asked": asked, "behind": behind}

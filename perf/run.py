@@ -7,7 +7,10 @@ drive it closed loop for the window, decide ``correct``, print the result.
 A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a configuration
 (``perf/configs/<config>.json``) under a traffic mix
 (``perf/traffic/<traffic>.json``).  A per-layer metric is a file in
-``perf/layer_metrics/``.  Nothing here names a cell, a mix or a metric.
+``perf/layer_metrics/``.  A traffic mix may state a fault schedule
+(``perf/schedule.py``), each of whose verbs is a file in ``perf/faults/``; it
+runs inside the window, and a mix without one takes the control flow it always
+took.  Nothing here names a cell, a mix, a metric or a verb.
 
 Processes: replicas are ``python -m mochi_tpu.server`` children, ONE verifier
 service owns the chip (started through ``perf/service_launch.py``, which calls
@@ -53,6 +56,9 @@ BAD_WRITE2S = 4
 WARM_HEADROOM = 2  # flushes pile up: the largest seen was 1.4 x threads x quorum items
 READY_TIMEOUT_S = 1150.0  # a first run compiles; the contract allows it 1200 s
 FAILED_LATENCY_MS = 1e9  # printed where a percentile falls on a failed operation
+# an update acknowledged this long before a kill is in the killed replica's log
+# (reference.check_direct); the longest update of any run so far took 0.9 s
+DIRECT_SLACK_S = 2.0
 
 
 class RunFailure(Exception):
@@ -66,8 +72,12 @@ def say(*parts) -> None:
 # ------------------------------------------------------------------ the data
 
 
-def load_cell(root: str, workload: str) -> dict:
-    """Resolve a cell by name: its entry, configuration and traffic mix."""
+def load_cell(root: str, workload: str, faults_dir: str | None = None) -> dict:
+    """Resolve a cell by name: its entry, configuration and traffic mix (and,
+    where the mix states a fault schedule, the schedule's verbs: files in
+    ``faults/`` beside ``traffic/``, or in ``faults_dir``, where a control
+    keeps broken ones)."""
+    import schedule
     import ycsb
 
     with open(os.path.join(root, "BENCHMARK.json")) as fh:
@@ -82,7 +92,12 @@ def load_cell(root: str, workload: str) -> dict:
     # the data directories lie beside the one that holds the configuration
     data_dir = os.path.join(root, os.path.dirname(os.path.dirname(config_entry["file"])))
     traffic = ycsb.load_traffic(os.path.join(data_dir, "traffic", cell["traffic"] + ".json"))
-    return {"bench": bench, "cell": cell, "config": config, "traffic": traffic,
+    try:
+        verbs = (schedule.validate(traffic["faults"], faults_dir or os.path.join(data_dir, "faults"))
+                 if "faults" in traffic else [])
+    except schedule.ScheduleError as exc:
+        raise RunFailure(f"traffic {cell['traffic']!r}: {exc}") from exc
+    return {"bench": bench, "cell": cell, "config": config, "traffic": traffic, "verbs": verbs,
             "layer_dir": os.path.join(data_dir, "layer_metrics")}
 
 
@@ -242,6 +257,20 @@ async def programs_ready(pc, ctl_dir: str, sizes: list, quiet_s: float = 45.0,
     raise RunFailure(f"the service was still building programs {timeout_s}s after the load")
 
 
+def warm_reach(lowest: int, ready: set, quorum: int, loaders: int, writers: int) -> int:
+    """The largest batch to offer the service before the load (0: none).
+    ``lowest`` is the routing's crossover, ``ready`` the buckets it has both
+    programs for; ``writers`` callers update in the window, ``loaders`` write
+    the load.  Either can pile up ``WARM_HEADROOM`` certificates each."""
+    if not ready or lowest <= 0:
+        return 0
+    if WARM_HEADROOM * writers * quorum >= lowest:
+        return max(ready)
+    if WARM_HEADROOM * loaders * quorum >= lowest:
+        return min(WARM_HEADROOM * loaders * quorum, max(ready))
+    return 0
+
+
 def replica_processes(config: dict) -> int:
     want = config["replica_processes"]
     if want == "cores-2":
@@ -256,6 +285,7 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
     import cluster as cl
     import probe
     import reference as ref
+    import schedule
     import ycsb
     from service_launch import request
 
@@ -266,6 +296,16 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
     threads = shape["threads"]
     gen_procs = min(shape["generator_processes"], threads)
     seed, seconds = args.seed, float(args.seconds)
+    events = []
+    if "faults" in traffic:
+        if rehearse:  # the tiny shape packs its replicas, and a kill takes a whole process
+            shape = dict(shape, replica_processes=n)
+        procs = replica_processes(shape)
+        try:
+            events = schedule.bind(traffic["faults"], data["verbs"], seed, seconds, n, shape["f"],
+                                   {f"server-{i}": i % procs for i in range(n)})
+        except schedule.ScheduleError as exc:
+            raise RunFailure(f"traffic {cell['traffic']!r}: {exc}") from exc
 
     out_dir = os.path.join(PERF, "out", f"{cell['name']}-{seed}")
     shutil.rmtree(out_dir, ignore_errors=True)
@@ -285,6 +325,10 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         n_servers=n, rf=rf, n_processes=replica_processes(shape),
         uds=uds, verifier="service", service_backend="tpu",
         admin_base_port=cl.ADMIN_BASE_PORT, storage_dir=os.path.join(out_dir, "storage"),
+        # what the configuration states, applied (and compared with what the
+        # replicas report, below); the shipped files state the product's defaults
+        storage_engine=shape["storage_engine"], wal_fsync=shape["wal_fsync"],
+        admission=shape["admission"] == "on", byzantine=shape.get("byzantine"),
         seed=seed, ready_timeout_s=READY_TIMEOUT_S,
         # every program the service builds is cached, however quick its
         # compile, so "the window added no cache entry" is exact
@@ -294,6 +338,7 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
     )
     workers = Workers(worker_script, out_dir)
     result: dict = {}
+    fault_task = None
     try:
         t0 = time.monotonic()
         await pc.start()
@@ -323,13 +368,18 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         # doubled for flushes that pile up behind a slow one), no size up to
         # the largest ready bucket is safe: flushes of over 2,048 items were
         # seen on the chip.  Offer all of them now, so that the building
-        # overlaps the load and is over before the window.
+        # overlaps the load and is over before the window.  A mix that
+        # updates nothing sends the service nothing: only the LOAD's writers
+        # can reach the crossover, so offer the sizes up to their reach, and
+        # what the load starts building is waited for before the window.
         counters = cl.service_counters(status)
         both_ready = set(counters["ready_buckets"]) & set(counters["comb_ready_buckets"])
         warmed = {"sizes": [], "mismatches": 0}
-        if both_ready and WARM_HEADROOM * threads * quorum >= counters["min_device_items"] > 0:
-            warmed = await probe.warm_device_buckets(
-                pc, seed, counters["min_device_items"], max(both_ready))
+        lowest = counters["min_device_items"]
+        reach = warm_reach(lowest, both_ready, quorum, shape["load_threads"],
+                           threads if float(traffic["updateproportion"]) > 0 else 0)
+        if reach:
+            warmed = await probe.warm_device_buckets(pc, seed, lowest, reach)
             say(f"offered warm-up batches of {warmed['sizes']} items")
 
         # ---- load, by the generator processes
@@ -356,8 +406,11 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         # ---- the window
         def snapshot() -> dict:
             cpu = pc.cpu_seconds()
+            status = pc.service_status()
             return {
-                "service": cl.service_counters(pc.service_status()),
+                "service": cl.service_counters(status),
+                # the stage timers and histograms (verifier/stages.py), as they are
+                "service_stages": status.get("stages"),
                 "replicas": cl.replica_counters(pc.replica_statuses()),
                 "replica_cpu": sum(v for k, v in cpu.items() if k.startswith("proc-")),
                 "service_cpu": cpu.get("verifier-service", 0.0),
@@ -368,11 +421,22 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         t0 = time.monotonic()
         how = await programs_ready(pc, ctl_dir, warmed["sizes"])
         say(f"programs for the offered sizes {how} after {time.monotonic() - t0:.1f}s more")
+        def observe(server_id: str) -> dict:
+            """A fault event's look at the service and at its replica (None
+            while its process is down)."""
+            sp = pc.process_for(server_id)
+            up = sp.proc.returncode is None
+            return {"service": cl.service_counters(pc.service_status()),
+                    "replica": cl.replica_view(pc.replica_status(server_id, 10.0) if up else None),
+                    "process_cpu": sp.cpu_seconds() if up else None}
+
         before = snapshot()
         t_start = time.monotonic() + 1.0
         t_end = t_start + seconds
         workers.go(t_start, seconds)
         setup_s = t_start - T_PROCESS_START
+        fault_task = (asyncio.ensure_future(schedule.run(pc, events, t_start, observe))
+                      if events else None)
         traces = {}
         trace_len = min(TRACE_SECONDS, seconds / 2)
         if args.trace:
@@ -384,7 +448,21 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
             traces["window"] = {"dir": os.path.join(out_dir, "trace-window"),
                                 "seconds": t_b["stopped_monotonic"] - t_a["started_monotonic"]}
         done = await workers.lines(timeout_s=ycsb.SDK_TIMEOUT_S * 3)
+        try:
+            fault_records = await fault_task if fault_task is not None else []
+        except Exception as exc:
+            raise RunFailure(f"the fault schedule did not run to its end: {exc!r}") from exc
+        for rec in fault_records:
+            say(f"fault {rec['do']} {rec['server_id']}: started {rec['started_s']:.3f}s into the "
+                f"window, took {rec['seconds']:.3f}s, timed {json.dumps(rec['timed'])}")
         after = snapshot()
+        for rec in fault_records:
+            # a killed process took its counters with it: what it had counted
+            # before the kill stays counted in the window's deltas
+            if rec["before"]["replica"] is not None and rec["after"]["replica"] is None:
+                after["replica_cpu"] += rec["before"]["process_cpu"] or 0.0
+                for key, value in rec["before"]["replica"]["counters"].items():
+                    after["replicas"][key] += value
         gen = workers.results()
         ops = [op for g in gen for op in g["ops"]]
         say(f"window closed: {sum(d['done'] for d in done)} operations recorded")
@@ -393,6 +471,22 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
                 say(f"generator {g['worker']} failed operations:", json.dumps(g["errors"]))
             if g.get("retried"):
                 say(f"generator {g['worker']} attempts made again:", json.dumps(g["retried"]))
+
+        # ---- a replica that was started again, asked alone for every record
+        # it owns that the window updated: first of all, because the SDK's reads
+        # nudge a replica they outvote, and the read-back below reads everything
+        direct, direct_counts = {}, {}
+        restarted = sorted({r["server_id"] for r in schedule.restarted(fault_records)})
+        updated = {op[ref.REC] for op in ops if op[ref.KIND] == ref.UPDATE}
+        t0 = time.monotonic()
+        for sid in restarted:
+            owned = {rec: ycsb.key_name(rec) for rec in updated
+                     if sid in pc.config.replica_set_for_key(ycsb.key_name(rec))}
+            direct[sid] = await probe.direct_reads(pc, sid, owned)
+        if restarted:
+            say(f"direct read-back from {restarted} alone: {sum(map(len, direct.values()))} "
+                f"records in {time.monotonic() - t0:.3f}s, {time.monotonic() - t_end:.1f}s "
+                f"after the window closed")
 
         # ---- after the window: read back, probe the device, send bad Write2s
         pool = ycsb.value_pool(seed)
@@ -435,6 +529,15 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         checks += ref.check_window(ops, hist, quorum)
         checks += ref.check_readback(readback, hist, quorum)
         checks += ref.check_probe(bad)
+        checks += ref.check_deployment(shape, final["replicas"])
+        if events:
+            checks += ref.check_recovery(fault_records)
+            direct_checks, direct_counts = ref.check_direct(
+                direct, hist, fault_records, DIRECT_SLACK_S, quorum)
+            checks += direct_checks
+            say(f"direct read-back: {direct_counts['behind']} of {direct_counts['asked']} records "
+                "behind the newest acknowledged write on the restarted replica alone "
+                "(committed while it was down, or inside the slack; not compared)")
         C = ref.Check
         checks += [
             C("operations_recorded", len(ops), 1, at_least=True),
@@ -489,7 +592,8 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
             v = ref.percentile(values, q)
             return FAILED_LATENCY_MS if math.isinf(v) else v
 
-        e2e = {"ops_s": summary["ops_s"], "setup_s": setup_s}
+        e2e = {"ops_s": summary["ops_s"], "setup_s": setup_s,
+               **schedule.end_to_end(events, fault_records)}
         if lat[ref.UPDATE]:
             e2e["update_p95_ms"] = pct(lat[ref.UPDATE], 95)
         if lat[ref.READ]:
@@ -522,6 +626,8 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
                     },
                 },
                 "trace": reduced,
+                "cluster": {"replicas": n, "rf": rf, "f": shape["f"], "quorum": quorum},
+                "faults": fault_records, "end_to_end": e2e,
             }
             result["metrics"] = read_layer_metrics(data["layer_dir"], bench, cell["name"], snap)
             if not rehearse:
@@ -534,7 +640,16 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         if rehearse:
             result["device"].pop("memory_peak_bytes")
             result["rehearsal"] = True
+        # every number compared beside its limit: the result's last key, and
+        # the last lines on standard error
+        result["checks"] = {c.name: {"value": c.value, "limit": c.limit,
+                                     "rule": ">=" if c.at_least else "<="} for c in checks}
+        for c in checks:
+            print("[perf]", c.line(), file=sys.stderr)
+        sys.stderr.flush()
     finally:
+        if fault_task is not None:
+            fault_task.cancel()
         await workers.close()
         await pc.close()
         if args.keep:
@@ -544,7 +659,7 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
     return result
 
 
-def main(argv=None, launcher=None, worker_script=None) -> int:
+def main(argv=None, launcher=None, worker_script=None, faults_dir=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seed", type=int, required=True)
@@ -559,7 +674,7 @@ def main(argv=None, launcher=None, worker_script=None) -> int:
     args = parser.parse_args(argv)
     try:
         gate(args.rehearse)
-        data = load_cell(args.root, args.workload)
+        data = load_cell(args.root, args.workload, faults_dir)
         build_native()
         result = asyncio.run(run_cell(
             args, data,

@@ -5,7 +5,12 @@ has to come out as not correct.
 
 ``accept-all``: the verifier service answers "valid" for every signature (the
 step that would tempt a later PR is to skip or weaken the certificate check).
-``stale-reads``: the generator's reads come from a stale cache.  Takes the
+``stale-reads``: the generator's reads come from a stale cache.
+``emptied-storage`` (a cell with a fault schedule): the restarted replica comes
+back with an emptied storage directory, so it has lost what it acknowledged.
+``forged-log`` (likewise): a grant's signature is altered in the last commits of
+the killed replica's log, CRCs made right, so only a replay that verifies every
+certificate notices.  Takes the
 same arguments as ``perf/run.py`` (``--rehearse`` for the CPU rehearsal).
 Exits 0 when the run printed ``"correct": false``.
 """
@@ -24,6 +29,8 @@ import run  # noqa: E402
 CONTROLS = {
     "accept-all": {"launcher": os.path.join(HERE, "accept_all_launch.py")},
     "stale-reads": {"worker_script": os.path.join(HERE, "stale_read_worker.py")},
+    "emptied-storage": {"faults_dir": os.path.join(HERE, "faults_emptied")},
+    "forged-log": {"faults_dir": os.path.join(HERE, "faults_forged")},
 }
 
 

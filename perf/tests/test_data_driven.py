@@ -1,5 +1,6 @@
-"""A configuration, a traffic mix, a per-layer metric and a cell are each added
-as new files plus entries, with no edit to a file that is there."""
+"""A configuration, a traffic mix (with a fault schedule), a fault verb, a
+per-layer metric and a cell are each added as new files plus entries, with no
+edit to a file that is there."""
 
 import hashlib
 import json
@@ -12,7 +13,7 @@ import run
 
 PERF = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 REPO = os.path.dirname(PERF)
-DATA_DIRS = ("configs", "traffic", "layer_metrics")
+DATA_DIRS = ("configs", "traffic", "layer_metrics", "faults")
 
 THROWAWAY_METRIC = '''
 NAME = "gen.ops_per_cpu_s"
@@ -25,6 +26,21 @@ SOURCE = "host_clock"
 def read(snap):
     cpu = snap["generator"]["cpu_seconds"]
     return snap["ops_ok"] / cpu if cpu else None
+'''
+
+
+THROWAWAY_VERB = '''
+import signal
+import time
+
+KILLS = True
+
+
+async def run(pc, event, state):
+    t0 = time.monotonic()
+    pc.kill_replica(event["server_id"], signal.SIGTERM)   # a drain, not a crash
+    await pc.process_for(event["server_id"]).proc.wait()
+    return {"drained_s": time.monotonic() - t0}
 '''
 
 
@@ -47,13 +63,17 @@ def add_throwaway(root):
                         ignore=shutil.ignore_patterns("__pycache__"))
     before = file_hashes(root)
     config = json.load(open(os.path.join(PERF, "configs", "rf4-n5.json")))
-    config.update(name="rf4-n7", replicas=7, replica_processes=7)
+    config.update(name="rf4-n7", replicas=7, replica_processes=7, storage_engine="paged")
     config["rehearsal"].update(replicas=7, recordcount=48)
     with open(os.path.join(root, "perf", "configs", "rf4-n7.json"), "w") as fh:
         json.dump(config, fh)
     with open(os.path.join(root, "perf", "traffic", "ycsb-b-uniform.json"), "w") as fh:
         json.dump({"readproportion": 0.95, "updateproportion": 0.05,
-                   "requestdistribution": "uniform"}, fh)
+                   "requestdistribution": "uniform",
+                   "faults": [{"at_s": 1.0, "do": "drain_replica", "replica": "seeded"},
+                              {"at_s": 2.0, "do": "restart_replica", "replica": "same"}]}, fh)
+    with open(os.path.join(root, "perf", "faults", "drain_replica.py"), "w") as fh:
+        fh.write(THROWAWAY_VERB)
     with open(os.path.join(root, "perf", "layer_metrics", "gen.ops_per_cpu_s.py"), "w") as fh:
         fh.write(THROWAWAY_METRIC)
     bench = json.load(open(os.path.join(root, "BENCHMARK.json")))
@@ -92,10 +112,12 @@ def test_a_cell_a_config_a_mix_and_a_metric_are_added_by_files(tmp_path):
     before = add_throwaway(root)
     after = file_hashes(root)
     assert {k: after[k] for k in before} == before       # nothing that was there changed
-    assert len(after) == len(before) + 3
+    assert len(after) == len(before) + 4
     data = run.load_cell(root, "n7-ycsb-b")
-    assert data["config"]["replicas"] == 7
+    assert data["config"]["replicas"] == 7 and data["config"]["storage_engine"] == "paged"
     assert data["traffic"]["requestdistribution"] == "uniform"
+    # the schedule's verbs are the new file and a shipped one, found by name
+    assert [v.__name__ for v in data["verbs"]] == ["fault_verb_drain_replica", "fault_verb_restart_replica"]
     metrics = run.read_layer_metrics(data["layer_dir"], data["bench"], "n7-ycsb-b", SNAP)
     assert metrics["gen.ops_per_cpu_s"] == {"value": 200.0, "unit": "ops/s"}
     # and the cells that were there still resolve, with the new metric too
@@ -173,3 +195,11 @@ def test_benchmark_json_and_the_files_agree():
         run.load_cell(REPO, w["name"])
     with pytest.raises(run.RunFailure):
         run.load_cell(REPO, "no-such-cell")
+
+
+def test_a_cell_whose_mix_names_a_verb_without_a_file_is_refused_before_the_boot(tmp_path):
+    root = str(tmp_path / "checkout")
+    add_throwaway(root)
+    os.remove(os.path.join(root, "perf", "faults", "drain_replica.py"))
+    with pytest.raises(run.RunFailure, match="traffic 'ycsb-b-uniform': unknown fault verb 'drain_replica'"):
+        run.load_cell(root, "n7-ycsb-b")

@@ -83,3 +83,16 @@ def test_warm_sizes_cover_every_doubling_between_the_ends(monkeypatch):
     assert got == {"sizes": sent, "mismatches": 0}
     assert sent[0] == 384 and sent[-1] == 1376 and sent == sorted(sent)
     assert all(b / a <= probe.WARM_STEP + 0.01 for a, b in zip(sent, sent[1:]))
+
+
+@pytest.mark.parametrize("cell,args,reach", [
+    # lowest, ready, quorum, loaders, writers
+    ("n64-ycsb-a", (384, {512, 8192}, 43, 8, 16), 8192),     # the callers reach the crossover: every size
+    ("n64-ycsb-c", (384, {512, 8192}, 43, 8, 0), 688),       # nothing updates: only what the load can pile up
+    ("rf4-ycsb-a", (384, {512, 8192}, 3, 32, 32), 0),        # 3-grant certificates reach nothing
+    ("rf4-recover", (384, {512, 8192}, 3, 32, 32), 0),
+    ("no program of both kinds", (384, set(), 43, 8, 16), 0),
+    ("a service that routes nothing to the device", (0, {512}, 43, 8, 16), 0),
+])
+def test_the_warm_up_offers_what_the_mix_and_the_load_can_reach(cell, args, reach):
+    assert run.warm_reach(*args) == reach
