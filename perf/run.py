@@ -51,7 +51,9 @@ for _p in (REPO, PERF):
 
 import layer_reader  # noqa: E402
 
-TRACE_SECONDS = 5.0  # the profiler's share of the window, at its end
+# the profiler's share of the window: its end, or from the command of a
+# scheduled verb that brings an end-to-end metric (``trace_from_s``)
+TRACE_SECONDS = 5.0
 BAD_WRITE2S = 4
 WARM_HEADROOM = 2  # flushes pile up: the largest seen was 1.4 x threads x quorum items
 READY_TIMEOUT_S = 1150.0  # a first run compiles; the contract allows it 1200 s
@@ -257,18 +259,66 @@ async def programs_ready(pc, ctl_dir: str, sizes: list, quiet_s: float = 45.0,
     raise RunFailure(f"the service was still building programs {timeout_s}s after the load")
 
 
-def warm_reach(lowest: int, ready: set, quorum: int, loaders: int, writers: int) -> int:
+def replay_items(shape: dict, verbs: list) -> int:
+    """The signatures a restarted replica's replay can bring the service that
+    its memo no longer holds (0: none).  A replica stores ``recordcount x rf /
+    replicas`` certificates of a quorum of grants each; where the schedule
+    restarts one and they outnumber the memo the configuration STATES
+    (``memo_items``; the service's ``/status`` does not give its capacity), the
+    memo has dropped some, and which is not the harness's to know.  A
+    configuration that states no memo has no replay reach."""
+    memo = shape.get("memo_items")
+    if memo is None or not any(getattr(v, "RESTARTS", False) for v in verbs):
+        return 0
+    stored = shape["recordcount"] * shape["rf"] // shape["replicas"] * shape["quorum"]
+    return stored if stored > memo else 0
+
+
+async def traced(ctl_dir: str, trace_dir: str, work, t_start: float = 0.0) -> tuple:
+    """The service's profiler around ``await work()``: (what ``work``
+    returned, the trace: where it lies, the seconds traced, when it began
+    after ``t_start``, and what the profiler took to start and to stop).  The
+    requests run in a thread: a stop can take most of a minute, and the fault
+    schedule and the load's commentary share this loop."""
+    from service_launch import request
+
+    t0 = time.monotonic()
+    t_a = await asyncio.to_thread(request, ctl_dir, {"op": "trace_start", "dir": trace_dir})
+    t1 = time.monotonic()
+    out = await work()
+    t2 = time.monotonic()
+    t_b = await asyncio.to_thread(request, ctl_dir, {"op": "trace_stop"}, 300.0)
+    return out, {"dir": trace_dir, "seconds": t_b["stopped_monotonic"] - t_a["started_monotonic"],
+                 # one monotonic clock for every process of the machine
+                 "started_s": t_a["started_monotonic"] - t_start,
+                 "profiler_s": [t1 - t0, time.monotonic() - t2]}
+
+
+def warm_reach(lowest: int, ready: set, quorum: int, loaders: int, writers: int,
+               replay: int = 0) -> int:
     """The largest batch to offer the service before the load (0: none).
     ``lowest`` is the routing's crossover, ``ready`` the buckets it has both
     programs for; ``writers`` callers update in the window, ``loaders`` write
-    the load.  Either can pile up ``WARM_HEADROOM`` certificates each."""
+    the load.  Either can pile up ``WARM_HEADROOM`` certificates each.
+    ``replay`` signatures (``replay_items``) reach the service a full request
+    at a time, thinned by its memo to any size, so they reach every bucket as
+    the callers of a large cluster do."""
     if not ready or lowest <= 0:
         return 0
-    if WARM_HEADROOM * writers * quorum >= lowest:
+    if WARM_HEADROOM * writers * quorum >= lowest or replay >= lowest:
         return max(ready)
     if WARM_HEADROOM * loaders * quorum >= lowest:
         return min(WARM_HEADROOM * loaders * quorum, max(ready))
     return 0
+
+
+def trace_from_s(events: list, seconds: float, trace_len: float) -> float:
+    """Where in the window ``--trace 1`` starts the profiler, for ``trace_len``
+    seconds: at the command of the first scheduled verb that brings an
+    end-to-end metric (the recovery is what such a cell is about), and in a
+    cell without one at the window's last ``trace_len`` seconds."""
+    timed = [ev["at_s"] for ev in events if getattr(ev["verb"], "END_TO_END", None)]
+    return float(min(timed)) if timed else seconds - trace_len
 
 
 def replica_processes(config: dict) -> int:
@@ -338,7 +388,7 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
     )
     workers = Workers(worker_script, out_dir)
     result: dict = {}
-    fault_task = None
+    fault_task = profiler_warm = None
     try:
         t0 = time.monotonic()
         await pc.start()
@@ -377,7 +427,8 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         warmed = {"sizes": [], "mismatches": 0}
         lowest = counters["min_device_items"]
         reach = warm_reach(lowest, both_ready, quorum, shape["load_threads"],
-                           threads if float(traffic["updateproportion"]) > 0 else 0)
+                           threads if float(traffic["updateproportion"]) > 0 else 0,
+                           replay_items(shape, data["verbs"]))
         if reach:
             warmed = await probe.warm_device_buckets(pc, seed, lowest, reach)
             say(f"offered warm-up batches of {warmed['sizes']} items")
@@ -396,7 +447,22 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
             }
             for w in range(gen_procs)
         ])
+        # the first trace of a process that holds device events takes the
+        # profiler 44-65 s to stop, the second 14-17 s, a later one under a
+        # second, whatever is in them (PERF.md section 7, third), and the
+        # service stalls meanwhile.  A traced run takes the first now, beside
+        # the load, so that neither of its two traces pays it after the window
+        profiler_warm = (asyncio.ensure_future(traced(
+            ctl_dir, os.path.join(out_dir, "trace-warm"),
+            # (items of its own: the memo must not answer the probe after the window)
+            lambda: probe.device_probe(pc, ~seed, min(both_ready))))
+            if args.trace and both_ready else None)
         loaded = await workers.lines(timeout_s=900.0)
+        if profiler_warm is not None:
+            report, warm = await profiler_warm
+            warmed["mismatches"] += report["mismatches"]
+            say("profiler warmed beside the load: the trace of one probe took "
+                "{:.1f}s to start and {:.1f}s to stop".format(*warm["profiler_s"]))
         n_load_failed = sum(l["n_load_failed"] for l in loaded)
         say(f"loaded {sum(l['loaded'] for l in loaded)} records in "
             f"{time.monotonic() - t0:.1f}s, {n_load_failed} failed")
@@ -426,7 +492,8 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
             while its process is down)."""
             sp = pc.process_for(server_id)
             up = sp.proc.returncode is None
-            return {"service": cl.service_counters(pc.service_status()),
+            status = pc.service_status()
+            return {"service": cl.service_counters(status), "service_stages": status.get("stages"),
                     "replica": cl.replica_view(pc.replica_status(server_id, 10.0) if up else None),
                     "process_cpu": sp.cpu_seconds() if up else None}
 
@@ -438,15 +505,15 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         fault_task = (asyncio.ensure_future(schedule.run(pc, events, t_start, observe))
                       if events else None)
         traces = {}
-        trace_len = min(TRACE_SECONDS, seconds / 2)
         if args.trace:
-            await asyncio.sleep(max(0.0, t_end - trace_len - time.monotonic()))
-            t_a = request(ctl_dir, {"op": "trace_start", "dir": os.path.join(out_dir, "trace-window")})
+            trace_len = min(TRACE_SECONDS, seconds / 2)
+            t_from = t_start + trace_from_s(events, seconds, trace_len)
+            await asyncio.sleep(max(0.0, t_from - time.monotonic()))
+            _, traces["window"] = await traced(
+                ctl_dir, os.path.join(out_dir, "trace-window"),
+                lambda: asyncio.sleep(max(0.0, min(t_from + trace_len, t_end) - time.monotonic())),
+                t_start)
         await asyncio.sleep(max(0.0, t_end - time.monotonic()))
-        if args.trace:
-            t_b = request(ctl_dir, {"op": "trace_stop"}, timeout_s=300.0)
-            traces["window"] = {"dir": os.path.join(out_dir, "trace-window"),
-                                "seconds": t_b["stopped_monotonic"] - t_a["started_monotonic"]}
         done = await workers.lines(timeout_s=ycsb.SDK_TIMEOUT_S * 3)
         try:
             fault_records = await fault_task if fault_task is not None else []
@@ -502,12 +569,11 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         probe_size = min(routed) if routed else min(ready)
         idle0 = cl.service_counters(pc.service_status())
         if args.trace:
-            t_a = request(ctl_dir, {"op": "trace_start", "dir": os.path.join(out_dir, "trace-probe")})
-        dprobe = await probe.device_probe(pc, seed, probe_size)
-        if args.trace:
-            t_b = request(ctl_dir, {"op": "trace_stop"}, timeout_s=300.0)
-            traces["probe"] = {"dir": os.path.join(out_dir, "trace-probe"),
-                               "seconds": t_b["stopped_monotonic"] - t_a["started_monotonic"]}
+            dprobe, traces["probe"] = await traced(
+                ctl_dir, os.path.join(out_dir, "trace-probe"),
+                lambda: probe.device_probe(pc, seed, probe_size), t_start)
+        else:
+            dprobe = await probe.device_probe(pc, seed, probe_size)
         idle1 = cl.service_counters(pc.service_status())
         say(f"device probe: {json.dumps(dprobe)}")
 
@@ -606,12 +672,27 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
                 if metric_applies(m, cell["name"]) and m["name"] in e2e
             }
         else:
+            import hostspans
             import xplane
 
-            reduced = {k: xplane.reduce_dir(t["dir"], t["seconds"]) for k, t in traces.items()}
+            # each reduction is a child of its own, and a child's import of JAX
+            # is most of its cost: the device planes and the host spans (which
+            # the readers would otherwise reduce later, one after the other)
+            # are reduced side by side
+            t0 = time.monotonic()
+            spans_of = {"platform": stats["platform"],
+                        "trace": {k: {"window_s": t["seconds"]} for k, t in traces.items()}}
+            *planes, host_spans = await asyncio.gather(
+                *(asyncio.to_thread(xplane.reduce_dir, t["dir"], t["seconds"]) for t in traces.values()),
+                asyncio.to_thread(hostspans.of, spans_of))
+            reduced = {k: dict(r, started_s=t["started_s"]) for (k, t), r in zip(traces.items(), planes)}
             for k, r in reduced.items():
-                say(f"trace {k}: {r['window_s']:.3f}s traced, device busy {r['busy_s']:.6f}s, "
-                    f"{r['launches']} launches, programs {json.dumps(r['programs'])}")
+                began, ended = traces[k]["profiler_s"]
+                say(f"trace {k}: {r['window_s']:.3f}s traced from {r['started_s']:.3f}s into the "
+                    f"window, device busy {r['busy_s']:.6f}s, "
+                    f"{r['launches']} launches, programs {json.dumps(r['programs'])}; the profiler "
+                    f"took {began:.1f}s to start and {ended:.1f}s to stop")
+            say(f"traces reduced in {time.monotonic() - t0:.1f}s")
             updates_ok = sum(1 for op in ops if op[ref.KIND] == ref.UPDATE and op[ref.OK])
             snap = {
                 "platform": stats["platform"], "window_s": seconds, "ops_ok": summary["attempted"] - summary["failed"],
@@ -625,7 +706,7 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
                         for name in ycsb.STAGE_TIMERS
                     },
                 },
-                "trace": reduced,
+                "trace": reduced, "host_spans": host_spans,
                 "cluster": {"replicas": n, "rf": rf, "f": shape["f"], "quorum": quorum},
                 "faults": fault_records, "end_to_end": e2e,
             }
@@ -634,6 +715,12 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
                 result["device"]["busy_s"] = sum(r["busy_s"] for r in reduced.values())
                 result["device"]["window_s"] = sum(r["window_s"] for r in reduced.values())
                 result["breakdown"] = xplane.breakdown(list(reduced.values()))
+                # where the readers reduced the host's spans beside the device's
+                # plane, the gaps carry what the host was doing in them
+                labelled = [g[:2] for r in (snap.get("host_spans") or {}).values() for g in r["gaps"]]
+                if labelled:
+                    result["breakdown"]["idle_gaps"] = sorted(
+                        labelled, key=lambda g: g[1], reverse=True)[:10]
             if args.keep:
                 with open(os.path.join(out_dir, "snapshot.json"), "w") as fh:
                     json.dump(snap, fh)
@@ -648,8 +735,9 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
             print("[perf]", c.line(), file=sys.stderr)
         sys.stderr.flush()
     finally:
-        if fault_task is not None:
-            fault_task.cancel()
+        for task in (fault_task, profiler_warm):
+            if task is not None:
+                task.cancel()
         await workers.close()
         await pc.close()
         if args.keep:

@@ -95,6 +95,31 @@ def test_a_rehearsal_kills_and_restarts_a_replica_and_times_its_recovery(trace):
         assert name in result["checks"]
 
 
+@pytest.mark.parametrize("trace", [0, 1])
+def test_a_rehearsal_of_the_cold_memo_cell_reports_its_recovery_and_no_update_tail_end_to_end(trace):
+    # the plumbing only: at the rehearsal's 240 records the memo holds every certificate,
+    # so nothing is offered before the load and the replay never leaves the memo
+    done, result = rehearse(os.path.join(PERF, "run.py"), "--workload", "rf4-50k-recover",
+                            "--seed", str(2**31 + 21 + trace), "--seconds", "12", "--trace", str(trace))
+    assert done.returncode == 0, done.stderr[-2000:]
+    assert result["correct"] is True and result["failed"] == 0, done.stdout[-3000:]
+    assert "records=240" in done.stdout and "offered warm-up batches" not in done.stdout
+    if trace == 0:
+        assert set(result["metrics"]) == {"ops_s", "recover_s", "setup_s"}
+    else:
+        # the profiler started at the restart command, 10 s into the window of 12
+        assert "traced from 10." in done.stdout
+        assert RECOVERY <= set(result["metrics"]) and "tail.read_p95_ms" in result["metrics"]
+        # no update tail end to end, so the readers that move it report under ops_s
+        assert {"tail.update_p95_ms", "client.write1_p50_ms.ops", "verifier.items_per_flush.ops",
+                "store.fsyncs_per_update.ops"} <= set(result["metrics"])
+        assert "client.write1_p50_ms" not in result["metrics"]
+        # device readings are the chip's: a rehearsal prints none
+        assert "recovery.device_busy_share" not in result["metrics"]
+        assert result["metrics"]["recovery.replay_entries"]["value"] >= 240 * 4 / 5 * 0.5
+    assert result["checks"]["replay_entries_convicted"] == {"value": 0, "limit": 0, "rule": "<="}
+
+
 @pytest.mark.parametrize("control,failed_check", [
     ("accept-all", "bad_write2_accepted_by_replicas"),
     ("stale-reads", "window_stale_reads"),

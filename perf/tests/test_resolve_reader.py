@@ -44,5 +44,5 @@ def test_the_entry_names_the_layer_and_metric_the_queue_wait_does():
     mine, beside = by_name[NAME], by_name["verifier.queue_wait_ms"]
     # PR 26 keyed both to the cells whose windows send the service a verify RPC
     # (a read-only cell's service records neither span)
-    assert mine["workloads"] == ["n64-ycsb-a", "rf4-ycsb-a", "rf4-recover"]
+    assert mine["workloads"] == ["n64-ycsb-a", "rf4-ycsb-a", "rf4-recover", "rf4-50k-recover"]
     assert {k: mine[k] for k in mine if k != "name"} == {k: beside[k] for k in beside if k != "name"}

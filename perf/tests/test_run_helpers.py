@@ -1,11 +1,17 @@
 """The harness's own waiting and sizing logic, without a cluster."""
 
 import asyncio
+import json
+import os
 
 import pytest
 
 import probe
 import run
+import schedule
+
+PERF = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+REPO = os.path.dirname(PERF)
 
 
 def status(ladder, comb, min_device=384):
@@ -91,8 +97,65 @@ def test_warm_sizes_cover_every_doubling_between_the_ends(monkeypatch):
     ("n64-ycsb-c", (384, {512, 8192}, 43, 8, 0), 688),       # nothing updates: only what the load can pile up
     ("rf4-ycsb-a", (384, {512, 8192}, 3, 32, 32), 0),        # 3-grant certificates reach nothing
     ("rf4-recover", (384, {512, 8192}, 3, 32, 32), 0),
+    # a replay of more signatures than the memo holds reaches every bucket, as n64's callers do
+    ("rf4-50k-recover", (384, {512, 8192}, 3, 32, 32, 120_000), 8192),
+    ("a replay that stays under the crossover", (384, {512, 8192}, 3, 32, 32, 383), 0),
     ("no program of both kinds", (384, set(), 43, 8, 16), 0),
     ("a service that routes nothing to the device", (0, {512}, 43, 8, 16), 0),
 ])
 def test_the_warm_up_offers_what_the_mix_and_the_load_can_reach(cell, args, reach):
     assert run.warm_reach(*args) == reach
+
+
+KILL1 = os.path.join(PERF, "traffic", "ycsb-a-kill1.json")
+
+
+def shipped(name):
+    with open(os.path.join(PERF, "configs", name + ".json")) as fh:
+        return json.load(fh)
+
+
+def verbs_of(mix_path):
+    with open(mix_path) as fh:
+        return schedule.validate(json.load(fh)["faults"], os.path.join(PERF, "faults"))
+
+
+@pytest.mark.parametrize("config,change,items", [
+    ("rf4-n5-50k", {}, 50_000 * 4 // 5 * 3),              # 120,000 grants against a memo of 65,536
+    ("rf4-n5-50k", {"recordcount": 27_307}, 0),           # 21,845 certificates, 65,535 grants: the memo holds them all
+    ("rf4-n5-50k", {"recordcount": 27_308}, 65_538),      # one certificate more than it holds
+    ("rf4-n5-50k", {"memo_items": None}, 0),
+    ("rf4-n5", {}, 0),                                    # states no memo: no replay reach
+    ("rf4-n5", {"recordcount": 10**6}, 0),
+    ("n64-f21", {}, 0),
+])
+def test_the_replay_reaches_the_device_where_a_replicas_certificates_outnumber_the_stated_memo(config, change, items):
+    assert run.replay_items(dict(shipped(config), **change), verbs_of(KILL1)) == items
+
+
+def test_a_mix_that_restarts_nothing_has_no_replay_reach():
+    assert run.replay_items(shipped("rf4-n5-50k"), []) == 0
+    # and the rehearsal's shape of the new configuration stays under its memo
+    config = shipped("rf4-n5-50k")
+    assert run.replay_items(dict(config, **config["rehearsal"]), verbs_of(KILL1)) == 0
+
+
+@pytest.mark.parametrize("cell,reach", [("n64-ycsb-a", 8192), ("n64-ycsb-c", 688), ("rf4-ycsb-a", 0),
+                                        ("rf4-recover", 0), ("rf4-50k-recover", 8192)])
+def test_the_shipped_cells_are_offered_what_they_were_and_the_new_one_every_size(cell, reach):
+    # as ``run_cell`` calls it, on the service's shipped crossover and boot buckets
+    data = run.load_cell(REPO, cell)
+    config, traffic = data["config"], data["traffic"]
+    writers = config["threads"] if float(traffic["updateproportion"]) > 0 else 0
+    assert run.warm_reach(384, {512, 8192}, config["quorum"], config["load_threads"], writers,
+                          run.replay_items(config, data["verbs"])) == reach
+
+
+def test_a_schedules_trace_starts_at_the_restart_command_and_any_other_at_the_windows_end():
+    events = schedule.bind(json.load(open(KILL1))["faults"], verbs_of(KILL1), 7, 30.0, 5, 1,
+                           {f"server-{i}": i for i in range(5)})
+    assert [e["do"] for e in events] == ["kill_replica", "restart_replica"]
+    assert run.trace_from_s(events, 30.0, run.TRACE_SECONDS) == 10.0
+    assert run.trace_from_s([], 30.0, run.TRACE_SECONDS) == 25.0
+    # a schedule none of whose verbs brings an end-to-end metric is traced as a cell without one
+    assert run.trace_from_s(events[:1], 30.0, run.TRACE_SECONDS) == 25.0

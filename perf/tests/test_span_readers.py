@@ -6,6 +6,7 @@ import pytest
 
 import hostspans
 import run
+import canned_faults as canned
 from test_data_driven import REPO, SNAP
 
 
@@ -90,3 +91,69 @@ def test_nothing_to_read_is_nothing_reported():
     got = read("rf4-ycsb-a", dict(SNAP, platform="tpu", host_spans={"window": one_tick}))
     assert "service.loop_cpu_share" not in got and "service.rpc_ms" in got
     assert "kernel.ladder_ms" not in got and "verifier.device_us_per_item" in got  # no probe trace here
+
+
+# ------------------------------------------- the recovery's own two readers
+
+RECOVERY_CELLS = ["rf4-recover", "rf4-50k-recover"]
+
+
+def replayed(device_items, flushed_ms=None, trace=None):
+    """A kill and a restart whose replay sent ``device_items`` signatures to the
+    device; ``flushed_ms``: the service's flush-device timer (before, after)."""
+    faults = canned.records()
+    back = faults[1]
+    back["after"] = dict(back["after"], service=dict(canned.SERVICE1, device_items=device_items))
+    if flushed_ms is not None:
+        for look, ms in zip(("before", "after"), flushed_ms):
+            timers = {"verifier.flush-host": {"count": 9, "sum_ms": 7.0}}
+            if ms is not None:
+                timers["verifier.flush-device"] = {"count": 3, "sum_ms": ms}
+            back[look] = dict(back[look], service_stages={"timers": timers, "counters": {}})
+    return dict(SNAP, platform="tpu", faults=faults, cluster={"quorum": 3}, trace=trace or {})
+
+
+@pytest.mark.parametrize("snap,expect", [
+    (replayed(76_800, (100.0, 2020.0)), 25.0),       # 1.92 s of device-route flushes for 76,800 items
+    (replayed(768, (None, 19.2)), 25.0),             # the timer had never ticked before the restart
+    (replayed(0, (100.0, 100.0)), None),             # rf4-recover's shape: the memo answered the replay
+    (replayed(768), None),                           # a look without the stage timers
+    (dict(SNAP, platform="tpu"), None),              # a cell without a schedule
+])
+def test_the_replays_device_route_is_timed_per_item_where_it_took_it(snap, expect):
+    got = read("rf4-50k-recover", snap).get("recovery.device_us_per_item")
+    assert got == (pytest.approx(expect) if expect is not None else None)
+    # keyed to the cell whose replay reaches the device: rf4-recover's never does, and a
+    # listed cell has to report what it lists
+    assert "recovery.device_us_per_item" not in read("rf4-recover", snap)
+
+
+@pytest.mark.parametrize("cell", RECOVERY_CELLS)
+@pytest.mark.parametrize("trace,platform,expect", [
+    # the restart's command came 10.02 s into the window and READY 4 s later
+    ({"window_s": 5.0, "busy_s": 1.25, "started_s": 10.01}, "tpu", 25.0),
+    ({"window_s": 5.0, "busy_s": 0.0, "started_s": 10.01}, "tpu", 0.0),     # the memo answered it
+    ({"window_s": 5.0, "busy_s": 1.25, "started_s": 25.0}, "tpu", None),    # a trace of the window's end
+    ({"window_s": 5.0, "busy_s": 1.25}, "tpu", None),                       # nobody said when it started
+    ({"window_s": 5.0, "busy_s": 1.25, "started_s": 10.01}, "cpu", None),
+])
+def test_device_busy_share_reads_the_trace_that_covers_the_recovery(cell, trace, platform, expect):
+    snap = dict(replayed(768), platform=platform, trace={"window": trace})
+    got = read(cell, snap).get("recovery.device_busy_share")
+    assert got == (pytest.approx(expect) if expect is not None else None)
+    # and none of it is there where nothing was restarted
+    assert "recovery.device_busy_share" not in read(cell, dict(snap, faults=[]))
+
+
+def test_the_new_cell_reports_what_rf4_recover_reports_for_its_recovery():
+    bench = run.load_cell(REPO, "rf4-50k-recover")["bench"]
+    keyed = {m["name"] for m in bench["per_layer"] + bench["end_to_end"]
+             if "rf4-recover" in m.get("workloads", ())}
+    both = {m["name"] for m in bench["per_layer"] + bench["end_to_end"]
+            if "rf4-50k-recover" in m.get("workloads", ())}
+    # all of them but the update tail, which is end to end only in a cell quiet enough for its bound
+    assert keyed - both == {"update_p95_ms"}
+    assert both - keyed == {m + ".ops" for m in (
+        "client.write1_p50_ms", "client.write2_wait_p50_ms", "verifier.items_per_flush",
+        "verifier.device_item_share", "device.idle_share", "store.fsyncs_per_update")} | {
+            "tail.update_p95_ms", "recovery.device_us_per_item"}
