@@ -41,6 +41,7 @@ the store's batch loop turn are pure in-memory appends.
 from __future__ import annotations
 
 import asyncio
+import collections
 import hashlib
 import hmac
 import logging
@@ -52,6 +53,7 @@ from typing import Dict, List, Optional, Tuple
 
 from ..protocol import SyncEntry, Transaction, WriteCertificate, transaction_hash
 from ..protocol.codec import encode as _codec_encode
+from ..verifier.service import RemoteVerifier
 from ..verifier.spi import VerifyItem
 from . import wal
 from .spi import StorageEngine
@@ -65,6 +67,18 @@ _SNAP_HEADER = struct.Struct("<I")  # crc32 of the doc blob
 # contributes ~quorum VerifyItems, so 128 entries ≈ 384-512 signatures per
 # batch — comfortably inside the batch engine's sweet spot.
 REPLAY_CHUNK = 128
+# A snapshot's data entries go a request's worth of signatures a chunk: what
+# ONE request of the replica's verifier chain carries, so the service's
+# flushes stay full and no chunk is cut in two on its way.
+REPLAY_REQUEST_ITEMS = RemoteVerifier.MAX_REQUEST_ITEMS
+# Chunks planned (their verdicts requested) and not yet applied, at most: the
+# verifier answers two while the replica applies a third.  The bound on the
+# VerifyItems alive at once, too.
+REPLAY_DEPTH = 3
+# The replay's loops (decode, apply) hand the event loop a turn once they
+# have held it this long: a request is sent, and a verdict read, on loop
+# turns, and the replica serves nobody before recover() returns.
+REPLAY_TURN_S = 0.002
 # Bounded per-entry attribution (the admin surface renders these).
 CONVICTIONS_MAX = 64
 
@@ -138,6 +152,97 @@ def unframe_snapshot(data: bytes) -> bytes:
     return blob
 
 
+async def _turn(held_since: float) -> float:
+    """Hand the event loop one turn where the caller has held it for
+    ``REPLAY_TURN_S``; returns since when the caller holds it now."""
+    if time.perf_counter() - held_since < REPLAY_TURN_S:
+        return held_since
+    await asyncio.sleep(0)
+    return time.perf_counter()
+
+
+class _ReplayPipeline:
+    """A verified replay's bounded look-ahead.  ``submit`` plans a chunk of
+    commits from ``store.cert_config`` and asks the verifier for its verdict
+    as a task; chunks are applied strictly in the order submitted, each only
+    on its own verdict, and the oldest is applied once ``REPLAY_DEPTH``
+    chunks are planned and not applied: never more exist, and the verifier
+    answers the next ones while the replica applies this one (at a depth of
+    1, or where one chunk is all there is, plan, verify and apply follow
+    one another).  ``drain`` applies everything submitted: whatever changes
+    what a later chunk is planned from (a config install) or must land in
+    log order (a reclaim) waits for it.  Leaving the block drains; leaving
+    it on an exception or a cancellation cancels every verdict still asked
+    for and waits for the tasks, so none outlives the replay and nothing
+    of an unanswered chunk is applied."""
+
+    def __init__(
+        self, engine, store, verifier,
+        convict_stale: bool = True, attribute: bool = True,
+    ):
+        self._engine = engine
+        self._store = store
+        self._verifier = verifier
+        self._convict_stale = convict_stale
+        self._attribute = attribute
+        self._pending: collections.deque = collections.deque()  # (preps, verdict task)
+
+    async def __aenter__(self) -> "_ReplayPipeline":
+        return self
+
+    async def __aexit__(self, exc_type, exc, tb) -> None:
+        try:
+            if exc_type is None:
+                await self.drain()
+        finally:
+            await self._abandon()  # nothing, after a drain that ended
+
+    async def submit(self, batch) -> None:
+        if not batch:
+            return
+        items, preps = self._engine._plan(self._store, batch)
+        verdict = (
+            asyncio.ensure_future(
+                self._engine._verify(self._verifier, items, time.perf_counter())
+            )
+            if items
+            else None
+        )
+        self._pending.append((preps, verdict))
+        # one turn of the loop: the request is with the verifier (its
+        # ``verify_batch`` entered) before anything older is applied
+        await asyncio.sleep(0)
+        if len(self._pending) >= REPLAY_DEPTH:
+            await self._apply_oldest()
+
+    async def drain(self) -> None:
+        while self._pending:
+            await self._apply_oldest()
+
+    async def _apply_oldest(self) -> None:
+        preps, verdict = self._pending[0]
+        bitmap: List[bool] = []
+        if verdict is not None:
+            blocked_at = time.perf_counter()
+            bitmap, answered_at = await verdict
+            # blocked, with nothing left to apply, until the verdict was in
+            self._engine._replay_add(
+                "verify_wait_ms", max(0.0, answered_at - blocked_at) * 1e3
+            )
+        self._pending.popleft()
+        await self._engine._apply(
+            self._store, preps, bitmap, self._convict_stale, self._attribute
+        )
+
+    async def _abandon(self) -> None:
+        verdicts = [v for _preps, v in self._pending if v is not None]
+        self._pending.clear()
+        for verdict in verdicts:
+            verdict.cancel()
+        if verdicts:
+            await asyncio.gather(*verdicts, return_exceptions=True)
+
+
 class DurableStorage(StorageEngine):
     """One replica's durable engine (``MochiReplica(storage_dir=...)``)."""
 
@@ -209,6 +314,12 @@ class DurableStorage(StorageEngine):
             "skipped_unowned": 0,
             "torn_tail": False,
             "ms": 0.0,
+            # the verifier's part: requests, their summed issue-to-verdict
+            # time, and how much of that the replay spent blocked on a
+            # verdict (the rest was hidden under decode and apply)
+            "verify_calls": 0,
+            "verify_rtt_ms": 0.0,
+            "verify_wait_ms": 0.0,
         }
         self._convictions: List[Dict[str, object]] = []
         self._convicted_keys: set = set()
@@ -563,40 +674,56 @@ class DurableStorage(StorageEngine):
         second pass, so "did not advance" is not evidence here — "the
         verified replay refused to adopt this entry's transaction" is.
         Finally the per-key epoch marks are adopted upward-only."""
-        def entries_of(objs):
-            out = []
-            for obj in objs:
-                key, _value, _exists, cert, txn, _epoch = obj
-                if cert is None or txn is None:
-                    continue
-                try:
-                    out.append(
-                        SyncEntry(
-                            key,
-                            Transaction.from_obj(txn),
-                            WriteCertificate.from_obj(cert),
-                        )
-                    )
-                except Exception:
-                    self._convict(None, key, None, "undecodable snapshot entry")
-            return out
+        def entry_of(obj) -> Optional[SyncEntry]:
+            key, _value, _exists, cert, txn, _epoch = obj
+            if cert is None or txn is None:
+                return None
+            try:
+                return SyncEntry(
+                    key,
+                    Transaction.from_obj(txn),
+                    WriteCertificate.from_obj(cert),
+                )
+            except Exception:
+                self._convict(None, key, None, "undecodable snapshot entry")
+                return None
 
-        config_entries = entries_of(doc.get("data_config", ()))
-        data_entries = entries_of(doc.get("data", ()))
+        def as_batch(entries):
+            return [(None, [e.key], e.transaction, e.certificate) for e in entries]
+
+        config_entries = [
+            e for e in map(entry_of, doc.get("data_config", ())) if e is not None
+        ]
         for pass_no in range(2):
             await self._apply_verified(
                 store,
-                [(None, [e.key], e.transaction, e.certificate) for e in config_entries],
+                as_batch(config_entries),
                 verifier,
                 convict_stale=False,
                 attribute=pass_no == 1,
             )
-        await self._apply_verified(
-            store,
-            [(None, [e.key], e.transaction, e.certificate) for e in data_entries],
-            verifier,
-            convict_stale=False,
-        )
+        # The data pass, in the snapshot's own order: a chunk is decoded,
+        # planned and its verdict asked for while the chunks before it are
+        # verified and applied, so the first request leaves after the first
+        # chunk is decoded and the verification hides under decode and apply.
+        data_entries: List[SyncEntry] = []
+        async with _ReplayPipeline(
+            self, store, verifier, convict_stale=False
+        ) as pipe:
+            chunk_from, signatures = 0, 0
+            held = time.perf_counter()
+            for obj in doc.get("data", ()):
+                held = await _turn(held)
+                entry = entry_of(obj)
+                if entry is None:
+                    continue
+                grants = len(entry.certificate.grants)
+                if signatures + grants > REPLAY_REQUEST_ITEMS:
+                    await pipe.submit(as_batch(data_entries[chunk_from:]))
+                    chunk_from, signatures = len(data_entries), 0
+                data_entries.append(entry)
+                signatures += grants
+            await pipe.submit(as_batch(data_entries[chunk_from:]))
         for e in config_entries + data_entries:
             if not store.owns(e.key) or e.key in self._convicted_keys:
                 continue
@@ -623,6 +750,14 @@ class DurableStorage(StorageEngine):
                 sv.current_epoch = epoch
 
     async def _replay_wal(self, store, segments, watermark, verifier) -> None:
+        """The log after the snapshot, ``REPLAY_CHUNK`` commits a chunk
+        through one pipeline: applied strictly in log order, the verdicts
+        of the next chunks asked for while this one is applied.  Leaving
+        the block is the drain at the end of the log."""
+        async with _ReplayPipeline(self, store, verifier) as pipe:
+            await self._replay_records(store, segments, watermark, pipe)
+
+    async def _replay_records(self, store, segments, watermark, pipe) -> None:
         from ..cluster.config import CONFIG_KEY_PREFIX
 
         last_index = segments[-1][0] if segments else 0
@@ -666,28 +801,30 @@ class DurableStorage(StorageEngine):
                         continue
                     if any(k.startswith(CONFIG_KEY_PREFIX) for k in keys):
                         # a config install changes signer keys and ownership
-                        # for everything after it: drain, then apply alone
-                        if batch:
-                            await self._apply_verified(store, batch, verifier)
-                            batch = []
-                        await self._apply_verified(store, [item], verifier)
+                        # for everything after it: drain the whole pipeline
+                        # (no later chunk may be planned before the install
+                        # is applied), then apply it alone
+                        await pipe.submit(batch)
+                        batch = []
+                        await pipe.drain()
+                        await pipe.submit([item])
+                        await pipe.drain()
                         continue
                     batch.append(item)
                     if len(batch) >= REPLAY_CHUNK:
-                        await self._apply_verified(store, batch, verifier)
+                        await pipe.submit(batch)
                         batch = []
                 elif rec.rtype == wal.RT_RECLAIM:
                     # ordering: reclaims interleave with commits; drain the
-                    # pending commit chunk first so the epoch bump lands
-                    # after the commits that preceded it in the log
-                    if batch:
-                        await self._apply_verified(store, batch, verifier)
-                        batch = []
+                    # pipeline first so the epoch bump lands after the
+                    # commits that preceded it in the log
+                    await pipe.submit(batch)
+                    batch = []
+                    await pipe.drain()
                     self._replay_reclaim(store, rec)
                 else:
                     self._convict(rec.seq, None, None, f"unknown record type {rec.rtype}")
-        if batch:
-            await self._apply_verified(store, batch, verifier)
+        await pipe.submit(batch)
         self._seq = max(self._seq, prev_seq)
         self._written_seq = self._synced_seq = self._seq
 
@@ -740,16 +877,28 @@ class DurableStorage(StorageEngine):
         convict_stale: bool = True,
         attribute: bool = True,
     ) -> None:
-        """One pooled verify round trip for a chunk of replay commits
-        (``(seq, keys, transaction, certificate)`` tuples), then
+        """One chunk of replay commits (``(seq, keys, transaction,
+        certificate)`` tuples) through the verified path, start to end: a
+        pipeline of one, so plan, ONE pooled verify round trip, then
         store-level validation per entry (quorum, hash, staleness) via the
         full Write2 path.  ``convict_stale=False`` for snapshot entries
         (adoption is audited post-pass instead); ``attribute=False`` for
         the snapshot's config warm-up pass, whose failures are expected
         (the archive chain may not be learnable yet) and re-judged on the
         second pass."""
-        if not batch:
-            return
+        async with _ReplayPipeline(
+            self, store, verifier, convict_stale, attribute
+        ) as pipe:
+            await pipe.submit(batch)
+
+    def _replay_add(self, key: str, ms: float) -> None:
+        self._replay[key] = round(float(self._replay[key]) + ms, 2)
+
+    def _plan(self, store, batch):
+        """What a chunk asks the verifier: every grant signature its
+        certificates carry, under the keys of the configuration each
+        certificate is judged against AS THE STORE STANDS NOW (so nothing
+        is planned across a config install that is not applied yet)."""
         items: List[VerifyItem] = []
         preps = []
         for seq, keys, txn, cert in batch:
@@ -765,8 +914,26 @@ class DurableStorage(StorageEngine):
                 idx.append(i)
                 items.append(VerifyItem(key, mg.signing_bytes(), mg.signature))
             preps.append((seq, keys, txn, cert, server_ids, idx, start))
-        bitmap = await verifier.verify_batch(items) if items else []
+        return items, preps
+
+    async def _verify(self, verifier, items: List[VerifyItem], issued_at: float):
+        """The one ``verify_batch`` of a chunk, timed from its issue (the
+        pipeline's ``submit``) to the verdict; returns the verdict and when
+        it was in."""
+        bitmap = await verifier.verify_batch(items)
+        answered_at = time.perf_counter()
+        self._replay["verify_calls"] = int(self._replay["verify_calls"]) + 1
+        self._replay_add("verify_rtt_ms", (answered_at - issued_at) * 1e3)
+        return bitmap, answered_at
+
+    async def _apply(
+        self, store, preps, bitmap, convict_stale: bool, attribute: bool
+    ) -> None:
+        """A planned chunk on its verdict: convictions, then the store's own
+        validation per entry; synchronous but for the loop's turns."""
+        held = time.perf_counter()
         for seq, keys, txn, cert, server_ids, idx, start in preps:
+            held = await _turn(held)
             valid = [False] * len(server_ids)
             for j, i in enumerate(idx):
                 valid[i] = bool(bitmap[start + j])

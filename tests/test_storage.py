@@ -24,6 +24,8 @@ import os
 import shutil
 import tempfile
 
+import pytest
+
 from mochi_tpu.client.txn import TransactionBuilder
 from mochi_tpu.protocol import SyncEntry
 from mochi_tpu.storage import wal
@@ -551,6 +553,458 @@ def test_idempotent_reapply_not_restaged():
 
     with tempfile.TemporaryDirectory() as td:
         asyncio.run(asyncio.wait_for(body(td), timeout=120))
+
+
+# ------------------------------------- the verified replay as a pipeline
+#
+# ``recover`` asks the verifier for the next chunks' verdicts while it applies
+# this one (``storage/durable.py`` ``_ReplayPipeline``, ``REPLAY_DEPTH``).
+# These replay one frozen directory DIRECTLY (a bare ``DataStore`` and an
+# engine, no cluster) under a verifier that records what it was asked and
+# when, at chunk sizes small enough for a dozen commits to be several chunks.
+
+
+class _RecordingVerifier:
+    """The real CPU verdicts, with every request's issue and verdict put on
+    ``events``; ``gate`` holds every verdict back until it is set, and the
+    ``fail_on``-th request (from 0) raises ``exc`` instead of answering."""
+
+    def __init__(self, events, gate=None, fail_on=None, exc=None):
+        from mochi_tpu.verifier.spi import CpuVerifier
+
+        self.inner = CpuVerifier()
+        self.events = events
+        self.gate = gate
+        self.fail_on = fail_on
+        self.exc = exc
+        self.calls = 0
+        self.unanswered = 0
+        self.most_unanswered = 0
+
+    async def verify_batch(self, items):
+        call = self.calls
+        self.calls += 1
+        self.events.append(("issue", call))
+        self.unanswered += 1
+        self.most_unanswered = max(self.most_unanswered, self.unanswered)
+        try:
+            if self.gate is not None:
+                await self.gate.wait()
+            await asyncio.sleep(0.002)  # a round trip is never free
+            if call == self.fail_on:
+                raise self.exc
+            return await self.inner.verify_batch(items)
+        finally:
+            self.unanswered -= 1
+            self.events.append(("verdict", call))
+
+
+async def _frozen_dir(td, n=14, snapshot_after=None, reconfigure_after=None):
+    """A replica's storage directory as a crash would leave it after ``n``
+    single-key commits (``sk0``..), and the config it booted under; with
+    ``snapshot_after`` the first so-many are in a snapshot and the rest in
+    the log after it; with ``reconfigure_after`` a configuration change
+    (same members, the next configstamp) is committed at that point."""
+    vc, client = await _populated(td, n=0)
+    try:
+        boot_config = vc.config
+        victim = vc.replica("server-1")
+        for i in range(n):
+            if i == snapshot_after:
+                await victim.storage.snapshot(victim.store)
+            if i == reconfigure_after:
+                servers = {
+                    r.server_id: f"{vc.host}:{r.bound_port}" for r in vc.replicas
+                }
+                await client.reconfigure_cluster(vc.config.evolve(servers))
+            await client.execute_write_transaction(
+                TransactionBuilder().write(f"sk{i}", b"v%d" % i).build()
+            )
+        await victim.storage.flush()
+        return _freeze_storage(td, "server-1"), boot_config
+    finally:
+        await vc.close()
+
+
+def _bare_store(config, events=None):
+    """A store as the replica boots it, with the replica's own config
+    install hook in miniature and, with ``events``, every apply recorded."""
+    from mochi_tpu.cluster.config import ClusterConfig
+    from mochi_tpu.server.store import DataStore
+
+    store = DataStore("server-1", config)
+
+    def install(blob):
+        new = ClusterConfig.from_json(blob.decode())
+        if new.configstamp > store.config.configstamp:
+            store.note_config(store.config)
+            store.config = new
+            store.note_config(new)
+            if events is not None:
+                events.append(("installed", new.configstamp))
+
+    store.on_config_value = install
+    if events is not None:
+        apply = store.apply_sync_entry
+
+        def recorded(entry):
+            events.append(("apply", entry.key))
+            return apply(entry)
+
+        store.apply_sync_entry = recorded
+    return store
+
+
+async def _direct_replay(frozen, config, verifier=None, events=None, engine=None):
+    """Replay a copy of ``frozen`` into a bare store; returns ``(store,
+    engine, report)``."""
+    from mochi_tpu.storage.durable import DurableStorage
+
+    work = tempfile.mkdtemp(prefix="replay-", dir=os.path.dirname(frozen))
+    directory = os.path.join(work, "server-1")
+    shutil.copytree(frozen, directory)
+    store = _bare_store(config, events)
+    eng = (engine or DurableStorage)(directory, "server-1", fsync="off")
+    report = await eng.recover(store, verifier=verifier)
+    return store, eng, report
+
+
+def _store_image(store):
+    from mochi_tpu.protocol import transaction_hash
+
+    def of(space):
+        return {
+            k: (
+                sv.value, sv.exists, sv.current_epoch,
+                transaction_hash(sv.last_transaction)
+                if sv.last_transaction is not None else None,
+            )
+            for k, sv in space.items()
+        }
+
+    return of(store.data), of(store.data_config), dict(store.reclaimed)
+
+
+def _report_image(report):
+    """A replay report without its times; the convictions as a set (the
+    scan runs ahead of the apply, so their order follows the depth)."""
+    counts = {
+        k: v for k, v in report.items()
+        if k != "convictions" and not k.endswith("ms")
+    }
+    convictions = sorted(
+        (str(c["seq"]), str(c["key"]), str(c["txh"]), c["reason"])
+        for c in report["convictions"]
+    )
+    return counts, convictions
+
+
+def _small_chunks(monkeypatch, depth=None):
+    from mochi_tpu.storage import durable
+
+    monkeypatch.setattr(durable, "REPLAY_CHUNK", 2)  # certificates, the log
+    monkeypatch.setattr(durable, "REPLAY_REQUEST_ITEMS", 8)  # signatures, a snapshot
+    if depth is not None:
+        monkeypatch.setattr(durable, "REPLAY_DEPTH", depth)
+    return durable.REPLAY_DEPTH
+
+
+def _run(coro_of_td):
+    with tempfile.TemporaryDirectory() as td:
+        return asyncio.run(asyncio.wait_for(coro_of_td(td), timeout=120))
+
+
+@pytest.mark.parametrize("depth", [1, None], ids=["depth-1", "shipped-depth"])
+def test_replay_requests_ahead_applies_in_order_within_its_bound(monkeypatch, depth):
+    """(a) Order and bound.  With the verdicts held back, exactly
+    ``REPLAY_DEPTH`` requests are out and nothing is applied; released, every
+    chunk is applied in log order, request k+1 is with the verifier before
+    chunk k's apply begins (at the shipped depth; at depth 1 never), and no
+    more than the depth are ever unanswered."""
+    depth = _small_chunks(monkeypatch, depth)
+
+    async def body(td):
+        frozen, config = await _frozen_dir(td, n=14)  # 7 chunks of 2
+        events = []
+        gate = asyncio.Event()
+        verifier = _RecordingVerifier(events, gate=gate)
+        task = asyncio.ensure_future(
+            _direct_replay(frozen, config, verifier, events)
+        )
+        await asyncio.sleep(0.2)
+        assert verifier.unanswered == depth, events
+        assert not [e for e in events if e[0] == "apply"], events
+        gate.set()
+        store, _eng, report = await task
+        assert report["convicted"] == 0 and report["entries"] == 14, report
+        assert report["verify_calls"] == 7, report
+        assert verifier.most_unanswered == depth
+        applied = [e[1] for e in events if e[0] == "apply"]
+        assert applied == [f"sk{i}" for i in range(14)], applied
+        # request k+1 (chunk k+1 = keys 2k+2, 2k+3) against chunk k's apply
+        ahead = 0
+        for k in range(6):
+            issued = events.index(("issue", k + 1))
+            begins = events.index(("apply", f"sk{2 * k}"))
+            ends = events.index(("apply", f"sk{2 * k + 1}"))
+            if depth > 1:
+                assert issued < begins, (k, events)
+            ahead += issued < ends
+        assert ahead == (6 if depth > 1 else 0), events
+        # never applied on an unanswered verdict
+        for k in range(7):
+            assert events.index(("verdict", k)) < events.index(("apply", f"sk{2 * k}"))
+        # (f) the counters that say so
+        assert 0 <= report["verify_wait_ms"] <= report["verify_rtt_ms"], report
+        assert store._get("sk13").value == b"v13"
+
+    _run(body)
+
+
+def _forge_signature(records):
+    for mg_obj in _last_data_commit(records)[2][2].values():
+        mg_obj[3] = b"\x00" * 64
+
+
+def _thin_certificate(records):
+    cert = records[len(records) // 2][2][2]  # a commit in mid-log
+    for sid in list(cert)[2:]:
+        del cert[sid]  # 2 grants of 3: under quorum
+
+
+def _reorder(records):
+    mid = len(records) // 2
+    records[mid], records[mid + 1] = records[mid + 1], records[mid]
+
+
+def _tear_tail(directory):
+    _index, path = wal.list_segments(directory)[-1]
+    with open(path, "rb+") as fh:
+        fh.truncate(os.path.getsize(path) - 11)
+
+
+@pytest.mark.parametrize(
+    "damage",
+    [None, _forge_signature, _thin_certificate, _reorder, _tear_tail],
+    ids=["sound", "forged-signature", "thinned-certificate", "reordered", "torn-tail"],
+)
+def test_replay_is_the_same_at_any_depth(monkeypatch, damage):
+    """(b) Equivalence.  One directory (a snapshot of 6 keys and a log of 10
+    commits after it), replayed with no look-ahead and with the shipped
+    depth: the same store, the same report's counts, the same convictions."""
+    shipped = _small_chunks(monkeypatch)
+    assert shipped > 1
+
+    async def body(td):
+        from mochi_tpu.storage import durable
+
+        frozen, config = await _frozen_dir(td, n=16, snapshot_after=6)
+        if damage is _tear_tail:
+            damage(frozen)
+        elif damage is not None:
+            _rewrite_last_segment(frozen, "server-1", damage)
+        images = {}
+        for depth in (1, shipped):
+            monkeypatch.setattr(durable, "REPLAY_DEPTH", depth)
+            store, _eng, report = await _direct_replay(frozen, config)
+            images[depth] = (_store_image(store), _report_image(report))
+            assert report["verify_calls"] >= 7, report  # several chunks each
+        assert images[1] == images[shipped]
+        (data, _config, _reclaimed), (counts, convictions) = images[shipped]
+        if damage is None:
+            assert counts["convicted"] == 0 and counts["entries"] == 16, counts
+        elif damage is _tear_tail:
+            assert counts["torn_tail"] and counts["entries"] == 15, counts
+            assert "sk15" not in data or data["sk15"][0] is None
+        else:
+            assert counts["convicted"] >= 1 and convictions, counts
+
+    _run(body)
+
+
+def test_replay_drains_for_a_config_install_and_a_reclaim(monkeypatch):
+    """(c) A config install in mid-log: everything before it is applied
+    before it is asked about, it is applied alone, and nothing after it is
+    planned (its keys read, its verdict asked for) before the new
+    configuration is installed: the certificates formed under the new
+    configstamp are judged against the NEW configuration.  A reclaim record
+    in mid-log drains the same way."""
+    _small_chunks(monkeypatch)
+
+    async def body(td):
+        from mochi_tpu.cluster.config import CONFIG_CLUSTER_KEY
+        from mochi_tpu.server.store import DataStore
+        from mochi_tpu.storage.durable import DurableStorage
+
+        frozen, boot_config = await _frozen_dir(td, n=12, reconfigure_after=5)
+
+        # a reclaim after the 9th data commit, MAC'd with the directory's own
+        # key and bound to its place; the records after it move up by one
+        mac_of = DurableStorage(frozen, "server-1", fsync="off")._reclaim_mac
+
+        def add_reclaim(records):
+            at = next(
+                i for i, r in enumerate(records) if r[2][0] == ["sk9"]
+            )
+            for rec in records[at:]:
+                rec[0] += 1
+            seq = records[at][0] - 1
+            records.insert(at, [
+                seq, wal.RT_RECLAIM,
+                ["rk", 7, b"h" * 32, 3, mac_of(seq, "rk", 7, b"h" * 32, 3)],
+            ])
+
+        _rewrite_last_segment(frozen, "server-1", add_reclaim)
+
+        events = []
+        judged = []
+        cert_config = DataStore.cert_config
+
+        def recorded(self, wc):
+            judged.append((self._cert_stamp(wc), self.config.configstamp))
+            return cert_config(self, wc)
+
+        monkeypatch.setattr(DataStore, "cert_config", recorded)
+        reclaim = DurableStorage._replay_reclaim
+
+        def reclaimed(self, store, rec):
+            events.append(("reclaim", rec.seq))
+            return reclaim(self, store, rec)
+
+        monkeypatch.setattr(DurableStorage, "_replay_reclaim", reclaimed)
+        verifier = _RecordingVerifier(events)
+        store, _eng, report = await _direct_replay(
+            frozen, boot_config, verifier, events
+        )
+        assert report["convicted"] == 0, report
+        assert report["reclaims"] == 1 and report["entries"] >= 14, report
+        assert store.config.configstamp == boot_config.configstamp + 1
+        assert store.reclaimed[("rk", 7)] == b"h" * 32
+        for i in range(12):
+            assert store._get(f"sk{i}").value == b"v%d" % i
+        # no certificate was planned under a configuration older than its own
+        assert judged and all(stamp <= current for stamp, current in judged), judged
+        assert any(stamp == boot_config.configstamp + 1 for stamp, _ in judged)
+
+        def drained_at(i):
+            before = events[:i]
+            issued = sum(1 for e in before if e[0] == "issue")
+            answered = sum(1 for e in before if e[0] == "verdict")
+            return issued == answered
+
+        install = events.index(("apply", CONFIG_CLUSTER_KEY))
+        # alone: its own request is the last one issued before it, every other
+        # verdict was in and applied (sk0..sk4), and nothing followed until
+        # the new configuration stood
+        assert events[install - 2][0] == "issue" and events[install - 1][0] == "verdict"
+        assert drained_at(install - 2)
+        applied_before = [e[1] for e in events[:install] if e[0] == "apply"]
+        assert applied_before == [f"sk{i}" for i in range(5)], applied_before
+        installed = events.index(("installed", boot_config.configstamp + 1))
+        assert not [e for e in events[install:installed] if e[0] == "issue"]
+        at_reclaim = next(i for i, e in enumerate(events) if e[0] == "reclaim")
+        assert drained_at(at_reclaim)
+        applied = [e[1] for e in events[:at_reclaim] if e[0] == "apply"]
+        assert applied[-1] == "sk8" and "sk9" not in applied, applied
+        # sk9..sk11 are two chunks: neither was asked about before the reclaim
+        asked = [e[1] for e in events[:at_reclaim] if e[0] == "issue"]
+        assert max(asked) == verifier.calls - 3, (asked, verifier.calls)
+
+    _run(body)
+
+
+@pytest.mark.parametrize(
+    "exc", [RuntimeError("verifier down"), asyncio.CancelledError()],
+    ids=["raises", "cancelled"],
+)
+def test_replay_look_ahead_failure_leaves_nothing_behind(monkeypatch, exc):
+    """(d) The verifier fails (or is cancelled) on a look-ahead request, the
+    fourth, while earlier chunks are being applied: ``recover`` fails as it
+    always did, the chunks answered before it are applied, nothing of the
+    failed chunk or after it is, and no task outlives the replay."""
+    _small_chunks(monkeypatch)
+
+    async def body(td):
+        frozen, config = await _frozen_dir(td, n=14)
+        events = []
+        verifier = _RecordingVerifier(events, fail_on=3, exc=exc)
+        before = asyncio.all_tasks()
+        with pytest.raises(type(exc)):
+            await _direct_replay(frozen, config, verifier, events)
+        await asyncio.sleep(0)
+        assert asyncio.all_tasks() == before, asyncio.all_tasks() - before
+        assert verifier.unanswered == 0
+        applied = [e[1] for e in events if e[0] == "apply"]
+        assert applied == [f"sk{i}" for i in range(6)], applied  # chunks 0-2
+
+    _run(body)
+
+
+def test_replay_falls_back_where_the_service_is_gone(monkeypatch):
+    """(d, the other way out) Behind a ``RemoteVerifier`` whose service
+    answers nothing, every look-ahead request is re-verified by its CPU
+    fallback, as a single request always was: the replay ends sound."""
+    _small_chunks(monkeypatch)
+
+    async def body(td):
+        from mochi_tpu.verifier.service import RemoteVerifier
+        from mochi_tpu.verifier.spi import CoalescingVerifier
+
+        frozen, config = await _frozen_dir(td, n=14)
+        remote = RemoteVerifier("127.0.0.1", 1, timeout_s=0.5)  # nobody listens
+        verifier = CoalescingVerifier(remote)
+        try:
+            _store, _eng, report = await _direct_replay(frozen, config, verifier)
+        finally:
+            await verifier.close()
+        assert report["convicted"] == 0 and report["entries"] == 14, report
+        assert remote.fallback_batches >= 1 and remote.remote_batches == 0
+
+    _run(body)
+
+
+def test_paged_engine_replays_the_same_log_to_the_same_report(monkeypatch):
+    """(e) ``PagedStorage`` inherits the log's path: over the same
+    directory (a log, no pages yet) it reports what the WAL engine does."""
+    _small_chunks(monkeypatch)
+
+    async def body(td):
+        from mochi_tpu.storage.paged import PagedStorage
+
+        frozen, config = await _frozen_dir(td, n=14)
+        _rewrite_last_segment(frozen, "server-1", _forge_signature)
+        wal_store, _e, wal_report = await _direct_replay(frozen, config)
+        paged_store, _e, paged_report = await _direct_replay(
+            frozen, config, engine=PagedStorage
+        )
+        assert _report_image(paged_report) == _report_image(wal_report)
+        assert wal_report["verify_calls"] == 7 and wal_report["convicted"] >= 1
+        assert _store_image(paged_store) == _store_image(wal_store)
+
+    _run(body)
+
+
+def test_replay_report_carries_the_verifier_counters():
+    """(f) ``storage.replay`` (``stats()``, ``/status``) and the replay
+    report carry ``verify_calls``, ``verify_rtt_ms`` and ``verify_wait_ms``:
+    zero on an empty directory, and after a replay the wait is never more
+    than the round trips it is part of."""
+
+    async def body(td):
+        from mochi_tpu.storage.durable import DurableStorage
+
+        empty = DurableStorage(os.path.join(td, "empty"), "server-1", fsync="off")
+        replay = empty.stats()["replay"]
+        assert (replay["verify_calls"], replay["verify_rtt_ms"],
+                replay["verify_wait_ms"]) == (0, 0.0, 0.0), replay
+        frozen, config = await _frozen_dir(td, n=8, snapshot_after=4)
+        _store, eng, report = await _direct_replay(frozen, config)
+        replay = eng.stats()["replay"]
+        assert replay["verify_calls"] == report["verify_calls"] == 2, replay
+        assert 0 <= replay["verify_wait_ms"] <= replay["verify_rtt_ms"] <= replay["ms"]
+        assert replay["verify_rtt_ms"] > 0
+
+    _run(body)
 
 
 # ------------------------------------------------------- analysis hygiene
