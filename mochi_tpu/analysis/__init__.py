@@ -10,9 +10,8 @@ this codebase's failure modes (see docs/ANALYSIS.md):
 * ``protocol-invariants``  — payload registration + quorum-math locality
 
 Programmatic entry point: :func:`mochi_tpu.analysis.core.run`.  The pass is
-wired into tier-1 (``tests/test_static_analysis.py``) and into the bench
-gate (``scripts/standing_rules.py``), so a finding fails CI, not code
-review.
+wired into tier-1 (``tests/test_static_analysis.py``) and
+``scripts/lint.sh``, so a finding fails CI, not code review.
 """
 
 from .core import Finding, RunResult, all_rules, run

@@ -254,8 +254,8 @@ def display_path(fp: str, scan_root: Optional[str] = None) -> str:
     """The path a finding carries (and its fingerprint hashes).
 
     Must be (a) CWD-independent — lint.sh scans from the repo root while
-    scripts/standing_rules.py passes absolute paths from an arbitrary CWD,
-    and a fingerprint mismatch silently un-baselines everything — and
+    another caller may pass absolute paths from an arbitrary CWD, and a
+    fingerprint mismatch silently un-baselines everything — and
     (b) scope-faithful — per-checker scoping looks for components like
     ``crypto/`` and suffixes like ``cluster/config.py``, so a bare-basename
     display would both drop checkers and break exemptions on single-file

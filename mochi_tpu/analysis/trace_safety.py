@@ -13,7 +13,7 @@ of the fused program.
 Scope (``scoped=True``): files under ``crypto/`` and ``parallel/`` — the
 two packages whose code runs under trace.  A function is considered traced
 if it is decorated with a jit-like decorator (``jit``, ``pjit``,
-``pallas_call``, ``partial(jit, ...)``) or if any parameter is annotated as
+``partial(jit, ...)``) or if any parameter is annotated as
 a JAX array (``jnp.ndarray``, ``jax.Array``) — the convention this
 codebase already follows throughout ``crypto/field.py`` / ``curve.py``.
 
@@ -31,7 +31,7 @@ from .core import Finding, build_import_map, dotted_name, resolve_call, snippet_
 
 RULE = "jax-trace-safety"
 
-_JIT_DECORATORS = {"jit", "pjit", "pallas_call", "custom_vjp", "checkpoint"}
+_JIT_DECORATORS = {"jit", "pjit", "custom_vjp", "checkpoint"}
 _ARRAY_ANNOTATIONS = ("jnp.ndarray", "jax.Array", "jax.numpy.ndarray", "Array")
 _STATIC_ATTRS = {"shape", "ndim", "dtype", "size"}
 _HOST_SYNC_CALLS = {"float", "int", "bool", "complex"}

@@ -153,8 +153,8 @@ class MochiDBClient:
     # subset.  Without this, one in-set replica
     # returning a garbage-signed (or wrong-hash) grant inside a validly
     # authenticated envelope poisons the assembled certificate and every
-    # replica rejects the Write2 — a measured liveness hole under the
-    # forge-cert attack (benchmarks/config10_byzantine.py).  Costs one
+    # replica rejects the Write2 — a liveness hole under the forge-cert
+    # attack (tests/test_byzantine_live.py).  Costs one
     # host verify per grant (~0.2 ms native-C), overlapped with the
     # fan-out's network wait.  Kill switch: MOCHI_VERIFY_GRANT_SIGS=0.
     verify_grant_sigs: bool = field(
@@ -176,10 +176,8 @@ class MochiDBClient:
     # python round).  The trimmed targets now come from the suspicion-
     # steered _quorum_targets (round 12): against an UNRESPONSIVE in-set
     # replica the trim no longer wastes a timeout per fan-out once
-    # suspicion converges — the round-12 A/B under the silent adversary
-    # (benchmarks/results_r12.json "trim_write1_ab") measures that
-    # scenario; the honest-loopback loss stands, so the default stays
-    # False — measure per deployment.
+    # suspicion converges; the honest-loopback loss stands, so the
+    # default stays False — measure per deployment.
     trim_write1: bool = False
     # Round-18 fast path (crypto/session.py): MAC'd envelopes get signed
     # checkpoint declarations every CHECKPOINT_MSGS/CHECKPOINT_MS, and
@@ -389,7 +387,7 @@ class MochiDBClient:
         # Timed per target: this is the client's per-envelope serialization
         # cost (payload encode — cached after the first target — plus the
         # MAC/sign), the "fan-out serialization" slice of the commit
-        # breakdown (benchmarks/config6_bigcluster.py).
+        # breakdown.
         with self.metrics.timer("envelope-encode-sign"):
             # Propagate the txn's trace context (round 15) — SAMPLED traces
             # only, so unsampled traffic keeps the exact pre-trace wire

@@ -12,8 +12,7 @@ Division of labor (the TPU-first design, SURVEY.md §7):
   256-step double-scalar-mul, batched over the leading axis.  Oversized
   requests chunk at MAX_BUCKET with every chunk launched before any is
   read back, so chunk k+1's host prepare and transfer overlap chunk k's
-  device execution (measured end-to-end on 64k items: 19.1k sequential ->
-  69.8k sigs/s pipelined+packed, config-2 artifact).
+  device execution.
 
 Batches are padded to power-of-two buckets so XLA compiles a handful of
 program shapes, then caches (SURVEY.md §7: static shapes; first compile
@@ -63,14 +62,11 @@ from ..verifier.spi import VerifyItem
 LOG = logging.getLogger(__name__)
 
 MIN_BUCKET = 16
-# Largest single device launch.  Measured on v5e (bench.py, round 2): 8192
-# lanes is the throughput peak (91k sigs/s sequential, 111k with 4 batches
-# in flight) after the signed-window ladder halved the per-item
-# small-multiples tables and the pad-skew multiply removed the
-# HBM-streaming intermediates; 16384 still spills VMEM, 4096 underfills.
-# Bigger requests are chunked at this size behind a bounded launch window,
-# so rate stays flat instead of regressing.  Tune via MOCHI_MAX_BUCKET
-# without a code change.
+# Largest single device launch: the ladder's per-item small-multiples
+# tables are what a launch holds in VMEM, and past this many lanes they
+# spill.  Bigger requests are chunked at this size behind a bounded launch
+# window, so rate stays flat instead of regressing.  Tune via
+# MOCHI_MAX_BUCKET without a code change.
 def _max_bucket() -> int:
     """MOCHI_MAX_BUCKET, sanitized: >= MIN_BUCKET and a power of two (a
     non-power would chunk at sizes _bucket_size pads PAST the VMEM cap the
@@ -89,13 +85,6 @@ MAX_BUCKET = _max_bucket()
 # O(_PIPELINE_DEPTH * MAX_BUCKET) while chunk k+1's host prepare/transfer
 # overlaps chunk k's device execution.
 _PIPELINE_DEPTH = 4
-
-
-def _impl() -> str:
-    """Device implementation: "xla" (default) or "pallas"
-    (``MOCHI_VERIFY_IMPL=pallas`` — the hand-tiled kernel,
-    :mod:`mochi_tpu.crypto.pallas_verify`)."""
-    return os.environ.get("MOCHI_VERIFY_IMPL", "xla")
 
 
 def _bucket_size(n: int) -> int:
@@ -281,7 +270,6 @@ def prepare_packed(items: Sequence[VerifyItem]):
 # whatever the traced function happens to be called.
 LADDER_PROGRAM = "jit_verify_prepared_packed"
 
-_verify_jit = jax.jit(curve.verify_prepared)
 _verify_packed_jit = jax.jit(
     curve.named_program(curve.verify_prepared_packed, LADDER_PROGRAM)
 )
@@ -450,13 +438,7 @@ def _prepare_padded(
 
 
 def _pack_padded(items: Sequence[VerifyItem], bucket: Optional[int]):
-    use_pallas = _impl() == "pallas"
-    if use_pallas:
-        # The (shelved) Pallas kernel consumes the bit-tensor format;
-        # the XLA path takes packed bytes (scalars decode on device).
-        y_a, sign_a, y_r, sign_r, s_sc, h_sc, pre_ok = prepare(items)
-    else:
-        y_a, sign_a, y_r, sign_r, s_sc, h_sc, pre_ok = prepare_packed(items)
+    y_a, sign_a, y_r, sign_r, s_sc, h_sc, pre_ok = prepare_packed(items)
     n = len(items)
     m = _bucket_size(n) if bucket is None else bucket
     assert m >= n
@@ -468,7 +450,7 @@ def _pack_padded(items: Sequence[VerifyItem], bucket: Optional[int]):
         h_sc = np.pad(h_sc, pad)
         sign_a = np.pad(sign_a, ((0, m - n),))
         sign_r = np.pad(sign_r, ((0, m - n),))
-    return use_pallas, (y_a, sign_a, y_r, sign_r, s_sc, h_sc), pre_ok
+    return (y_a, sign_a, y_r, sign_r, s_sc, h_sc), pre_ok
 
 
 def _dispatch(prepared, device: Optional[jax.Device] = None):
@@ -477,19 +459,14 @@ def _dispatch(prepared, device: Optional[jax.Device] = None):
     A chunk whose prechecks rejected EVERY item (e.g. a flood of
     non-canonical garbage) skips the device entirely — an attacker must
     spend real signing-grade work (canonical encodings) to buy device
-    time; byte noise is absorbed at host precheck rates
-    (scripts/forgery_bench.py measures both)."""
-    use_pallas, args, pre_ok = prepared
+    time; byte noise is absorbed at host precheck rates."""
+    args, pre_ok = prepared
     if not pre_ok.any():
         return None, pre_ok
     _note_dispatch()
     with _stage(stages.DISPATCH, stages.SPAN_DISPATCH):
         if device is not None:
             args = tuple(jax.device_put(a, device) for a in args)
-        if use_pallas:
-            from . import pallas_verify
-
-            return pallas_verify.verify_prepared_pallas(*args), pre_ok
         return _verify_packed_jit(*args), pre_ok
 
 

@@ -50,10 +50,7 @@ this (it never signs — ``MochiProtocol.proto:123``).
 Considered and not built: 5-bit windows (51 windows x 17 entries).  They
 cut the madd count ~20% (128 -> 102) but the basepoint masked-select grows
 from 9x64 to 17x51 terms (+50% select work), tables grow 1.5x, and the
-signed recode needs base-32 carries — net model estimate <10% either way,
-so the A/B budget went to the chain-vs-tree formulation instead
-(``COMB_IMPL``), which attacks the dependency DEPTH the roofline keeps
-flagging rather than the op count.
+signed recode needs base-32 carries — net model estimate <10% either way.
 """
 
 from __future__ import annotations
@@ -329,65 +326,7 @@ class SignerRegistry:
 # --------------------------------------------------------------------------
 # Device kernel
 
-# Accumulation formulation (MOCHI_COMB_IMPL):
-#   "chain" — DEFAULT: fori_loop of 128 Niels mixed additions (fewest
-#             field muls: 64 iterations x 2 madds x 7 muls ~ 900).
-#   "tree"  — all 128 window points materialized at once (signer rows
-#             gathered, basepoint rows selected with ONE one-hot f32
-#             matmul that XLA places on the MXU), converted Niels ->
-#             extended (3 const-muls each, vectorized over the point
-#             axis), then a 7-level balanced reduction with the complete
-#             addition — ~40% more field muls but an ~18x shallower
-#             sequential chain (7 wide adds vs 128 dependent madds) and
-#             ~10x fewer dispatched ops for the scheduler.  Candidate for
-#             the schedule-bound regime the roofline probe keeps
-#             indicating; A/B'd on chip by scripts/comb_bench.py.
-import os as _os
-
-COMB_IMPL = _os.environ.get("MOCHI_COMB_IMPL", "chain")
-
 SCOPE_COMB = "mochi_comb"  # the comb loop, beside curve.py's phase scopes
-
-# (p+1)/2: multiplying by it halves a field element (Niels y+x/y-x -> x,y)
-_INV2_INT = (F.P_INT + 1) // 2
-# 1/(2d): recovers t = x*y from the Niels xy2d coordinate
-_INV_2D_INT = pow(2 * F.D_INT % F.P_INT, F.P_INT - 2, F.P_INT)
-
-
-def _tree_accumulate(ypx, ymx, xy2d, n_points: int, lanes_n):
-    """Balanced-tree sum of ``n_points`` Niels points per lane.
-
-    Coordinates arrive limbs-leading over a fused point*lane axis —
-    (17, P*B) with the point axis MAJOR — signs already applied.  Converts
-    Niels -> extended with 3 constant muls per point (vectorized over the
-    whole P*B width) and reduces with log2(P) wide complete additions.
-    """
-    inv2 = F.const(_INV2_INT, ypx.shape[1:])
-    inv2d = F.const(_INV_2D_INT, ypx.shape[1:])
-    x = F.mul(F.sub(ypx, ymx), inv2)
-    y = F.mul(F.add(ypx, ymx), inv2)
-    t = F.mul(xy2d, inv2d)
-    z = F.one(ypx.shape[1:])
-    pt = curve.Point(x, y, z, t)
-    P = n_points
-    while P > 1:
-        half = P // 2
-        lo = curve.Point(*(
-            c.reshape(F.NLIMBS, P, lanes_n)[:, :half].reshape(
-                F.NLIMBS, half * lanes_n
-            )
-            for c in pt
-        ))
-        hi = curve.Point(*(
-            c.reshape(F.NLIMBS, P, lanes_n)[:, half:].reshape(
-                F.NLIMBS, half * lanes_n
-            )
-            for c in pt
-        ))
-        pt = curve.add(lo, hi)
-        P = half
-    return pt
-
 
 def verify_comb_prepared(
     table_flat: jnp.ndarray,
@@ -396,7 +335,6 @@ def verify_comb_prepared(
     sign_r: jnp.ndarray,
     s_bytes: jnp.ndarray,
     h_bytes: jnp.ndarray,
-    impl: Optional[str] = None,
 ) -> jnp.ndarray:
     """Batched comb verify -> (B,) validity bitmap.
 
@@ -406,11 +344,8 @@ def verify_comb_prepared(
     :func:`mochi_tpu.crypto.curve.verify_prepared`; scalars as (B, 32)
     packed LE bytes.  Public-key validity is the REGISTRY's invariant
     (registration performs the host-side RFC 8032 decode), so the kernel
-    checks only R's decode and the group equation.  ``impl`` selects the
-    accumulation (``chain``/``tree`` — see ``COMB_IMPL``); a STATIC jit
-    arg so A/B runs don't collide in the trace cache.
+    checks only R's decode and the group equation.
     """
-    impl = COMB_IMPL if impl is None else impl
     with jax.named_scope(curve.SCOPE_UNPACK):
         s_dig = curve.digits4_from_bits(curve.unpack_bits(s_bytes).T)
         h_dig = curve.digits4_from_bits(curve.unpack_bits(h_bytes).T)
@@ -421,18 +356,17 @@ def verify_comb_prepared(
         r_point, ok_r = curve.decompress(y_r.T, sign_r)
     lanes = y_r.shape[:1]
     with jax.named_scope(SCOPE_COMB):
-        q = _comb_accumulate(
-            table_flat, key_idx, s_mag, s_neg, h_mag, h_neg, lanes, impl
-        )
+        q = _comb_accumulate(table_flat, key_idx, s_mag, s_neg, h_mag, h_neg, lanes)
     with jax.named_scope(curve.SCOPE_COMPARE):
         eq_x = F.eq(q.x, F.mul(r_point.x, q.z))
         eq_y = F.eq(q.y, F.mul(r_point.y, q.z))
         return ok_r & eq_x & eq_y
 
 
-def _comb_accumulate(table_flat, key_idx, s_mag, s_neg, h_mag, h_neg, lanes, impl):
+def _comb_accumulate(table_flat, key_idx, s_mag, s_neg, h_mag, h_neg, lanes):
     """Q = [S]B + [h](-A) from the signer and basepoint comb tables: the
-    comb loop (or tree) of :func:`verify_comb_prepared`."""
+    comb loop of :func:`verify_comb_prepared`, 128 Niels mixed additions in
+    one ``fori_loop`` (64 iterations x 2 madds x 7 muls ~ 900 field muls)."""
 
     # One upfront row gather for all 64 windows — (64, B, 51) — instead of
     # 64 small in-loop gathers: XLA schedules a single fused gather and the
@@ -442,49 +376,6 @@ def _comb_accumulate(table_flat, key_idx, s_mag, s_neg, h_mag, h_neg, lanes, imp
     a_rows = jnp.take(table_flat, flat_idx, axis=0, mode="clip")
 
     b_tab = jnp.asarray(_b_comb())  # (64, 9, 51) trace-time constant
-
-    if impl == "tree":
-        B = a_rows.shape[1]
-
-        def signed_niels(flat, neg):
-            # flat: (51, N); neg: (N,) bool -> Niels negation applied
-            ypx = flat[: F.NLIMBS]
-            ymx = flat[F.NLIMBS : 2 * F.NLIMBS]
-            xy2d = flat[2 * F.NLIMBS :]
-            return (
-                F.select(neg, ymx, ypx),
-                F.select(neg, ypx, ymx),
-                F.select(neg, F.neg(xy2d), xy2d),
-            )
-
-        a_flat = a_rows.reshape(N_WINDOWS * B, ROW_WIDTH).T  # (51, 64B)
-        aypx, aymx, axy2d = signed_niels(a_flat, h_neg.reshape(-1))
-        # basepoint rows for ALL windows with ONE one-hot matmul: the 0/1
-        # selector and <2^15 limb values are exact in f32, and XLA places
-        # the (64, B, 9) x (64, 9, 51) batched matmul on the MXU.
-        # precision=HIGHEST: TPU's default f32 matmul decomposes through
-        # bf16 passes (8-bit mantissa) that would truncate the 15-bit limb
-        # values — same hazard _mul_mxu (field.py) documents; CPU's
-        # full-f32 default masks it in tests.
-        onehot = (
-            s_mag[:, :, None] == jnp.arange(N_ENTRIES, dtype=jnp.int32)
-        ).astype(jnp.float32)
-        b_rows = jnp.einsum(
-            "wbe,wec->wbc",
-            onehot,
-            b_tab.astype(jnp.float32),
-            precision=lax.Precision.HIGHEST,
-            preferred_element_type=jnp.float32,
-        ).astype(jnp.int32)
-        b_flat = b_rows.reshape(N_WINDOWS * B, ROW_WIDTH).T
-        bypx, bymx, bxy2d = signed_niels(b_flat, s_neg.reshape(-1))
-        return _tree_accumulate(
-            jnp.concatenate([aypx, bypx], axis=1),
-            jnp.concatenate([aymx, bymx], axis=1),
-            jnp.concatenate([axy2d, bxy2d], axis=1),
-            2 * N_WINDOWS,
-            B,
-        )
 
     h_neg_i = h_neg.astype(jnp.int32)
     s_neg_i = s_neg.astype(jnp.int32)
@@ -525,9 +416,7 @@ def _comb_accumulate(table_flat, key_idx, s_mag, s_neg, h_mag, h_neg, lanes, imp
 # cache know it by (pinned here; see batch_verify.LADDER_PROGRAM).
 COMB_PROGRAM = "jit_verify_comb_prepared"
 
-_verify_comb_jit = jax.jit(
-    curve.named_program(verify_comb_prepared, COMB_PROGRAM), static_argnames=("impl",)
-)
+_verify_comb_jit = jax.jit(curve.named_program(verify_comb_prepared, COMB_PROGRAM))
 
 
 # --------------------------------------------------------------------------

@@ -11,9 +11,7 @@ Layout (round-2 rework): **limbs-leading**.  A point is a 4-tuple
 (X, Y, Z, T) of field elements shaped ``(17, ...lanes)``
 (:mod:`mochi_tpu.crypto.field`), with x = X/Z, y = Y/Z, T = XY/Z — batch on
 the trailing lane axis, which is the TPU's 128-wide vector axis, so every
-field op runs on dense lane vectors.  This is the layout the round-1 Pallas
-kernel introduced; now it *is* the XLA path, and the Pallas kernel
-(:mod:`mochi_tpu.crypto.pallas_verify`) wraps the same :func:`verify_core`.
+field op runs on dense lane vectors.
 
 Scalars arrive as little-endian bit arrays precomputed on the host (the host
 also does SHA-512 and the mod-L reduction: variable-length hashing is host
@@ -64,32 +62,9 @@ def named_program(fn, program: str):
     entry.__name__ = entry.__qualname__ = program[len("jit_"):]
     return entry
 
-# Mosaic-safe mode (set by the Pallas kernel wrapper): Mosaic TC lowering has
-# no dynamic_slice on values, so the two data-dependent indexing sites in the
-# ladder (per-window digit extraction, per-slot table write) switch to
-# branchless masked forms, and the small-multiples table is built by 15
-# unrolled additions instead of a fori_loop of dynamic updates.
-MOSAIC_SAFE = False
-
 # Ladder fori_loop unroll factor (1 = loop 64 window bodies; higher trades
 # compile time for a larger per-iteration fusion scope on the VPU).
-# Measured on v5e with scripts/unroll_bench.py before changing.
 LADDER_UNROLL = 1
-
-# Table-select formulation (MOCHI_SELECT_IMPL):
-#   "per-coord" — round-2 form (one masked sum per coordinate array) —
-#                 the DEFAULT: it produced the measured 111k sigs/s
-#                 headline, and the headline capture must run the proven
-#                 config (chip time is scarce; a regressing default could
-#                 burn the only live window).
-#   "stacked"   — ONE masked 9-entry sum per table over the coords
-#                 concatenated on the limb axis ((9, 68|51, lanes)): 9 adds
-#                 + 9 selects per lookup instead of 63 per-coordinate op
-#                 chains; fewer HLO ops for the scheduler to place.
-#                 Candidate, A/B'd by the measurement battery step 3b.
-import os as _os
-
-SELECT_IMPL = _os.environ.get("MOCHI_SELECT_IMPL", "per-coord")
 
 
 class Point(NamedTuple):
@@ -280,21 +255,11 @@ def recode_signed4(dig: jnp.ndarray) -> Tuple[jnp.ndarray, jnp.ndarray]:
 def select_entry(table, idx: jnp.ndarray, n_entries: int):
     """Branchless per-lane table lookup: sum of masked entries.
 
-    ``table``: sequence of arrays with entry axis 0, every coordinate the
-    SAME shape ``(n_entries, 17, lanes)`` or the same lane-constant shape
-    ``(n_entries, 17, 1)`` (the stacked path concatenates them on axis 1,
-    so lane dims may not mix within one table); ``idx``: (lanes,) int32.
-    Data-dependent per-lane gathers don't vectorize on the VPU; n_entries
-    masked adds do.
-
-    With ``SELECT_IMPL == "stacked"`` the coordinate arrays are
-    concatenated on the limb axis first, so the whole lookup is ONE
-    9-term masked sum over a (n_entries, n_coords*17, lanes) array —
-    9 where+add pairs instead of 9*n_coords — then split back.
+    ``table``: sequence of arrays with entry axis 0, each
+    ``(n_entries, 17, lanes)`` or the lane-constant ``(n_entries, 17, 1)``;
+    ``idx``: (lanes,) int32.  Data-dependent per-lane gathers don't
+    vectorize on the VPU; n_entries masked adds do.
     """
-    if SELECT_IMPL == "stacked" and len(table) > 1:
-        stacked, widths = stack_table(table, n_entries, idx.shape)
-        return select_entry_stacked(stacked, widths, idx, n_entries)
     out = []
     for coord in table:
         acc = jnp.zeros_like(coord[0] + jnp.zeros_like(idx))
@@ -304,55 +269,15 @@ def select_entry(table, idx: jnp.ndarray, n_entries: int):
     return tuple(out)
 
 
-def stack_table(table, n_entries: int, lanes):
-    """Concatenate a table's coordinate arrays on the limb axis (hoist this
-    OUTSIDE the ladder loop — the concat would otherwise re-materialize
-    every iteration).
-
-    No lane broadcast here: the basepoint table's coords are (9, 17, 1)
-    lane-constants, and broadcasting them to (9, 17, B) before the concat
-    would materialize a per-lane copy of a constant table (~15 MB at
-    B=8192) that the per-coordinate form never built.  The masked select
-    broadcasts against ``idx`` lazily, exactly as before.
-    """
-    del n_entries, lanes  # shapes come from the coords themselves
-    widths = [c.shape[1] for c in table]
-    return jnp.concatenate(list(table), axis=1), widths
-
-
-def select_entry_stacked(stacked, widths, idx: jnp.ndarray, n_entries: int):
-    """One masked n_entries-term sum over the stacked array, then split."""
-    acc = jnp.zeros(stacked.shape[1:], stacked.dtype)
-    for e in range(n_entries):
-        acc = acc + jnp.where((idx == e)[None], stacked[e], 0)
-    out = []
-    off = 0
-    for w in widths:
-        out.append(acc[off : off + w])
-        off += w
-    return tuple(out)
-
-
 N_TABLE = 9  # [0..8]P — signed 4-bit windows need magnitudes 0..8 only
 
 
 def _small_multiples_table(p: Point):
     """[0..8]P stacked on axis 0 — built by 8 chained additions inside ONE
     fori_loop body (vs unrolled point ops: much smaller traced graph).
-
-    Mosaic-safe mode unrolls the chain and stacks at the end (no dynamic
-    updates); the extra ~70 traced muls are acceptable inside the kernel.
     """
     lanes = p.x.shape[1:]
     ident = identity(lanes)
-    if MOSAIC_SAFE:
-        pts = [ident]
-        for _ in range(N_TABLE - 1):
-            pts.append(add(pts[-1], p))
-        return tuple(
-            jnp.stack([getattr(pt, c) for pt in pts], axis=0)
-            for c in ("x", "y", "z", "t")
-        )
     table = tuple(
         jnp.zeros((N_TABLE, F.NLIMBS, *lanes), jnp.int32).at[0].set(c) for c in ident
     )
@@ -371,71 +296,36 @@ def _small_multiples_table(p: Point):
 
 
 def double_scalar_mul_windowed(
-    s_dig: jnp.ndarray, p_dig: jnp.ndarray, p_point: Point, b_tab=None
+    s_dig: jnp.ndarray, p_dig: jnp.ndarray, p_point: Point
 ) -> Point:
     """[s]B + [p]P with 4-bit windows, msb-first over 64 windows.
 
     ``s_dig``/``p_dig``: (64, lanes) base-16 digits (little-endian windows)
     — recoded internally to signed digits (:func:`recode_signed4`).
-    ``b_tab``: optional externally-supplied Niels basepoint tables — three
-    (9, 17[, 1]) arrays.  Pallas kernels pass them as operands (Mosaic
-    rejects closure-captured array constants); the XLA path leaves this None
-    and embeds them as literals.
     """
     lanes = s_dig.shape[1:]
     s_mag, s_neg = recode_signed4(s_dig)
     p_mag, p_neg = recode_signed4(p_dig)
     a_tab = _small_multiples_table(p_point)
-    if b_tab is None:
-        b_tab = tuple(
-            jnp.asarray(t)[..., None] if lanes else jnp.asarray(t)
-            for t in (_B_TAB_YPX, _B_TAB_YMX, _B_TAB_XY2D)
-        )
+    b_tab = tuple(
+        jnp.asarray(t)[..., None] if lanes else jnp.asarray(t)
+        for t in (_B_TAB_YPX, _B_TAB_YMX, _B_TAB_XY2D)
+    )
 
-    if MOSAIC_SAFE:
-        # No dynamic_slice in Mosaic: extract window w's digits with a
-        # branchless masked reduce over the window axis.
-        win_iota = lax.broadcasted_iota(jnp.int32, (64, *lanes), 0)
-
-        def digit_at(dig, w):
-            return jnp.sum(jnp.where(win_iota == w, dig, 0), axis=0)
-
-    else:
-
-        def digit_at(dig, w):
-            return lax.dynamic_index_in_dim(dig, w, axis=0, keepdims=False)
-
-    if SELECT_IMPL == "stacked":
-        # Hoisted once; each ladder iteration then does ONE 9-term masked
-        # sum per table instead of one per coordinate array.
-        a_stacked, a_widths = stack_table(a_tab, N_TABLE, lanes)
-        b_stacked, b_widths = stack_table(b_tab, N_TABLE, lanes)
-
-        def a_select(idx):
-            return select_entry_stacked(a_stacked, a_widths, idx, N_TABLE)
-
-        def b_select(idx):
-            return select_entry_stacked(b_stacked, b_widths, idx, N_TABLE)
-
-    else:
-
-        def a_select(idx):
-            return select_entry(a_tab, idx, N_TABLE)
-
-        def b_select(idx):
-            return select_entry(b_tab, idx, N_TABLE)
+    def digit_at(dig, w):
+        return lax.dynamic_index_in_dim(dig, w, axis=0, keepdims=False)
 
     def body(i, q):
         w = 63 - i
         q = double(double(double(double(Point(*q)))))
-        ex, ey, ez, et = a_select(digit_at(p_mag, w))
+        ex, ey, ez, et = select_entry(a_tab, digit_at(p_mag, w), N_TABLE)
         pn = digit_at(p_neg.astype(jnp.int32), w).astype(bool)
         # negative digit: -(x, y, z, t) = (-x, y, z, -t), branchless
         entry = Point(
             F.select(pn, F.neg(ex), ex), ey, ez, F.select(pn, F.neg(et), et)
         )
         q = add(q, entry)
-        nypx, nymx, nxy2d = b_select(digit_at(s_mag, w))
+        nypx, nymx, nxy2d = select_entry(b_tab, digit_at(s_mag, w), N_TABLE)
         sn = digit_at(s_neg.astype(jnp.int32), w).astype(bool)
         # Niels negation: swap (y+x)/(y-x), negate xy2d
         nypx, nymx = (
@@ -456,30 +346,24 @@ def verify_core(
     sign_r: jnp.ndarray,
     s_dig: jnp.ndarray,
     h_dig: jnp.ndarray,
-    b_tab=None,
 ) -> jnp.ndarray:
     """Limbs-leading batched verify -> validity bitmap (lanes,) bool.
 
     Inputs: ``y_a``/``y_r`` (17, lanes) limb tensors; ``sign_*`` (lanes,);
-    ``s_dig``/``h_dig`` (64, lanes) base-16 scalar digits; ``b_tab`` see
-    :func:`double_scalar_mul_windowed`.
+    ``s_dig``/``h_dig`` (64, lanes) base-16 scalar digits.
 
     Checks the cofactorless equation [S]B == R + [h]A (as OpenSSL/the CPU
     path does), rearranged to Q := [S]B + [h](-A), Q == R, compared
     projectively (X_Q == x_R * Z_Q, Y_Q == y_R * Z_Q) to avoid an inversion.
-    This function is the shared core of the XLA path
-    (:func:`verify_prepared`) and the Pallas kernel
-    (:mod:`mochi_tpu.crypto.pallas_verify`).
     """
-    # (Measured and rejected: fusing the A/R decompressions into one
-    # (17, 2B) call to halve the pow_p58 sequential depth — 108.7k vs
-    # ~110k sigs/s at batch 8192 depth-8; the doubled lane width during
-    # decompress cancels the depth win at the production bucket size.)
+    # (Tried and rejected: fusing the A/R decompressions into one (17, 2B)
+    # call to halve the pow_p58 sequential depth; the doubled lane width
+    # during decompress cancels the depth win at the production bucket size.)
     with jax.named_scope(SCOPE_DECOMPRESS):
         a_point, ok_a = decompress(y_a, sign_a)
         r_point, ok_r = decompress(y_r, sign_r)
     with jax.named_scope(SCOPE_LADDER):
-        q = double_scalar_mul_windowed(s_dig, h_dig, negate(a_point), b_tab=b_tab)
+        q = double_scalar_mul_windowed(s_dig, h_dig, negate(a_point))
     with jax.named_scope(SCOPE_COMPARE):
         eq_x = F.eq(q.x, F.mul(r_point.x, q.z))
         eq_y = F.eq(q.y, F.mul(r_point.y, q.z))

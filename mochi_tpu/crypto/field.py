@@ -6,9 +6,8 @@ Design notes (why this representation — round-2 rework):
   int32 array ``(17, ...lanes)`` — limbs on the *leading* axis, batch on the
   trailing axes.  On TPU the last dim maps to the 128-wide lane axis, so a
   batch of field elements ``(17, B)`` runs every elementwise op on full
-  128-lane vectors (the round-1 ``(B, 16)`` layout wasted 7/8 of each lane
-  group; the round-1 Pallas kernel existed solely to fix that — now the XLA
-  path has the good layout natively and the Pallas kernel shares this code).
+  128-lane vectors (a batch-leading ``(B, 16)`` layout wastes 7/8 of each
+  lane group).
 * **Why radix 15, not 16:** 17*15 = 255 exactly, so the fold constant is 19
   (2^255 === 19 mod p) and — the big one — products of *loosely reduced*
   limbs stay inside int32: with limbs <= 2^15+96, a product is < 2^31, so
@@ -26,19 +25,11 @@ Design notes (why this representation — round-2 rework):
   — i.e. a handful of times per verify, not thousands.
 * **Column accumulation is 17 shifted pad+adds.**  The 17x17 partial-
   product anti-diagonal sums ("columns") are built by padding each row to
-  its shifted position and summing (:func:`_skew_cols_pad`) — ~35 fusable
-  elementwise ops, no relayout.  The round-2a "reshape" variant (3 XLA
-  ops via a flatten/reshape skew) compiles equally fast but runs 3.4x
-  slower on v5e: the reshape is a relayout + fusion barrier, so the
-  (17, 34, B) intermediates stream through HBM (~27 us/mul at B=4096,
-  consistent with HBM bandwidth on ~40 MB of intermediates) where the
-  pad form stays VMEM-fused (~7.9 us/mul) — scripts/mul_microbench.py.
-  Either way the traced graph is ~25-35 HLO ops per multiply vs round-1's
-  ~300 (32 dynamic-slice updates), which is what cut XLA-CPU compile of
-  the full verifier from minutes to seconds (VERDICT.md round-1 item 4).
-  Inside Pallas/Mosaic kernels (where sublane-dim reshapes are
-  restricted) the same columns are built by unrolled static-slice adds —
-  select with :data:`SKEW_IMPL`.
+  its shifted position and summing (:func:`_skew_cols`) — ~35 fusable
+  elementwise ops, no relayout.  A flatten/reshape skew is fewer XLA ops,
+  but the reshape is a relayout and a fusion barrier on TPU: its
+  (17, 34, B) intermediates stream through HBM where the pad form stays
+  fused in VMEM.
 * No data-dependent control flow — everything is branchless select/arith
   so the whole verifier jits into one XLA program (SURVEY.md §7).
 
@@ -71,29 +62,6 @@ L_INT = (1 << 252) + 27742317777372353535851937790883648493
 # Ed25519 basepoint (affine)
 BX_INT = 15112221349535400772501151409588531511454012693041857206046113283949847762202
 BY_INT = 46316835694926478169428394003475163141307993866256225615783033603165251855960
-
-# How to build schoolbook columns: "pad" (17 shifted pad+adds — no
-# relayout, fuses into the partial-product computation; measured 3.4x
-# faster than "reshape" on v5e at (17, 4096): 7.9 vs 27.0 us/mul,
-# scripts/mul_microbench.py), "reshape" (3 XLA ops but the flatten/
-# reshape is a relayout + fusion barrier on TPU), "shift" (unrolled
-# static-slice adds — required inside Mosaic kernels, where reshapes
-# that touch the sublane dim are restricted), or "mxu" (column reduction
-# as one f32 matmul against a constant 0/1 shift matrix — moves the
-# reduction off the VPU onto the MXU; see ``_mul_mxu``).  Env-overridable
-# for the measurement battery's A/B (MOCHI_SKEW_IMPL).
-import os as _os
-
-SKEW_IMPL = _os.environ.get("MOCHI_SKEW_IMPL", "pad")
-
-
-def available_skews():
-    return ("pad", "reshape", "shift", "mxu")
-
-# How to materialize limb constants: "array" (one XLA literal — default) or
-# "scalars" (per-limb jnp.full from python ints — required inside Pallas
-# kernels, which cannot capture array constants from the closure).
-CONST_MODE = "array"
 
 
 def int_to_limbs(x: int) -> np.ndarray:
@@ -133,9 +101,6 @@ def bytes32_to_limbs(b: bytes) -> np.ndarray:
 
 def const(x: int, lanes=()) -> jnp.ndarray:
     """Device constant: (17, *lanes) int32, broadcast over trailing lanes."""
-    if CONST_MODE == "scalars":
-        limbs = [int(v) for v in int_to_limbs(x)]
-        return jnp.stack([jnp.full(lanes, l, dtype=jnp.int32) for l in limbs], axis=0)
     c = jnp.asarray(int_to_limbs(x))
     if lanes:
         c = jnp.broadcast_to(c.reshape(NLIMBS, *([1] * len(lanes))), (NLIMBS, *lanes))
@@ -144,10 +109,6 @@ def const(x: int, lanes=()) -> jnp.ndarray:
 
 def _limb_vec(np_limbs: np.ndarray, lanes=()) -> jnp.ndarray:
     """A fixed limb vector (e.g. p or 2p) as (17, *lanes or broadcastable)."""
-    if CONST_MODE == "scalars":
-        return jnp.stack(
-            [jnp.full(lanes, int(l), dtype=jnp.int32) for l in np_limbs], axis=0
-        )
     return jnp.asarray(np_limbs).reshape(NLIMBS, *([1] * len(lanes)))
 
 
@@ -156,8 +117,6 @@ def zeros(lanes) -> jnp.ndarray:
 
 
 def one(lanes) -> jnp.ndarray:
-    # concat, not .at[0].set: an indexed update lowers to scatter, which
-    # Mosaic has no TC lowering for; XLA folds both forms identically.
     return jnp.concatenate(
         [jnp.ones((1, *lanes), jnp.int32), jnp.zeros((NLIMBS - 1, *lanes), jnp.int32)],
         axis=0,
@@ -241,40 +200,10 @@ def neg(a: jnp.ndarray) -> jnp.ndarray:
 # ------------------------------------------------------------------- multiply
 
 
-def _skew_cols_reshape(x: jnp.ndarray) -> jnp.ndarray:
-    """Anti-diagonal sums of (..leading.., 17, 17, ...lanes) on axes (-2-L,..)?
-
-    Layout here: x is (17, 17, *lanes) — axis 0 = a-limb i, axis 1 = b-limb j.
-    Returns cols (33, *lanes): cols[k] = sum_{i+j=k} x[i,j].
-
-    Trick: pad rows to width 2n (34), flatten, pad to (n+1)(2n-1) = 594,
-    reshape (18, 33): element (i,j) lands at p = 34i+j, and p mod 33 =
-    (i+j) mod 33 = i+j (since i+j <= 32); summing the 18 rows gives the
-    column sums.  3 XLA ops instead of 32 dynamic-slice updates.
-    """
-    n = NLIMBS
-    lanes = x.shape[2:]
-    lane_pad = [(0, 0)] * len(lanes)
-    x2 = jnp.pad(x, [(0, 0), (0, n), *lane_pad])  # (17, 34, lanes)
-    flat = x2.reshape(n * 2 * n, *lanes)  # 578
-    flat = jnp.pad(flat, [(0, (n + 1) * (2 * n - 1) - n * 2 * n), *lane_pad])  # 594
-    return flat.reshape(n + 1, 2 * n - 1, *lanes).sum(axis=0)  # (33, lanes)
-
-
-def _skew_cols_shift(x: jnp.ndarray) -> jnp.ndarray:
-    """Same columns via unrolled static-slice adds (Mosaic-safe)."""
-    n = NLIMBS
-    lanes = x.shape[2:]
-    cols = jnp.zeros((2 * n - 1, *lanes), dtype=jnp.int32)
-    for i in range(n):
-        cols = lax.dynamic_update_slice_in_dim(
-            cols, lax.dynamic_slice_in_dim(cols, i, n, axis=0) + x[i], i, axis=0
-        )
-    return cols
-
-
-def _skew_cols_pad(x: jnp.ndarray) -> jnp.ndarray:
-    """Same columns via 17 shifted pad+adds: cols += pad(x[i], (i, 16-i)).
+def _skew_cols(x: jnp.ndarray) -> jnp.ndarray:
+    """Anti-diagonal sums of x (17, 17, *lanes), axis 0 = a-limb i, axis 1 =
+    b-limb j: returns cols (33, *lanes) with cols[k] = sum_{i+j=k} x[i, j],
+    via 17 shifted pad+adds: cols += pad(x[i], (i, 16-i)).
 
     Each term is an elementwise add of a sublane-shifted (17->33, lanes)
     slice — no flatten/reshape relayout, so XLA can fuse the whole column
@@ -290,15 +219,6 @@ def _skew_cols_pad(x: jnp.ndarray) -> jnp.ndarray:
     return cols
 
 
-def _skew_cols(x: jnp.ndarray) -> jnp.ndarray:
-    if SKEW_IMPL == "reshape":
-        return _skew_cols_reshape(x)
-    if SKEW_IMPL == "shift":
-        return _skew_cols_shift(x)
-    # "pad" — also the fallback for "mxu" ranks the matmul path declines
-    return _skew_cols_pad(x)
-
-
 def _fold_carry(cols_lo: jnp.ndarray, cols_hi: jnp.ndarray) -> jnp.ndarray:
     """Combine lo/hi column sums (hi shifted one limb up), fold the high 17
     columns at 2^255 === 19, and restore the loose-limb invariant.
@@ -312,56 +232,6 @@ def _fold_carry(cols_lo: jnp.ndarray, cols_hi: jnp.ndarray) -> jnp.ndarray:
     return _carry2(folded)
 
 
-# Constant reduction matrix for the "mxu" multiply: row k sums the lo
-# products with i+j == k and the hi products with i+j+1 == k (hi is the
-# product's high half, one limb up).  Shape (34, 2*289) f32 0/1.
-def _build_mxu_matrix() -> np.ndarray:
-    m = np.zeros((2 * NLIMBS, 2 * NLIMBS * NLIMBS), dtype=np.float32)
-    for i in range(NLIMBS):
-        for j in range(NLIMBS):
-            m[i + j, i * NLIMBS + j] = 1.0
-            m[i + j + 1, NLIMBS * NLIMBS + i * NLIMBS + j] = 1.0
-    return m
-
-
-_MXU_M = _build_mxu_matrix()
-
-
-def _mul_mxu(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
-    """Column reduction on the MXU: the 17x17 partial-product anti-diagonal
-    sums are one (34, 578) x (578, B) f32 matmul against a constant 0/1
-    shift matrix.
-
-    Exactness: lo < 2^15 and hi <= 32965 < 2^16 are f32-exact; each output
-    column sums <= 34 such terms -> < 2^21 < 2^24, still exact; folded
-    < 20 * 2^21 < 2^26 -> :func:`_carry2` precondition holds.  The VPU
-    still computes the 289 int32 products; what moves to the MXU is the
-    33-way reduction tree, the schedule-heavy half of the pad-skew form.
-    Requires 1-D lanes (the batched verifier path); other ranks fall back.
-    """
-    lanes = a.shape[1:]
-    prod = a[:, None] * b[None, :]  # (17, 17, B) int32
-    lo = (prod & MASK).astype(jnp.float32).reshape(NLIMBS * NLIMBS, *lanes)
-    hi = (prod >> RADIX).astype(jnp.float32).reshape(NLIMBS * NLIMBS, *lanes)
-    p = jnp.concatenate([lo, hi], axis=0)  # (578, B)
-    # precision=HIGHEST: TPU's default f32 matmul decomposes operands
-    # through bf16 passes whose 8-bit mantissa would truncate the 16-bit
-    # product halves — exactly on the hardware this path targets (CPU's
-    # full-f32 default would mask it in tests).
-    cols = lax.dot_general(
-        jnp.asarray(_MXU_M),
-        p,
-        (((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32,
-        precision=lax.Precision.HIGHEST,
-    )  # (34, B), exact integers < 2^21
-    # Fold in int32: 19 * col + col can reach ~21.7M > 2^24, past f32's
-    # exact-integer range (the cols themselves, < 2^21, convert exactly).
-    cols_i = cols.astype(jnp.int32)
-    folded = cols_i[:NLIMBS] + 19 * cols_i[NLIMBS:]
-    return _carry2(folded)
-
-
 def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     """Schoolbook 17x17-limb multiply, radix 2^15, fold at 2^255 === 19.
 
@@ -369,12 +239,6 @@ def mul(a: jnp.ndarray, b: jnp.ndarray) -> jnp.ndarray:
     (int32-safe, no uint32 casts).  lo < 2^15, hi = prod >> 15 <= 32965.
     Columns: <= 17 terms each for lo and hi -> < 2^21 -> :func:`_fold_carry`.
     """
-    if (
-        SKEW_IMPL == "mxu"
-        and CONST_MODE != "scalars"  # Mosaic kernels: no sublane reshape/dot
-        and len(a.shape) == 2 == len(b.shape)
-    ):
-        return _mul_mxu(a, b)
     prod = a[:, None] * b[None, :]  # (17, 17, lanes) int32
     lo = prod & MASK
     hi = prod >> RADIX
@@ -394,24 +258,8 @@ def square(a: jnp.ndarray) -> jnp.ndarray:
     unchanged: columns < 2^21, folded < 2^26 -> :func:`_carry2`.
     Roughly 36% of the verifier's field muls are squarings (the ladder's
     doublings and the decompression power chains), so the ~47% product
-    saving here is a measurable slice of the whole pipeline
-    (scripts/mul_microbench.py).
-
-    Inside Mosaic kernels the sublane-axis pad/concatenate chain below has
-    no validated lowering — route through :func:`mul`, whose column skews
-    are the Mosaic-vetted forms.  (The kernel is recognizable by either
-    Mosaic-mode flag: ``SKEW_IMPL == "shift"`` or ``CONST_MODE ==
-    "scalars"`` — :mod:`mochi_tpu.crypto.pallas_verify` sets the latter.)
+    saving here is a slice of the whole pipeline.
     """
-    if (
-        SKEW_IMPL == "shift"
-        or CONST_MODE == "scalars"
-        # mxu takes rank-2 squarings through the matmul reduction (the
-        # 153-product saving applies to VPU work the matmul replaces);
-        # other ranks keep the specialized symmetric schoolbook.
-        or (SKEW_IMPL == "mxu" and len(a.shape) == 2)
-    ):
-        return mul(a, a)
     n = NLIMBS
     lanes = a.shape[1:]
     lane_pad = [(0, 0)] * len(lanes)

@@ -125,10 +125,9 @@ def host_crypto_engine() -> str:
     """Which engine :func:`verify` routes to on THIS host: ``"openssl"``
     (the ``cryptography`` wheel), ``"native-c"`` (the lazily-built
     ``native/hbatch.c`` verification engine), or ``"pure-python"`` (the
-    :mod:`~mochi_tpu.crypto.hostfallback` bignum engine).  Benchmark
-    records stamp this so the recurring "wheel-less host inflates write
-    latency" caveat is machine-readable provenance, not prose
-    (benchmarks/run_all.py, bench.py)."""
+    :mod:`~mochi_tpu.crypto.hostfallback` bignum engine).  Replica and
+    service ``/status`` stamp it, so "a wheel-less host inflates write
+    latency" is machine-readable provenance, not prose."""
     if _HAVE_HOST_CRYPTO:
         return "openssl"
     try:

@@ -722,8 +722,8 @@ class RpcServer:
         loop = asyncio.get_running_loop()
         if self._unix_path is not None:
             # Unix-domain socket: same framed protocol, no TCP/IP stack —
-            # the kernel loopback send path is the measured cost floor for
-            # single-host clusters (BASELINE.md round-4 note).  Only a DEAD
+            # the kernel loopback send path is the cost floor of a
+            # single-host cluster.  Only a DEAD
             # leftover socket is unlinked: stealing a live server's path
             # would strand it running-but-unreachable, where TCP fails
             # loudly with EADDRINUSE (code-review r4).

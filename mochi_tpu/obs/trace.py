@@ -51,9 +51,7 @@ from contextvars import ContextVar
 from typing import Dict, Iterable, List, Optional, Sequence, Tuple
 
 # Default head-sampling rate when tracing is enabled without an explicit
-# rate (MOCHI_TRACE=1): 1-in-20 transactions carry spans.  The committed
-# config-7 A/B (benchmarks/results_r15.json) bounds the write-p50 cost of
-# exactly this default at ≤3%.
+# rate (MOCHI_TRACE=1): 1-in-20 transactions carry spans.
 DEFAULT_SAMPLE_RATE = 0.05
 
 # Ring bound: spans kept per process.  At ~200 bytes/span this is ~1 MB —
@@ -165,10 +163,10 @@ class TraceContext:
 
 
 # Process-global tracer registry (weak — a closed cluster's tracers are
-# collectable) behind run_all's ``trace_summary`` harness-rot probe.
-# Counters ALSO aggregate into _GLOBAL as they happen: a benchmark
-# summarizes after its cluster is closed, by which time the weak refs may
-# already be collected — the evidence must outlive the tracers.
+# collectable) behind :func:`global_summary`.
+# Counters ALSO aggregate into _GLOBAL as they happen: a caller that
+# summarizes after its cluster is closed finds the weak refs already
+# collected — the evidence must outlive the tracers.
 _TRACERS: "weakref.WeakSet" = weakref.WeakSet()
 _REG_LOCK = threading.Lock()
 _GLOBAL = {
@@ -523,12 +521,10 @@ def cost_cards(events: Iterable[Dict]) -> Dict[str, Dict]:
 
 
 def global_summary() -> Dict:
-    """Process-wide tracing evidence — the benchmark harness's
-    ``trace_summary`` stamp (non-empty even with tracing off, so the key's
-    PRESENCE is what tier-1 smoke pins).  Counters come from the module
-    aggregate, NOT the live tracer set: benchmarks summarize after their
-    clusters close, when the weakly-registered tracers may already be
-    collected; ``enabled``/``sample_rate`` reflect the env posture at call
+    """Process-wide tracing evidence (non-empty even with tracing off).
+    Counters come from the module aggregate, NOT the live tracer set: a
+    caller may summarize after its clusters close, when the weakly-registered
+    tracers may already be collected; ``enabled``/``sample_rate`` reflect the env posture at call
     time."""
     with _REG_LOCK:
         tracers = list(_TRACERS)

@@ -10,8 +10,8 @@ Usage:
 
 Repeating ``--server-id``/``--seed-file`` (pairwise, in order) hosts SEVERAL
 replicas on this process's one event loop — the packing knob of the
-shard-per-core deployment ladder (``testing/process_cluster.py``,
-``benchmarks/config8_scaleout.py``): one replica per process is the
+shard-per-core deployment ladder (``testing/process_cluster.py``): one
+replica per process is the
 production scale-out posture; all replicas in one process is the
 single-core baseline the ladder is measured against.
 

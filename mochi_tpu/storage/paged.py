@@ -49,7 +49,7 @@ manifest is durable before any WAL segment is deleted, and the manifest
 watermark makes replay of the overlap a no-op.  Page files the manifest
 never adopted are orphans, deleted at boot.
 
-Deliberate trade (documented, measured in benchmarks/config14): a page
+Deliberate trade: a page
 fault is a synchronous pread of ONE entry on the event loop — the store's
 read/validation paths are synchronous, so a fault cannot await.  The unit
 of blocking is one entry (~KB), bounded by the op that needed it, not by

@@ -53,8 +53,7 @@ Strategy catalog (``make_strategy`` names):
 ``storm``
     View-change/liveness storm: refuses a seeded fraction of Write1s
     (validly signed refusals) and floods peers with resync nudges.  Run
-    under a netsim partition schedule (``benchmarks/config10_byzantine``)
-    this is the reconfiguration-churn shape: transient quorum loss, retry
+    under a netsim partition schedule this is the reconfiguration-churn shape: transient quorum loss, retry
     pressure, background sync traffic.
 
 ``session-attack``

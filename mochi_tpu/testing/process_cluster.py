@@ -6,7 +6,7 @@ token-ring sharding exists for: the cluster's replicas are spread over
 ``n_processes`` real ``python -m mochi_tpu.server`` processes (each hosting
 ``n_servers / n_processes`` replicas on its own event loop), so aggregate
 throughput scales with cores instead of saturating one.  The two postures
-bracket the scale-out ladder (``benchmarks/config8_scaleout.py``):
+bracket the scale-out ladder:
 
 * ``n_processes=1``   — the single-process baseline (all replicas share one
   child process's loop; the client drives from the parent);

@@ -697,9 +697,8 @@ async def _run_leg(li: int, fault: Dict, vc, sim, clients, checker, spec, res) -
             # traffic that would teach it the new configstamp, and the
             # protocol makes no claims about a Byzantine member's local
             # state.  Waiting on vc.replicas wedged every silent+reconfig
-            # draw at the 15 s deadline (soak seeds 164/195/275/319/425,
-            # results_r16.json round-16 bring-up; regression-pinned in
-            # tests/test_scenario.py).
+            # draw at the 15 s deadline (soak seeds 164/195/275/319/425;
+            # regression-pinned in tests/test_scenario.py).
             honest = vc.honest_replicas()
             deadline = time.monotonic() + 15.0
             while time.monotonic() < deadline:

@@ -92,8 +92,8 @@ class VirtualCluster:
         self.fast_path = fast_path
         # Unix-domain sockets instead of loopback TCP (per-replica socket
         # files under this dir): skips the TCP/IP stack on the kernel send
-        # path, the measured cost floor for single-host clusters
-        # (BASELINE.md).  MOCHI_UDS=1 turns it on for any test/bench.
+        # path, the cost floor of a single-host cluster.  MOCHI_UDS=1 turns
+        # it on for any test.
         self._owns_uds_dir = False
         if uds_dir is None and os.environ.get("MOCHI_UDS") == "1":
             import tempfile

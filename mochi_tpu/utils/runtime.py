@@ -122,19 +122,3 @@ def tune_gc_for_server() -> None:
     gc.collect()
     gc.freeze()
     gc.set_threshold(50000, 50, 50)
-
-
-def reset_gc_debt() -> None:
-    """Collect torn-down cyclic graphs NOW and refreeze the survivors.
-
-    Under the relaxed server thresholds a just-closed in-process cluster's
-    object graph (replicas↔stores↔grant books, task callbacks — all
-    cyclic) lingers uncollected; a workload that follows in the same
-    process then pays repeated young-gen collections that trace the dead
-    giant graph — the config-6 "GC debt" artifact that depressed an
-    n16-after-n64 run ~45% (BASELINE.md).  Call between workloads that
-    must not observe each other's teardown; a full collect + refreeze
-    moves whatever legitimately survives out of the traced set entirely.
-    """
-    gc.collect()
-    gc.freeze()

@@ -1,8 +1,7 @@
 """Coalesced wakeups: one coarse timer services thousands of deadlines.
 
 asyncio gives every ``sleep``/``wait_for`` its own ``TimerHandle`` on the
-loop's heap.  At benchmark front-end scale (``benchmarks/config9_overload``:
-thousands of concurrent client sessions, each with a request timeout and a
+loop's heap.  At front-end scale (thousands of concurrent client sessions, each with a request timeout and a
 backoff sleep in flight) that is thousands of heap entries and — worse —
 thousands of *distinct wakeups*: the loop gets scheduled once per expiring
 timer, paying a full poll/dispatch cycle to fire one callback.

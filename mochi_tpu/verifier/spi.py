@@ -330,7 +330,7 @@ class CachingVerifier(SignatureVerifier):
         # Aggregate memo (round 18): cert-hash -> all-valid verdict.  Kept
         # SEPARATE from the per-item cache so one certificate counts as ONE
         # unique check in the hits/misses meter regardless of quorum size —
-        # that ratio IS the live verifies/txn meter (config7_wan).
+        # that ratio IS the live verifies/txn meter.
         self._agg: "dict[bytes, bool]" = {}
         self._agg_inflight: "dict[bytes, asyncio.Future]" = {}
         self.agg_hits = 0
@@ -589,7 +589,7 @@ class BatchingVerifier(SignatureVerifier):
     whichever chunk finishes first), frees the slot and takes the next chunk.
     Up to ``max_inflight`` chunks run concurrently: JAX dispatch is async, so
     in-flight batches overlap the host->device round trip with device
-    execution (scripts/pipeline_bench.py measures the effect); the loop
+    execution; the loop
     thread does all the counting, so the cap needs no semaphore.  A backend
     exception re-verifies the chunk on the CPU fallback (a coroutine, so
     that path alone spends a task) — never skipped, and counted in

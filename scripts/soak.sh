@@ -11,25 +11,26 @@
 #   python -m mochi_tpu.testing.scenario repro --seed N --minimize out.json
 #
 # Usage:
-#   scripts/soak.sh [COUNT] [START] [WORKERS]
+#   scripts/soak.sh [COUNT] [START] [WORKERS] [OUT]
 #
 #   COUNT    seeds to run             (default 1000)
 #   START    first seed               (default 0; shift per battery so
 #                                      successive soaks cover fresh draws)
 #   WORKERS  parallel worker procs    (default: cores, capped at 4)
-#
-# Writes the summary JSON next to the repo's benchmark records as
-# soak_<START>_<COUNT>.json (committable evidence; the config-13 record
-# in benchmarks/results_r16.json is the canonical ≥500-seed capture).
+#   OUT      where the summary JSON goes
+#            (default soak_<START>_<COUNT>.json in the directory the
+#            script was called from)
 
 set -euo pipefail
+CALLED_FROM="$PWD"
 cd "$(dirname "$0")/.."
 
 COUNT="${1:-1000}"
 START="${2:-0}"
 CORES="$(nproc 2>/dev/null || echo 2)"
 WORKERS="${3:-$(( CORES < 4 ? CORES : 4 ))}"
-OUT="benchmarks/soak_${START}_${COUNT}.json"
+OUT="${4:-${CALLED_FROM}/soak_${START}_${COUNT}.json}"
+case "${OUT}" in /*) ;; *) OUT="${CALLED_FROM}/${OUT}" ;; esac
 
 export JAX_PLATFORMS="${JAX_PLATFORMS:-cpu}"
 

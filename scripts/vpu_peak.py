@@ -1,8 +1,8 @@
 """Measure the device's sustained int32 multiply-add peak.
 
-Every utilisation figure of earlier rounds divided by an ASSUMED VPU peak;
-this microbenchmark measures the denominator on the device itself
-(``bench.py`` prints no utilisation without it):
+No int32 VPU peak is published for the chip (``perf/peaks.json`` holds a
+null), so a ``kernel.*`` roofline share has no denominator until one is
+measured on the device itself.  This microbenchmark measures it:
 
 - workload: ``x = x * m + c`` on a VMEM-resident int32 block, iterated
   inside one compiled program via ``lax.fori_loop`` with an 8-deep unrolled
@@ -11,7 +11,7 @@ this microbenchmark measures the denominator on the device itself
 - the loop value is data-dependent (x feeds back), so XLA cannot fold or
   strength-reduce the chain; m is chosen odd so the values never collapse.
 - per-call work is sized to tens of milliseconds, and the measured
-  dispatch + readback floor (``bench.dispatch_rtt_ms``) is SUBTRACTED from
+  dispatch + readback floor (:func:`dispatch_rtt_ms`) is SUBTRACTED from
   the timed region; both raw and corrected rates are reported, and a call
   that is mostly round trip is flagged instead of inflating the peak.
 - shapes: a small sweep (elements x iterations held ~constant-work) because
@@ -20,8 +20,8 @@ this microbenchmark measures the denominator on the device itself
 - timing: np.asarray readback of a 128-element checksum slice inside the
   timed region.
 
-Writes ``benchmarks/vpu_peak.json`` (keyed by ``device_kind``) and prints
-one ``VPU_PEAK_JSON`` line.
+Writes ``chiprun_out/vpu_peak.json`` (keyed by ``device_kind``; the
+directory a chip call brings back) and prints one ``VPU_PEAK_JSON`` line.
 
 Usage: python scripts/vpu_peak.py
 Needs the chip.  Under an explicit ``JAX_PLATFORMS=cpu`` it runs a tiny dry
@@ -42,6 +42,26 @@ if _REPO not in sys.path:
     sys.path.insert(0, _REPO)
 
 UNROLL = 8  # madds per fori_loop step: control overhead /8
+
+
+def dispatch_rtt_ms(dev) -> float:
+    """Median tiny-op device round trip, ms: the dispatch + readback floor
+    under every timed call."""
+    import numpy as np
+
+    import jax
+    import jax.numpy as jnp
+
+    x = jax.device_put(jnp.zeros((8,), jnp.int32), dev)
+    f = jax.jit(lambda v: v + 1)
+    np.asarray(f(x))  # compile outside the timed region
+    times = []
+    for _ in range(21):
+        t0 = time.perf_counter()
+        np.asarray(f(x))
+        times.append(time.perf_counter() - t0)
+    times.sort()
+    return round(times[len(times) // 2] * 1e3, 3)
 
 
 def _make_kernel(iters: int):
@@ -71,8 +91,6 @@ def measure() -> dict:
     enable_compile_cache()
     device_info(require_accelerator=True)  # needs the chip (or an explicit CPU pin)
     dev = jax.devices()[0]
-
-    from bench import dispatch_rtt_ms
 
     rtt_ms = dispatch_rtt_ms(dev)
     print(f"[vpu_peak] dispatch + readback floor: {rtt_ms} ms", flush=True)
@@ -144,7 +162,8 @@ def measure() -> dict:
         "utc": time.strftime("%Y-%m-%dT%H:%M:%SZ", time.gmtime()),
     }
     if dev.platform == "tpu" and usable:
-        out_path = os.path.join(_REPO, "benchmarks", "vpu_peak.json")
+        out_path = os.path.join(_REPO, "chiprun_out", "vpu_peak.json")
+        os.makedirs(os.path.dirname(out_path), exist_ok=True)
         tmp = out_path + ".tmp"
         with open(tmp, "w") as fh:
             json.dump(rec, fh, indent=1)

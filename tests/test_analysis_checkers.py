@@ -182,7 +182,7 @@ def test_local_name_collision_not_flagged(tmp_path):
 
 
 def test_fingerprints_stable_across_cwd(tmp_path, monkeypatch):
-    # lint.sh scans from the repo root; standing_rules.py passes absolute
+    # lint.sh scans from the repo root; another caller may pass absolute
     # paths from an arbitrary CWD — fingerprints must agree or a non-empty
     # baseline silently stops matching.
     pkg = tmp_path / "pkg"

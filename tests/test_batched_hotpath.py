@@ -567,27 +567,6 @@ def test_metrics_histogram_snapshot_and_prometheus():
     assert counts == sorted(counts)
 
 
-# ------------------------------------------------------- standing-rules data
-
-
-def test_standing_rules_host_record_reads_results_file():
-    import importlib.util
-    import os
-
-    spec = importlib.util.spec_from_file_location(
-        "standing_rules",
-        os.path.join(os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
-                     "scripts", "standing_rules.py"),
-    )
-    mod = importlib.util.module_from_spec(spec)
-    spec.loader.exec_module(mod)
-    rate, src = mod._host_core_n64_record()
-    # the scanner reads the NEWEST committed host battery (ADVICE r5) —
-    # r07 as of this round (wheel-less-host record; caveat lives in-file)
-    assert src == "benchmarks/results_r07.json"
-    assert rate == pytest.approx(1.09)
-
-
 # ------------------------------------------------- early-quorum safety pins
 #
 # PR-5 tentpole: the early-quorum predicates are LIVENESS devices — a

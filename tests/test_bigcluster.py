@@ -146,56 +146,3 @@ def test_comb_registry_at_design_size():
     expect = np.ones(64, bool)
     expect[bad] = False
     assert (out == expect).all(), np.nonzero(out != expect)
-
-
-@pytest.mark.slow
-def test_config6_shape_order_independence():
-    """Run-order-independence regression for the config-6 GC-debt artifact
-    (VERDICT r5 weak #4): an n=16 record taken AFTER an n=64 run must land
-    within 10% of an n16-first record.
-
-    Root cause (BASELINE.md "GC debt, root-caused"): the torn-down
-    64-replica object graph is cyclic, so under the relaxed server GC
-    thresholds it lingers uncollected while the next shape's allocations
-    repeatedly trigger collections that trace the dead giant graph.
-    ``reset_gc_debt()`` (collect + refreeze between shapes — what
-    benchmarks/config6_bigcluster.py now does) is the fix under test.
-    Marked slow: it is a timing comparison and runs real cluster
-    workloads; the tier-1 gate stays fast without it.
-    """
-    from benchmarks.config6_bigcluster import _run_shape
-    from mochi_tpu.utils.runtime import reset_gc_debt, tune_gc_for_server
-
-    tune_gc_for_server()
-
-    def n16_rate(reps: int = 3) -> float:
-        # best-of-N, one-sided: tenancy noise only ever SLOWS a run, so
-        # the max approaches the true rate (the repo's measurement rule —
-        # never single runs on this ±30% host)
-        rates = []
-        for _ in range(reps):
-            rec = asyncio.run(_run_shape(16, 4, 3, "cpu"))
-            rates.append(rec["txn_per_s"])
-            reset_gc_debt()
-        return max(rates)
-
-    # Up to two full attempts: a background-load window spanning one whole
-    # best-of-3 leg (but not the other) is indistinguishable from a real
-    # ordering effect within a single pair, so a failed comparison gets
-    # one fresh pair before it is believed.
-    last = None
-    for _attempt in range(2):
-        first = n16_rate()
-        # generate the debt: a full n=64 boot + workload + teardown
-        asyncio.run(_run_shape(64, 4, 2, "cpu"))
-        reset_gc_debt()  # the config-6 fix under test
-        after = n16_rate()
-        if after >= 0.9 * first:
-            return
-        last = (after, first)
-    after, first = last
-    raise AssertionError(
-        f"n16-after-n64 regressed past 10% in two independent pairs: "
-        f"{after:.1f} vs {first:.1f} txn/s — GC debt is back "
-        "(see BASELINE.md root cause)"
-    )

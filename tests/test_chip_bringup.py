@@ -145,7 +145,7 @@ def test_one_site_sets_the_compile_cache_dir():
 def test_device_backends_refuse_a_cpu_only_host_without_the_explicit_pin(monkeypatch):
     """JAX here finds only the CPU.  With JAX_PLATFORMS=cpu exported that is a
     requested dry run; without it, it is a host that lost its chip — and the
-    service, the in-replica TPU verifier and bench.py all refuse to start."""
+    service and the in-replica TPU verifier both refuse to start."""
     from mochi_tpu.server import __main__ as server_main
     from mochi_tpu.verifier import service
 
@@ -161,36 +161,20 @@ def test_device_backends_refuse_a_cpu_only_host_without_the_explicit_pin(monkeyp
         asyncio.run(service.amain(args))
     with pytest.raises(SystemExit, match="no accelerator"):
         server_main._build_verifier(argparse.Namespace(verifier="tpu"), config=None)
-    import bench
-
-    with pytest.raises(SystemExit, match="no accelerator"):
-        bench.main()
 
 
 def test_device_scripts_share_the_one_gate():
     """Every script under scripts/ that measures the device asks
     ``device_info(require_accelerator=True)``: one rule, one override
-    (JAX_PLATFORMS=cpu).  ``pallas_retry.py`` is stricter on purpose — its
-    question is the Mosaic compile, which has no CPU form."""
+    (JAX_PLATFORMS=cpu)."""
     scripts = os.path.join(REPO, "scripts")
     for name in sorted(os.listdir(scripts)):
-        if not name.endswith(".py") or name == "pallas_retry.py":
+        if not name.endswith(".py"):
             continue
         src = open(os.path.join(scripts, name)).read()
         assert "MOCHI_ALLOW_CPU" not in src and "_bench_common" not in src, name
         if "enable_compile_cache()" in src:  # it compiles, so it measures
             assert "device_info(require_accelerator=True)" in src, name
-
-
-def test_pallas_kernel_never_interprets_unless_asked():
-    import numpy as np
-
-    from mochi_tpu.crypto import batch_verify
-    from mochi_tpu.crypto.pallas_verify import verify_prepared_pallas
-
-    tensors = batch_verify.prepare(_items(2))[:6]
-    with pytest.raises(RuntimeError, match="interpret=True"):
-        np.asarray(verify_prepared_pallas(*tensors, block=8))
 
 
 # ------------------------------------------------- fallbacks are counted

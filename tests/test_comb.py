@@ -357,31 +357,6 @@ def test_cluster_protocol_over_comb_verifier():
     assert any(b._ready_comb for b in backends)
 
 
-@pytest.mark.slow
-def test_tree_impl_matches_chain_and_openssl(signers, registry):
-    """The tree accumulation (MOCHI_COMB_IMPL=tree: one-hot MXU select +
-    balanced reduction) must produce bit-identical verdicts to the chain
-    form and OpenSSL on the adversarial mix."""
-    items = _mixed_items(signers, n=32)
-    expect = _expected(items)
-    key_idx = np.asarray(
-        [registry.index_of(it.public_key) for it in items], np.int32
-    )
-    (ckey, y_r, sign_r, s_sc, h_sc), pre_ok = comb._prepare_comb(
-        items, key_idx, None
-    )
-    table = registry.device_table()
-    chain = np.asarray(
-        comb._verify_comb_jit(table, ckey, y_r, sign_r, s_sc, h_sc, impl="chain")
-    )
-    tree = np.asarray(
-        comb._verify_comb_jit(table, ckey, y_r, sign_r, s_sc, h_sc, impl="tree")
-    )
-    np.testing.assert_array_equal(chain, tree)
-    got = [bool(b) for b in np.logical_and(tree[: len(items)], pre_ok)]
-    assert got == expect
-
-
 def test_comb_chunked_pipeline_path(monkeypatch, signers, registry):
     """Oversized comb batches chunk at MAX_BUCKET behind the bounded
     launch window (verify_stream's pipelined path) — shrunk via
@@ -466,13 +441,13 @@ def test_comb_table_math_against_host_ints(signers):
 
 
 @pytest.mark.slow
-def test_device_matmuls_pin_highest_precision():
-    """Every dot_general in the comb programs must carry explicit
-    Precision.HIGHEST: TPU's DEFAULT f32 matmul decomposes through bf16
-    passes whose 8-bit mantissa truncates the 15-bit table limbs — wrong
-    basepoint rows, valid signatures rejected (ADVICE r4 medium; the CPU
-    backend computes full f32 either way, which is exactly why a numeric
-    test here cannot catch it and this structural check exists)."""
+def test_comb_program_has_no_matmul():
+    """The comb program is integer VPU work: no ``dot_general``.  TPU's
+    DEFAULT f32 matmul decomposes through bf16 passes whose 8-bit mantissa
+    truncates the 15-bit table limbs (wrong basepoint rows, valid signatures
+    rejected), and the CPU backend computes full f32 either way, so a numeric
+    test here cannot catch one: a formulation that brings a matmul in has to
+    pin ``Precision.HIGHEST`` and change this structural check with it."""
     import jax
 
     from mochi_tpu.crypto.batch_verify import prepare_packed
@@ -502,26 +477,7 @@ def test_device_matmuls_pin_highest_precision():
                         dot_precisions(x.jaxpr, out)
         return out
 
-    from jax import lax
-
-    for impl, expect_dots in (("tree", True), ("chain", False)):
-        jx = jax.make_jaxpr(
-            lambda *a: comb.verify_comb_prepared(*a, impl=impl)
-        )(table, key_idx, y_r, sign_r, s_sc, h_sc)
-        precs = dot_precisions(jx.jaxpr, [])
-        assert bool(precs) == expect_dots, (impl, precs)
-        for p in precs:
-            assert p == (lax.Precision.HIGHEST, lax.Precision.HIGHEST), (impl, p)
-
-    # Same hazard, same pin for the MXU column-reduction multiply
-    # (MOCHI_SKEW_IMPL=mxu; field.py:_mul_mxu documents the bound proof).
-    import jax.numpy as jnp
-
-    from mochi_tpu.crypto import field as F
-
-    a = jnp.ones((F.NLIMBS, 4), jnp.int32)
-    jx = jax.make_jaxpr(F._mul_mxu)(a, a)
-    precs = dot_precisions(jx.jaxpr, [])
-    assert precs, "mxu multiply lost its dot_general"
-    for p in precs:
-        assert p == (lax.Precision.HIGHEST, lax.Precision.HIGHEST), p
+    jx = jax.make_jaxpr(comb.verify_comb_prepared)(
+        table, key_idx, y_r, sign_r, s_sc, h_sc
+    )
+    assert dot_precisions(jx.jaxpr, []) == []

@@ -52,18 +52,6 @@ class TestField:
         self._assert_mod_eq(F.mul_small(A, 2), [2 * x for x in xs])
         self._assert_mod_eq(F.mul_small(A, 977), [977 * x for x in xs])
 
-    def test_mul_skew_impls_agree(self):
-        xs, ys, A, B = self._rand_pairs(seed=3)
-        prev = F.SKEW_IMPL
-        try:
-            F.SKEW_IMPL = "reshape"
-            r1 = np.asarray(F.mul(A, B))
-            F.SKEW_IMPL = "shift"
-            r2 = np.asarray(F.mul(A, B))
-        finally:
-            F.SKEW_IMPL = prev
-        assert (r1 == r2).all()
-
     @pytest.mark.slow
     def test_pow_invert_canonical(self):
         xs, _, A, _ = self._rand_pairs(n=4, seed=2)
@@ -180,8 +168,8 @@ class TestBatchVerify:
 
     def test_all_rejected_batch_skips_device(self, monkeypatch):
         """A chunk whose prechecks reject every item (garbage flood) must
-        return all-False WITHOUT launching the device program — the
-        no-device-amplification property scripts/forgery_bench.py measures."""
+        return all-False WITHOUT launching the device program: a flood of
+        byte noise buys no device time."""
         kp = generate_keypair()
         # S >= L: canonical-length but fails the host range precheck
         garbage = [

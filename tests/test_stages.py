@@ -180,7 +180,7 @@ def test_launch_stages_tick_in_the_registry_of_the_backend_that_called(spans):
     items = make_items(5)
     bv._tls.metrics = backend.metrics  # what JaxBatchBackend.__call__ does before it calls down
     try:
-        _, args, pre_ok = bv._prepare_padded(items, 16)
+        args, pre_ok = bv._prepare_padded(items, 16)
         assert pre_ok.all() and args[0].shape[0] == 16
         assert bv._readback((np.ones(16, bool), np.ones(5, bool)), 5) == [True] * 5
     finally:

@@ -76,35 +76,3 @@ def test_reconfigure_cli_removes_server_live(tmp_path):
             await client.close()
 
     run(main())
-
-
-def test_publish_guards_protect_scoreboard():
-    """run_all's published-block merge: errored runs and CPU fallbacks must
-    never clobber good / live-TPU entries (the round-4 incident)."""
-    from benchmarks.run_all import merge_published
-
-    baseline = {
-        "published": {
-            "1": {"metric": "m1", "value": 500.0, "platform": "cpu"},
-            "2": {"metric": "m2", "value": 91000.0, "platform": "tpu"},
-        }
-    }
-    results = [
-        {"config": "1", "metric": "m1", "error": "timeout"},          # guard 1
-        {"config": "2", "metric": "m2", "value": 300.0, "platform": "cpu"},  # guard 2
-        {"config": "3", "metric": "m3", "value": 42.0, "platform": "cpu"},   # fresh
-        {"config": "2b", "metric": "m2", "value": 95000.0, "platform": "tpu"},
-    ]
-    skipped = merge_published(baseline, results, "99")
-    pub = baseline["published"]
-    assert pub["1"]["value"] == 500.0 and "error" not in pub["1"]
-    assert pub["2"]["value"] == 91000.0 and pub["2"]["platform"] == "tpu"
-    assert pub["3"]["value"] == 42.0
-    assert pub["3"]["source"] == "benchmarks/results_r99.json"
-    # a fresh TPU run publishes normally
-    assert pub["2b"]["value"] == 95000.0
-    assert len(skipped) == 2
-
-    # an errored run with NO existing entry still records (loud, not silent)
-    merge_published(baseline, [{"config": "7", "metric": "m7", "error": "x"}], "99")
-    assert baseline["published"]["7"]["error"] == "x"

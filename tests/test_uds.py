@@ -2,11 +2,11 @@
 
 A deployment option for single-host clusters (``gen_cluster --uds``,
 ``VirtualCluster(uds_dir=...)``, ``MOCHI_UDS=1``): same framed protocol,
-no TCP/IP stack.  Measured on the 1-core CI host (config1 A/B, r4): no
-throughput win over loopback TCP in either posture — the binding cost
-there is scheduling/protocol work, not the network stack — so TCP stays
-the default; the feature exists for multi-core single-host deployments
-where the loopback send path is the demonstrated hot spot (BASELINE.md).
+no TCP/IP stack.  Where every process shares one core it is no win over
+loopback TCP (the binding cost there is scheduling and protocol work, not
+the network stack), so TCP stays the default; the feature exists for
+multi-core single-host deployments where the loopback send path is the
+demonstrated hot spot.
 """
 
 from __future__ import annotations
