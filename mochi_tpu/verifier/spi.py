@@ -22,7 +22,7 @@ import asyncio
 import hashlib
 import logging
 import time
-from collections import deque
+from collections import OrderedDict, deque
 from typing import Callable, Deque, List, NamedTuple, Optional, Sequence, Tuple
 
 from ..crypto import keys as crypto_keys
@@ -282,7 +282,7 @@ async def _owner_result(owner: asyncio.Future):
 
 
 class CachingVerifier(SignatureVerifier):
-    """LRU memo over any verifier — verification is a pure function of
+    """Bounded memo over any verifier — verification is a pure function of
     (public key, message, signature), so caching is sound.
 
     Where it pays: the shared verifier service (``verifier/service.py``)
@@ -293,6 +293,13 @@ class CachingVerifier(SignatureVerifier):
     one check, not rf).  Two layers, each with its single-flight table: a
     whole call (the tuple of its items: the other rf-1 replicas' request is
     one hash and one lookup) and, behind it, each item.
+
+    Each memo is bounded by ``max_entries`` and drops its oldest INSERTION
+    first: neither a hit nor writing a key that is already there renews it.
+    The memos are ``OrderedDict``s for that one operation: the front of a
+    plain ``dict`` is found by walking the dead slots every earlier eviction
+    left (``next(iter(d))``: tens of thousands on a full memo), on the loop
+    thread; ``popitem(last=False)`` unlinks it.
     """
 
     def __init__(
@@ -305,7 +312,7 @@ class CachingVerifier(SignatureVerifier):
         self.max_entries = max_entries
         # stage timers (verifier/stages.py); the service hands in its own
         self.metrics = metrics if metrics is not None else Metrics()
-        self._cache: "dict[Tuple[bytes, bytes, bytes], bool]" = {}
+        self._cache: "OrderedDict[Tuple[bytes, bytes, bytes], bool]" = OrderedDict()
         # single-flight: key -> (the owner call's ONE future, the key's index
         # in the verdicts it resolves to) for a verification already
         # dispatched but not yet answered.  All rf replicas of a set check the
@@ -322,16 +329,20 @@ class CachingVerifier(SignatureVerifier):
         # instead of one key and one lookup per item.  The key is the items
         # themselves (the dict compares by value on a hit), no digest; bounded
         # by items held, like the per-item cache.
-        self._calls: "dict[Tuple[VerifyItem, ...], Tuple[bool, ...]]" = {}
+        self._calls: "OrderedDict[Tuple[VerifyItem, ...], Tuple[bool, ...]]" = OrderedDict()
         self._calls_items = 0
         self._calls_inflight: "dict[Tuple[VerifyItem, ...], asyncio.Future]" = {}
         self.hits = 0
         self.misses = 0
+        # verdicts the per-item and aggregate memos dropped to stay within
+        # max_entries (the two that `misses` counts: misses less this is what
+        # they hold; the whole-call memo holds copies)
+        self.memo_evictions = 0
         # Aggregate memo (round 18): cert-hash -> all-valid verdict.  Kept
         # SEPARATE from the per-item cache so one certificate counts as ONE
         # unique check in the hits/misses meter regardless of quorum size —
         # that ratio IS the live verifies/txn meter.
-        self._agg: "dict[bytes, bool]" = {}
+        self._agg: "OrderedDict[bytes, bool]" = OrderedDict()
         self._agg_inflight: "dict[bytes, asyncio.Future]" = {}
         self.agg_hits = 0
         self.agg_misses = 0
@@ -389,10 +400,8 @@ class CachingVerifier(SignatureVerifier):
             self._calls_items += len(call)
         self._calls[call] = tuple(out)
         while self._calls_items > self.max_entries and self._calls:
-            # drop the oldest insertion (dict preserves order)
-            oldest = next(iter(self._calls))
+            oldest, _ = self._calls.popitem(last=False)
             self._calls_items -= len(oldest)
-            del self._calls[oldest]
 
     def _plan(self, items: Sequence[VerifyItem]):
         """The per-item lookup (synchronous): verdicts already known, the
@@ -453,17 +462,21 @@ class CachingVerifier(SignatureVerifier):
                 if not mine.done():
                     mine.set_result(None)
                 raise
-            verdicts = [bool(ok) for ok in bitmap]
-            for (k, idxs), ok in zip(new_keys.items(), verdicts):
-                for i in idxs:
-                    out[i] = ok
-                if len(self._cache) >= self.max_entries:
-                    # drop the oldest insertion (dict preserves order)
-                    self._cache.pop(next(iter(self._cache)))
-                self._cache[k] = ok
-            self._release(new_keys, mine)
-            if not mine.done():
-                mine.set_result(verdicts)
+            # the synchronous stretch after the answer: one tick per call
+            with stages.stage(
+                self.metrics, stages.MEMO_SETTLE, stages.SPAN_MEMO_SETTLE, items=len(new_keys)
+            ):
+                verdicts = [bool(ok) for ok in bitmap]
+                for (k, idxs), ok in zip(new_keys.items(), verdicts):
+                    for i in idxs:
+                        out[i] = ok
+                    if len(self._cache) >= self.max_entries:
+                        self._cache.popitem(last=False)
+                        self.memo_evictions += 1
+                    self._cache[k] = ok
+                self._release(new_keys, mine)
+                if not mine.done():
+                    mine.set_result(verdicts)
         for owner, pairs in waiting.items():
             verdicts = await _owner_result(owner)
             if verdicts is None:
@@ -538,14 +551,16 @@ class CachingVerifier(SignatureVerifier):
             if not fut.done():
                 fut.set_result(None)
             raise
-        verdict = all(bool(b) for b in bitmap)
-        if len(self._agg) >= self.max_entries:
-            self._agg.pop(next(iter(self._agg)))
-        self._agg[key] = verdict
-        if self._agg_inflight.get(key) is fut:
-            del self._agg_inflight[key]
-        if not fut.done():
-            fut.set_result(verdict)
+        with stages.stage(self.metrics, stages.MEMO_SETTLE, stages.SPAN_MEMO_SETTLE, items=1):
+            verdict = all(bool(b) for b in bitmap)
+            if len(self._agg) >= self.max_entries:
+                self._agg.popitem(last=False)
+                self.memo_evictions += 1
+            self._agg[key] = verdict
+            if self._agg_inflight.get(key) is fut:
+                del self._agg_inflight[key]
+            if not fut.done():
+                fut.set_result(verdict)
         return verdict
 
     async def close(self) -> None:
@@ -828,6 +843,7 @@ def verifier_stats(verifier) -> dict:
         "misses",
         "agg_hits",     # CachingVerifier: one-attestation certificate memo
         "agg_misses",
+        "memo_evictions",  # ...verdicts its full memos dropped, oldest insertion first
         "calls",        # CoalescingVerifier: caller-side verify_batch calls
         "inner_calls",  # ...vs inner round trips (calls/inner_calls = merge ratio)
     ):

@@ -19,6 +19,7 @@ from ..obs import hostspan
 # ---- timers (seconds), one tick per RPC, call, chunk, launch or build
 SERVICE_RPC = "service.rpc"  # envelope in hand -> sealed reply, awaited verify included
 MEMO_LOOKUP = "service.memo-lookup"  # CachingVerifier's synchronous key-build + lookup loop
+MEMO_SETTLE = "service.memo-settle"  # ...and its synchronous stretch after the answer: verdicts, inserts, evictions
 QUEUE_WAIT = "verifier.queue-wait"  # oldest item of a chunk enqueued -> its backend started
 RESOLVE_WAIT = "verifier.resolve-wait"  # a chunk's backend returned -> its calls resolved on the loop
 FLUSH_HOST = "verifier.flush-host"  # one backend call routed to the host engine
@@ -41,6 +42,7 @@ SPAN_RPC_ADMIT = "mochi.service.rpc.admit"  # _handle's synchronous head
 SPAN_RPC_REPLY = "mochi.service.rpc.reply"  # _handle's synchronous tail; wait_us = head to here
 SPAN_TICK = "mochi.service.tick"  # once a second on the loop thread: loop_cpu_us, epoch_us
 SPAN_MEMO = "mochi.verifier.memo"  # items
+SPAN_MEMO_SETTLE = "mochi.verifier.memo-settle"  # items: the call's misses
 SPAN_CHUNK = "mochi.verifier.chunk"  # executor thread, one flushed chunk: items, wait_us
 SPAN_RESOLVE = "mochi.verifier.resolve"  # loop thread, one flushed chunk: items, calls, wait_us
 SPAN_FLUSH = "mochi.verifier.flush"  # one backend call: items, route, bucket, epoch_us

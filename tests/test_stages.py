@@ -21,7 +21,9 @@ from mochi_tpu.obs import hostspan
 from mochi_tpu.utils.metrics import Metrics, Timer
 from mochi_tpu.verifier import stages
 from mochi_tpu.verifier.service import RemoteVerifier, ServiceAdminServer, VerifierService
-from mochi_tpu.verifier.spi import BatchingVerifier, CachingVerifier, CpuVerifier, VerifyItem
+from mochi_tpu.verifier.spi import (
+    BatchingVerifier, CachingVerifier, CpuVerifier, VerifyItem, verifier_stats,
+)
 
 REPO = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 
@@ -214,6 +216,26 @@ def test_memo_lookup_ticks_once_per_call_and_counts_its_items(spans):
     assert spans.names("enter") == spans.names("exit")
 
 
+def test_memo_settle_ticks_once_per_settled_call_with_the_calls_misses(spans):
+    cv = CachingVerifier(CpuVerifier(), max_entries=8)
+    items = make_items(6)
+
+    async def main():
+        assert await cv.verify_batch(items) == [True] * 6                      # 6 misses
+        assert await cv.verify_batch(items[:4]) == [True] * 4                  # all hits: nothing to settle
+        assert await cv.verify_batch(items[2:] + make_items(3, tag=b"n")) == [True] * 7   # 3 misses
+        assert await cv.verify_aggregate(b"k" * 32, items[:3]) is True         # one attestation: 1 miss
+
+    run(main())
+    snap = cv.metrics.snapshot()
+    assert snap["timers"][stages.MEMO_SETTLE]["count"] == 3 and snap["timers"][stages.MEMO_LOOKUP]["count"] == 4
+    settled = [args["items"] for k, name, args in spans.events if k == "enter" and name == stages.SPAN_MEMO_SETTLE]
+    assert settled == [6, 3, 1] and sum(settled) == cv.misses
+    # the ninth verdict pushed the first out, and the one extractor says so on both operator surfaces
+    assert cv.memo_evictions == 1 == verifier_stats(cv)["memo_evictions"]
+    assert spans.names("enter") == spans.names("exit")  # never across an await
+
+
 def test_service_rpc_ticks_once_per_rpc_and_spans_only_its_synchronous_ends(spans):
     async def main():
         svc = VerifierService(port=0, verifier=CpuVerifier())
@@ -232,10 +254,12 @@ def test_service_rpc_ticks_once_per_rpc_and_spans_only_its_synchronous_ends(span
     snap = svc.status()["stages"]
     assert snap["timers"][stages.SERVICE_RPC]["count"] == 2 == svc.requests
     assert snap["timers"][stages.MEMO_LOOKUP]["count"] == 2 and snap["counters"][stages.MEMO_ITEMS] == 9
+    assert snap["timers"][stages.MEMO_SETTLE]["count"] == 2 and svc.status()["verifier"]["memo_evictions"] == 0
     assert snap["timers"][stages.GC]["count"] >= 1
     order = [(k, n) for k, n, _ in spans.events if n != stages.SPAN_GC and n != stages.SPAN_TICK]
     one_rpc = [("enter", stages.SPAN_RPC_ADMIT), ("exit", stages.SPAN_RPC_ADMIT),
                ("enter", stages.SPAN_MEMO), ("exit", stages.SPAN_MEMO),
+               ("enter", stages.SPAN_MEMO_SETTLE), ("exit", stages.SPAN_MEMO_SETTLE),
                ("enter", stages.SPAN_RPC_REPLY), ("exit", stages.SPAN_RPC_REPLY)]
     assert order == one_rpc * 2
     replies = [a for k, n, a in spans.events if k == "enter" and n == stages.SPAN_RPC_REPLY]
@@ -374,17 +398,21 @@ def test_the_harness_reads_the_programs_by_the_names_the_product_pins():
     sys.path.insert(0, os.path.join(REPO, "perf"))
     try:
         import hostspans
+        import layer_reader
     finally:
         sys.path.remove(os.path.join(REPO, "perf"))
     from mochi_tpu.crypto import batch_verify as bv, comb
 
     assert (hostspans.LADDER_PROGRAM, hostspans.COMB_PROGRAM) == (bv.LADDER_PROGRAM, comb.COMB_PROGRAM)
     assert hostspans.SPAN_PREFIX == stages.SPAN_PREFIX
+    settle = layer_reader.load(os.path.join(REPO, "perf", "layer_metrics", "recovery.memo_settle_us_per_item.py"))
+    assert settle.TIMER == stages.MEMO_SETTLE
     named = {n for _, names in hostspans.CAUSES for n in names} | {hostspans.TICK}
     ours = {v for k, v in vars(stages).items() if k.startswith("SPAN_") and k != "SPAN_PREFIX"}
-    # every span has a cause, or is the tick; PR 25's resolve span (loop thread, microseconds) has
-    # none until a `benchmark` PR may edit perf/hostspans.py (ROADMAP A0b): its instants read no_span
-    assert named == ours - {stages.SPAN_RESOLVE}
+    # every span has a cause, or is the tick; PR 25's resolve span and PR 30's memo-settle span (loop
+    # thread both) have none until a `benchmark` PR may edit perf/hostspans.py (ROADMAP A0b): their
+    # instants read no_span
+    assert named == ours - {stages.SPAN_RESOLVE, stages.SPAN_MEMO_SETTLE}
 
 
 def test_verdicts_under_the_scopes_match_the_host_engine():
