@@ -181,11 +181,20 @@ async def amain(args) -> None:
         )
         await replica.start()
         replicas.append(replica)
+        resynced = ""
         if args.resync_on_boot:
             # Replica state is in-memory (like the reference): after a restart,
             # pull committed state from peers before serving (paper's UptoSpeed).
             advanced = await replica.resync()
-            logging.info("boot resync: %d objects recovered", advanced)
+            report = replica.resync_report()
+            logging.info(
+                "boot resync: %d objects recovered (%d entries pulled in %.0f ms)",
+                advanced, report["entries_pulled"], report["ms"],
+            )
+            # READY after --resync-on-boot means re-hydrated from the peers; a
+            # boot whose pulls were abandoned past what f tolerates says so
+            # here (and in /status storage.resync) instead of passing for one
+            resynced = " resync=complete" if report["complete"] else " resync=INCOMPLETE"
         if args.admin_port is not None:
             from ..admin import AdminServer
 
@@ -200,7 +209,7 @@ async def amain(args) -> None:
         logging.info("replica %s serving on %s:%s", sid, replica.rpc.host, replica.bound_port)
         # Machine-readable readiness probe (one line per hosted replica):
         # supervisors and testing/process_cluster.py block on these.
-        print(f"READY {sid} {replica.bound_port}", flush=True)
+        print(f"READY {sid} {replica.bound_port}{resynced}", flush=True)
     # Graceful SIGTERM/SIGINT: drain first — stop accepting, finish admitted
     # work, flush coalesced writes (bounded by --drain-timeout) — then the
     # real close path: final snapshot (state is in-memory; the snapshot IS
@@ -316,7 +325,8 @@ def main(argv=None) -> None:
     parser.add_argument(
         "--resync-on-boot",
         action="store_true",
-        help="pull committed state from peers before serving (UptoSpeed)",
+        help="pull committed state from peers before serving (UptoSpeed); "
+        "the READY line then ends in resync=complete or resync=INCOMPLETE",
     )
     parser.add_argument(
         "--require-client-auth",

@@ -69,6 +69,7 @@ from ..verifier.spi import (
     VerifyItem,
     aggregate_key,
 )
+from . import stages
 from .admission import AdmissionController, SessionTable, TokenBucket
 from .store import BadRequest, DataStore, QuotaExceeded
 
@@ -106,6 +107,14 @@ OPTIMISTIC_CERT_ITEM_BUDGET = 256
 # bump the conviction counters/spans (same posture as InvariantChecker's
 # per-run dump bound).
 CONVICTION_DUMPS_MAX = 8
+
+def _sync_payload_len(payload) -> int:
+    """Entries (or digests) in a sync answer, for a resync span's ``entries``."""
+    for field in ("entries", "keys", "shards"):
+        rows = getattr(payload, field, None)
+        if rows is not None:
+            return len(rows)
+    return 0
 
 # Ban-book bound (evict_client): identities whose session handshakes this
 # replica refuses after a policy eviction.  FIFO-bounded like every other
@@ -213,6 +222,7 @@ class MochiReplica:
         self.peer_pool = RpcClientPool(netsim=netsim, local_label=server_id)
         self._sync_tasks: set = set()
         self._pending_sync_keys: set = set()
+        self._resync_report: Optional[Dict[str, object]] = None  # last FULL run
         self._sync_worker: Optional[asyncio.Task] = None
         self.snapshot_path = snapshot_path
         self.snapshot_interval_s = snapshot_interval_s
@@ -1426,13 +1436,16 @@ class MochiReplica:
             # Serve committed state for transfer.  No trust needed on
             # either side: entries are (transaction, certificate) pairs
             # the receiver re-validates via the Write2 checks.
-            entries = self.store.export_sync_entries(
-                payload.keys,
-                min(payload.max_entries, 1024),
-                payload.after_key,
-                payload.prefix,
-            )
-            return self._respond(env, SyncEntriesFromServer(tuple(entries)))
+            with metrics.timer(stages.SYNC_SERVE):
+                entries = self.store.export_sync_entries(
+                    payload.keys,
+                    min(payload.max_entries, 1024),
+                    payload.after_key,
+                    payload.prefix,
+                )
+                metrics.mark(stages.SYNC_PAGES_SERVED)
+                metrics.mark(stages.SYNC_ENTRIES_SERVED, len(entries))
+                return self._respond(env, SyncEntriesFromServer(tuple(entries)))
         if isinstance(payload, SyncDigestRequestToServer):
             # Anti-entropy digest page (round 14): shard rollups or per-key
             # digests, so a resyncing peer names the DIFFERENCE before
@@ -1440,28 +1453,29 @@ class MochiReplica:
             # hashes; the transfer itself stays the certificate-validated
             # SyncRequestToServer path, so lying here buys nothing.
             metrics.mark("replica.sync-digest-requests")
-            if payload.tokens is None:
+            with metrics.timer(stages.SYNC_SERVE):
+                if payload.tokens is None:
+                    return self._respond(
+                        env,
+                        SyncDigestFromServer(
+                            shards=tuple(
+                                (t, n, d)
+                                for t, n, d in self.store.export_shard_digests()
+                            )
+                        ),
+                    )
                 return self._respond(
                     env,
                     SyncDigestFromServer(
-                        shards=tuple(
-                            (t, n, d)
-                            for t, n, d in self.store.export_shard_digests()
+                        keys=tuple(
+                            self.store.export_key_digests(
+                                payload.tokens[:SHARD_TOKENS],
+                                min(payload.max_entries, 4096),
+                                payload.after_key,
+                            )
                         )
                     ),
                 )
-            return self._respond(
-                env,
-                SyncDigestFromServer(
-                    keys=tuple(
-                        self.store.export_key_digests(
-                            payload.tokens[:SHARD_TOKENS],
-                            min(payload.max_entries, 4096),
-                            payload.after_key,
-                        )
-                    )
-                ),
-            )
         if isinstance(payload, NudgeSyncToServer):
             # Advisory lag hint (paper's client-initiated UptoSpeed,
             # mochiDB.tex:168-169): queue the keys for the single
@@ -2001,6 +2015,32 @@ class MochiReplica:
             info, self._signed_request(payload), timeout_s
         )
 
+    async def _resync_page(
+        self, run: "stages.ResyncRun", sid, info, request, timeout_s: float,
+        timer: str, span: str, key: str,
+    ):
+        """One page's round trip of a resync, sent again once where it
+        failed or timed out: the answer's payload, or None after two
+        failures.  Each attempt is one tick of ``timer``."""
+        for _attempt in range(2):
+            wall0, t0 = time.time(), time.perf_counter()
+            payload = None
+            try:
+                res = await self._peer_send(sid, info, request, timeout_s)
+                payload = res.payload
+                run.count("bytes_pulled", len(res.signing_bytes()))
+            except asyncio.CancelledError:
+                raise
+            except Exception:
+                self.metrics.mark("replica.resync-page-failed")
+            run.tick(
+                timer, span, key, wall0, time.perf_counter() - t0,
+                peer=sid, entries=_sync_payload_len(payload),
+            )
+            if payload is not None:
+                return payload
+        return None
+
     async def resync(
         self, keys: Optional[Iterable[str]] = None, timeout_s: float = 5.0
     ) -> int:
@@ -2015,11 +2055,21 @@ class MochiReplica:
         transaction-hash match, staleness), so a Byzantine peer can at worst
         send us stale-but-valid state, which the timestamp check ignores.
 
+        A page request that fails or times out is sent once more; a second
+        failure (or a refusal) ends THAT pull of that peer and is counted
+        (``abandoned``).  A full run (no ``keys``) leaves its report —
+        stage times, counters, per-peer pages and whether every shard this
+        replica owns was pulled to its end from all but at most f of its
+        other owners (``complete``) — for ``resync_report()`` and ``/status``
+        ``storage.resync`` (``server/stages.py``).
+
         Returns the number of objects whose state advanced.
         """
         key_tuple = tuple(keys) if keys is not None else None
         page = 1024
         advanced_keys: set = set()
+        run = stages.ResyncRun(self.metrics, self.tracer, full=key_tuple is None)
+        abandoned: set = set()
 
         def peers_now():
             # Re-read per pass: a mid-resync reconfig swaps the peer list
@@ -2031,6 +2081,12 @@ class MochiReplica:
                 if sid != self.server_id
             ]
 
+        def abandon(sid) -> None:
+            run.peer(sid)["abandoned"] += 1
+            abandoned.add(sid)
+            self.metrics.mark("replica.resync-peer-abandoned")
+            LOG.warning("resync: a pull of %s ended on a failed page", sid)
+
         async def pull_peer(
             sid,
             info,
@@ -2038,20 +2094,22 @@ class MochiReplica:
             req_keys: "Optional[tuple]" = None,
             count: Optional[str] = None,
         ) -> None:
+            stats = run.peer(sid)
             after: Optional[str] = None
-            while True:  # page until a short page (or error/foreign payload)
+            while True:  # page until a short page (or a page that failed twice)
                 request = SyncRequestToServer(
                     keys=req_keys, max_entries=page, after_key=after, prefix=prefix
                 )
-                try:
-                    res = await self._peer_send(sid, info, request, timeout_s)
-                except asyncio.CancelledError:
-                    raise
-                except Exception:
+                payload = await self._resync_page(
+                    run, sid, info, request, timeout_s,
+                    stages.RESYNC_PULL, stages.SPAN_PULL, "pull_ms",
+                )
+                if not isinstance(payload, SyncEntriesFromServer):
+                    abandon(sid)
                     return
-                if not isinstance(res.payload, SyncEntriesFromServer):
-                    return
-                entries = res.payload.entries
+                entries = payload.entries
+                run.count("pages")
+                stats["pages"] += 1
                 if count is not None and entries:
                     # delta-vs-full transfer accounting (the round-14
                     # incremental anti-entropy evidence on storage_stats)
@@ -2065,47 +2123,69 @@ class MochiReplica:
                 # after verification: speculative state adoption would
                 # trade safety for nothing.
                 owned = [e for e in entries if self.store.owns(e.key)]
-                if self.fast_path:
-                    # Warm the aggregate memo for the whole page at once;
-                    # the per-entry re-check below then hits the memo (no
-                    # second signature round trip).
-                    await asyncio.gather(
-                        *(
-                            self._check_certificate_fast(e.certificate)
-                            for e in owned
-                        )
+                run.count("entries_unowned", len(entries) - len(owned))
+                run.count("entries_pulled", len(owned))
+                stats["entries"] += len(owned)
+                wall0, verify_s, apply_s = time.time(), 0.0, 0.0
+                t0 = time.perf_counter()
+                with run.waiting():
+                    # the page's certificates as ONE request of its verifier
+                    # chain, as the verified replay hands over a chunk
+                    verdicts = await self._check_certificates_fast(
+                        [e.certificate for e in owned]
                     )
-                for entry in owned:
-                    checked = await self._check_certificate_fast(
-                        entry.certificate
-                    )
+                verify_s += time.perf_counter() - t0
+                for entry, verdict in zip(owned, verdicts):
+                    t0 = time.perf_counter()
+                    checked = verdict
                     if checked is None:
-                        # fast path off, aggregate ineligible, or a failed
-                        # aggregate: the attributing per-grant audit
-                        checked = await self._check_certificate(
-                            entry.certificate
-                        )
+                        # fast path off, aggregate ineligible, or a grant
+                        # that did not verify: the attributing per-grant audit
+                        with run.waiting():
+                            checked = await self._check_certificate(
+                                entry.certificate
+                            )
+                    t1 = time.perf_counter()
+                    verify_s += t1 - t0
                     if checked is None:
                         self.metrics.mark("replica.resync-bad-certificate")
+                        run.count("bad_certificates")
                         continue
                     if self.store.apply_sync_entry(
                         replace(entry, certificate=checked)
                     ):
                         advanced_keys.add(entry.key)
+                        run.count("entries_adopted")
+                        stats["adopted"] += 1
+                    else:
+                        run.count("entries_redundant")  # checked, not newer
+                    apply_s += time.perf_counter() - t1
+                if owned:
+                    # the two interleave entry by entry: each span is its
+                    # stage's summed seconds, laid at the page's start
+                    run.tick(
+                        stages.RESYNC_VERIFY, stages.SPAN_VERIFY, "verify_ms",
+                        wall0, verify_s, peer=sid, entries=len(owned),
+                    )
+                    run.tick(
+                        stages.RESYNC_APPLY, stages.SPAN_APPLY, "apply_ms",
+                        wall0, apply_s, peer=sid, entries=len(owned),
+                    )
                 if len(entries) < page:
                     return
                 after = entries[-1].key
 
-        async def digest_page(sid, info, request) -> Optional[SyncDigestFromServer]:
-            try:
-                res = await self._peer_send(sid, info, request, timeout_s)
-            except asyncio.CancelledError:
-                raise
-            except Exception:
-                return None
-            if not isinstance(res.payload, SyncDigestFromServer):
-                return None  # pre-round-14 peer (or refusal): caller falls back
-            return res.payload
+        async def digest_page(sid, info, request):
+            """A digest request's answer, whatever it is (a pre-round-14 peer
+            or a refusal answers with something else: the caller falls back);
+            None where it was not answered twice, and that is counted."""
+            payload = await self._resync_page(
+                run, sid, info, request, timeout_s,
+                stages.RESYNC_DIGEST, stages.SPAN_DIGEST, "digest_ms",
+            )
+            if payload is None:
+                abandon(sid)
+            return payload
 
         async def pull_peer_delta(sid, info) -> None:
             """Incremental anti-entropy (round 14): shard digests -> key
@@ -2115,7 +2195,9 @@ class MochiReplica:
             missed pull from ITSELF only); every transferred entry still
             re-validates through the Write2 path."""
             res = await digest_page(sid, info, SyncDigestRequestToServer())
-            if res is None or res.shards is None:
+            if res is None:
+                return
+            if not isinstance(res, SyncDigestFromServer) or res.shards is None:
                 await pull_peer(sid, info, None, None, count="full")
                 return
             local_shards = {
@@ -2160,9 +2242,13 @@ class MochiReplica:
                         tokens=tuple(mismatched), max_entries=4096, after_key=after
                     ),
                 )
-                if res is None or res.keys is None:
+                if res is None:
+                    return
+                if not isinstance(res, SyncDigestFromServer) or res.keys is None:
+                    abandon(sid)  # it spoke digests a page ago
                     return
                 self.metrics.mark("replica.resync-digest-pages")
+                run.count("digest_pages")
                 for key, digest in res.keys:
                     if not self.store.owns(key):
                         continue
@@ -2176,11 +2262,20 @@ class MochiReplica:
             if keys_matched:
                 self.metrics.mark("replica.resync-keys-matched", keys_matched)
             for i in range(0, len(delta), page):
+                if sid in abandoned:
+                    return
                 await pull_peer(
                     sid, info, None, tuple(delta[i : i + page]), count="delta"
                 )
 
-        with self.metrics.timer("replica.resync"):
+        async def alive(pull) -> None:
+            run.begin_pull()
+            try:
+                await pull
+            finally:
+                run.end_pull()
+
+        with self.metrics.timer(stages.RESYNC):
             # Pass 1 (x2): the _CONFIG_ keyspace alone — historical config
             # archives must be learned BEFORE the data certificates that are
             # validated against them (store.config_for_stamp), regardless of
@@ -2199,13 +2294,18 @@ class MochiReplica:
                 # keys=None here even for targeted resyncs: a nudge names
                 # only the head document, but catching up REQUIRES the
                 # _CONFIG_CLUSTER_CS_* rungs; the prefix bounds the sweep.
+                wall0, t0 = time.time(), time.perf_counter()
                 for _ in range(2):
                     await asyncio.gather(
                         *(
-                            pull_peer(sid, info, CONFIG_KEY_PREFIX, None)
+                            alive(pull_peer(sid, info, CONFIG_KEY_PREFIX, None))
                             for sid, info in peers_now()
                         )
                     )
+                run.tick(
+                    stages.RESYNC_CONFIG, None, "config_ms",
+                    wall0, time.perf_counter() - t0,
+                )
             # Pass 2: the requested keys (config keys re-apply as no-ops).
             # A FULL resync (keys=None) goes digest-first — per-shard
             # rollups, then per-key digests for mismatched shards, then a
@@ -2214,12 +2314,12 @@ class MochiReplica:
             # resyncs already name their keys.
             if key_tuple is None:
                 await asyncio.gather(
-                    *(pull_peer_delta(sid, info) for sid, info in peers_now())
+                    *(alive(pull_peer_delta(sid, info)) for sid, info in peers_now())
                 )
             else:
                 await asyncio.gather(
                     *(
-                        pull_peer(sid, info, None, key_tuple)
+                        alive(pull_peer(sid, info, None, key_tuple))
                         for sid, info in peers_now()
                     )
                 )
@@ -2229,8 +2329,39 @@ class MochiReplica:
         if self.storage.dirty:
             # resync applies stage commits like any other Write2: make the
             # pulled state durable before reporting it recovered
+            wall0, t0 = time.time(), time.perf_counter()
             await self.storage.flush()
+            run.tick(
+                stages.RESYNC_FLUSH, stages.SPAN_FLUSH, "flush_ms",
+                wall0, time.perf_counter() - t0,
+                entries=run.report["entries_adopted"],
+            )
+        # complete: no shard of ours lost more than f of its other owners
+        # (the f the quorum rules already tolerate) to an abandoned pull
+        f = self.config.f
+        report = run.finish(
+            not abandoned
+            or all(
+                sum(1 for sid in owners if sid in abandoned) <= f
+                for owners in map(
+                    self.config.replica_set_for_token, range(SHARD_TOKENS)
+                )
+                if self.server_id in owners
+            )
+        )
+        if key_tuple is None:
+            self._resync_report = report
+            if not report["complete"]:
+                LOG.warning(
+                    "resync INCOMPLETE: pulls of %s were abandoned",
+                    sorted(abandoned),
+                )
         return len(advanced_keys)
+
+    def resync_report(self) -> Optional[Dict[str, object]]:
+        """What the last FULL ``resync`` did (``server/stages.py``
+        ``ResyncRun``), or None where this process made none."""
+        return self._resync_report
 
     def _prepare_certificate(self, wc: WriteCertificate, defer_own: bool = False) -> tuple:
         """Sync half of certificate verification: resolve signer keys, run
@@ -2417,6 +2548,7 @@ class MochiReplica:
         during resync — the round-14 incremental state-transfer evidence)."""
         st = self.storage.stats()
         c = self.metrics.counters
+        served = self.metrics.timers.get(stages.SYNC_SERVE)
         st["anti_entropy"] = {
             "digest_pages": c.get("replica.resync-digest-pages", 0),
             "shards_matched": c.get("replica.resync-shards-matched", 0),
@@ -2424,7 +2556,13 @@ class MochiReplica:
             "delta_keys_pulled": c.get("replica.resync-delta-keys", 0),
             "full_keys_pulled": c.get("replica.resync-full-keys", 0),
             "applied": c.get("replica.resync-applied", 0),
+            # the serving side of a peer's resync (server/stages.py)
+            "sync_pages_served": c.get(stages.SYNC_PAGES_SERVED, 0),
+            "sync_entries_served": c.get(stages.SYNC_ENTRIES_SERVED, 0),
+            "sync_serve_ms": served.total_seconds * 1e3 if served else 0.0,
         }
+        # this process's last FULL resync, beside the engine's "replay"
+        st["resync"] = self._resync_report
         return st
 
     def byzantine_stats(self) -> Dict[str, object]:
@@ -2459,36 +2597,47 @@ class MochiReplica:
         bitmap = await self._verify_counted(items) if items else []
         return self._finish_certificate(wc, prep, bitmap)
 
-    async def _check_certificate_fast(
-        self, wc: WriteCertificate
-    ) -> Optional[WriteCertificate]:
-        """Aggregate-only certificate check (round 18 resync path): the
-        one-attestation verify, memoized cluster-wide by certificate hash,
-        so a resync page of certs the cluster already committed costs zero
-        signature verifies.  Returns None when the fast path is off, the
-        aggregate is ineligible, or it FAILS — callers must then audit via
+    async def _check_certificates_fast(
+        self, certificates: "Sequence[WriteCertificate]"
+    ) -> "List[Optional[WriteCertificate]]":
+        """Aggregate-only certificate check of a resync page (round 18): the
+        grants of all its certificates in ONE ``verify_batch`` of the
+        verifier chain (which cuts an oversize request itself), as the
+        verified replay hands a chunk over (``storage/durable.py``
+        ``_ReplayPipeline``); until PR 33 it was one 3-item round trip a
+        certificate, twice.  The verdict a certificate is what that gave:
+        the certificate where every one of its grants verified; None where
+        the fast path is off, the certificate is ineligible, or a grant
+        FAILED — callers must then audit that certificate via
         ``_check_certificate`` (the attributing per-grant path) before any
         adoption; this method never adopts on failure itself."""
         if not self.fast_path:
-            return None
-        agg = self._aggregate_items(wc)
-        if agg is None:
-            return None
-        akey, aitems, _server_ids = agg
+            return [None] * len(certificates)
+        items: List[VerifyItem] = []
+        slices: List[Optional[slice]] = []
+        for wc in certificates:
+            agg = self._aggregate_items(wc)
+            if agg is None:
+                slices.append(None)
+                continue
+            slices.append(slice(len(items), len(items) + len(agg[1])))
+            items.extend(agg[1])
         try:
-            ok = await self._verify_aggregate_counted(akey, aitems)
+            bitmap = await self._verify_counted(items) if items else []
         except asyncio.CancelledError:
             raise
         except Exception:
-            ok = False
-        if ok:
-            self._note_grant_evidence(wc.grants.values())
-            return WriteCertificate(dict(wc.grants))
-        # Someone in the grant set lied (or the cert is malformed): the
-        # caller pays the per-item audit so the conviction machinery can
-        # attribute WHICH grant was bad.
-        self.metrics.mark("replica.cert-agg-audit")
-        return None
+            bitmap = [False] * len(items)
+        out: List[Optional[WriteCertificate]] = []
+        for wc, span in zip(certificates, slices):
+            if span is not None and all(bitmap[span]):
+                self._note_grant_evidence(wc.grants.values())
+                out.append(WriteCertificate(dict(wc.grants)))
+                continue
+            if span is not None:
+                self.metrics.mark("replica.cert-agg-audit")
+            out.append(None)
+        return out
 
     def fastpath_stats(self) -> Dict[str, object]:
         """Round-18 fast-path observability: session/checkpoint posture and
@@ -2543,11 +2692,12 @@ wire_taint.register_verifier_edge(
     expect_live=True,
 )
 wire_taint.register_verifier_edge(
-    "cert-aggregate-resync", "_check_certificate_fast",
+    "cert-aggregate-resync", "_check_certificates_fast",
     [wire_taint.CLS_CERT],
-    note="resync/anti-entropy aggregate-first certificate recheck; audits "
-         "through _check_certificate (the builtin certificate-recheck edge) "
-         "on aggregate failure",
+    note="resync/anti-entropy aggregate-first certificate recheck, a page's "
+         "certificates in one batched call; audits through "
+         "_check_certificate (the builtin certificate-recheck edge) on a "
+         "failed grant",
     expect_live=True,
 )
 wire_taint.register_verifier_edge(

@@ -474,13 +474,16 @@ class ProcessCluster:
         sp.proc.send_signal(sig)
         return sp.proc.pid
 
-    async def restart_replica(self, server_id: str) -> None:
+    async def restart_replica(self, server_id: str, *, resync: bool = False) -> None:
         """Re-launch the (killed or exited) process hosting ``server_id``
         with its EXACT original argv — same ids, same ``--storage-dir``,
         same knobs — and block until every hosted replica reprints READY.
         With a durable ``storage_dir`` the child recovers its committed
         state from its own WAL + snapshot before READY (verified replay);
         without one it boots empty, the posture the resync protocol covers.
+        ``resync=True`` adds ``--resync-on-boot`` to THIS spawn alone (the
+        runbook's answer to a lost disk or a replaced node, docs/OPERATIONS.md
+        §3): READY then means re-hydrated from the peers.
         The cross-process twin of ``VirtualCluster.restart_replica``."""
         sp = self.host_process[server_id]
         assert sp.proc is not None and sp.argv, "cluster not started"
@@ -491,7 +494,13 @@ class ProcessCluster:
             )
         await self._reap([sp])  # collect the corpse + stop its pump
         # mochi-lint: disable=await-races -- sp is identity-stable: host_process is written once in start() and cleared only in close(); the reap cannot remap which process hosts server_id
-        await self._spawn(sp, self._spawn_env)
+        argv = sp.argv
+        if resync and "--resync-on-boot" not in argv:
+            sp.argv = [*argv, "--resync-on-boot"]
+        try:
+            await self._spawn(sp, self._spawn_env)
+        finally:
+            sp.argv = argv  # the next plain restart is the original again
         if self.pin_cores and hasattr(os, "sched_setaffinity"):
             try:
                 os.sched_setaffinity(

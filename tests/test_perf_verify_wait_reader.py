@@ -13,3 +13,17 @@ for p in (PERF, os.path.join(PERF, "tests")):
 
 from test_memo_settle_reader import *  # noqa: E402,F401,F403
 from test_verify_wait_reader import *  # noqa: E402,F401,F403
+
+
+def test_memo_settle_is_keyed_to_the_two_recovery_cells():  # noqa: F811
+    """PR 33 appended five entries to ``per_layer``: PR 30's is found by its
+    name, as ``test_verify_wait_is_keyed_to_the_two_recovery_cells`` finds PR
+    28's, not as the list's last (``perf/tests/test_memo_settle_reader.py`` is
+    a benchmark file, and a PR that is not a ``benchmark`` PR edits none)."""
+    import test_memo_settle_reader as m
+
+    entry = next(e for e in m.base.run.load_cell(m.base.REPO, "rf4-recover")["bench"]["per_layer"]
+                 if e["name"] == m.NAME)
+    assert entry == {
+        "name": m.NAME, "unit": "us", "better": "lower", "source": "program_span",
+        "layer": "verifier SPI and service queue", "moves": "recover_s", "workloads": m.base.RECOVERY_CELLS}

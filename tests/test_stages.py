@@ -399,13 +399,16 @@ def test_the_harness_reads_the_programs_by_the_names_the_product_pins():
     try:
         import hostspans
         import layer_reader
+
+        # the reader imports the harness's ``schedule``: loaded while perf/ is on the path, so
+        # that the test does not lean on another file of its worker having imported it first
+        settle = layer_reader.load(os.path.join(REPO, "perf", "layer_metrics", "recovery.memo_settle_us_per_item.py"))
     finally:
         sys.path.remove(os.path.join(REPO, "perf"))
     from mochi_tpu.crypto import batch_verify as bv, comb
 
     assert (hostspans.LADDER_PROGRAM, hostspans.COMB_PROGRAM) == (bv.LADDER_PROGRAM, comb.COMB_PROGRAM)
     assert hostspans.SPAN_PREFIX == stages.SPAN_PREFIX
-    settle = layer_reader.load(os.path.join(REPO, "perf", "layer_metrics", "recovery.memo_settle_us_per_item.py"))
     assert settle.TIMER == stages.MEMO_SETTLE
     named = {n for _, names in hostspans.CAUSES for n in names} | {hostspans.TICK}
     ours = {v for k, v in vars(stages).items() if k.startswith("SPAN_") and k != "SPAN_PREFIX"}

@@ -303,21 +303,29 @@ LIVE_MUTATIONS = [
      "if not self._auth_mac(env):",
      "if not bool(env):",
      "-apply"),
-    # round 18: sync-adopt checks the aggregate fast edge first, then the
-    # attributing per-grant audit — dropping BOTH must convict the sink
-    # (the memo warm-up gather above the loop discards its result, so it
-    # alone cannot launder the entry)
+    # round 18: sync-adopt checks the aggregate fast edge first (PR 33: a
+    # page's certificates in one batched call), then the attributing
+    # per-grant audit of whatever that left unverified — dropping BOTH must
+    # convict the sink
     ("mochi_tpu/server/replica.py",
-     "checked = await self._check_certificate_fast(\n"
-     "                        entry.certificate\n"
+     "verdicts = await self._check_certificates_fast(\n"
+     "                        [e.certificate for e in owned]\n"
      "                    )\n"
+     "                verify_s += time.perf_counter() - t0\n"
+     "                for entry, verdict in zip(owned, verdicts):\n"
+     "                    t0 = time.perf_counter()\n"
+     "                    checked = verdict\n"
      "                    if checked is None:\n"
-     "                        # fast path off, aggregate ineligible, or a failed\n"
-     "                        # aggregate: the attributing per-grant audit\n"
-     "                        checked = await self._check_certificate(\n"
-     "                            entry.certificate\n"
-     "                        )",
-     "checked = entry.certificate",
+     "                        # fast path off, aggregate ineligible, or a grant\n"
+     "                        # that did not verify: the attributing per-grant audit\n"
+     "                        with run.waiting():\n"
+     "                            checked = await self._check_certificate(\n"
+     "                                entry.certificate\n"
+     "                            )\n",
+     "verdicts = [e.certificate for e in owned]\n"
+     "                for entry, verdict in zip(owned, verdicts):\n"
+     "                    checked = entry.certificate\n"
+     "                    t0 = time.perf_counter()\n",
      "sync-adopt"),
     # round 17: the paged engine's fault path — drop the per-entry recheck
     # between read_page_entry (taint source) and apply_sync_entry (CERT
