@@ -183,17 +183,29 @@ async def amain(args) -> None:
         replicas.append(replica)
         resynced = ""
         if args.resync_on_boot:
-            # Replica state is in-memory (like the reference): after a restart,
-            # pull committed state from peers before serving (paper's UptoSpeed).
+            # After the verified replay of whatever --storage-dir / --data-dir
+            # held (nothing, on a lost disk): pull committed state from peers
+            # before serving (paper's UptoSpeed).  ONE pass, bounded by the
+            # peers' stores as they stood when it began: under load there is
+            # no pass that finds nothing new, so none is waited for.  What
+            # commits during the pass reaches this replica as it reaches any
+            # serving one (it listens since start(): Write2s, client nudges).
             advanced = await replica.resync()
             report = replica.resync_report()
             logging.info(
-                "boot resync: %d objects recovered (%d entries pulled in %.0f ms)",
+                "boot resync: %d objects recovered (%d entries pulled in %.0f ms; "
+                "%d of %d shards and %d of %d keys matched, %d pulls abandoned): "
+                "caught up with what a quorum of peers held at epoch_us %d",
                 advanced, report["entries_pulled"], report["ms"],
+                report["shards_matched"], report["shards_compared"],
+                report["keys_matched"], report["keys_compared"],
+                sum(p["abandoned"] for p in report["by_peer"].values()),
+                report["began_epoch_us"],
             )
-            # READY after --resync-on-boot means re-hydrated from the peers; a
-            # boot whose pulls were abandoned past what f tolerates says so
-            # here (and in /status storage.resync) instead of passing for one
+            # READY after --resync-on-boot means caught up to the pass's start
+            # (``began_epoch_us`` in /status storage.resync and in the line
+            # above); a boot whose pulls were abandoned past what f tolerates
+            # says so here (and in /status) instead of passing for one
             resynced = " resync=complete" if report["complete"] else " resync=INCOMPLETE"
         if args.admin_port is not None:
             from ..admin import AdminServer
@@ -325,8 +337,10 @@ def main(argv=None) -> None:
     parser.add_argument(
         "--resync-on-boot",
         action="store_true",
-        help="pull committed state from peers before serving (UptoSpeed); "
-        "the READY line then ends in resync=complete or resync=INCOMPLETE",
+        help="after the replay of --storage-dir/--data-dir, pull what the peers "
+        "hold and this replica lacks, in ONE bounded pass, before serving "
+        "(UptoSpeed); the READY line then ends in resync=complete or "
+        "resync=INCOMPLETE",
     )
     parser.add_argument(
         "--require-client-auth",

@@ -2055,6 +2055,13 @@ class MochiReplica:
         transaction-hash match, staleness), so a Byzantine peer can at worst
         send us stale-but-valid state, which the timestamp check ignores.
 
+        One pass, bounded: each peer is compared and pulled once, against
+        its store as it stands when asked, and the run ends; it does not go
+        round again until a pass finds nothing new (under load none does).
+        A complete run has caught up with what a quorum of the peers held
+        when it began (the report's ``began_epoch_us``); what commits
+        meanwhile arrives as it does at any serving replica.
+
         A page request that fails or times out is sent once more; a second
         failure (or a refusal) ends THAT pull of that peer and is counted
         (``abandoned``).  A full run (no ``keys``) leaves its report —
@@ -2200,9 +2207,15 @@ class MochiReplica:
             if not isinstance(res, SyncDigestFromServer) or res.shards is None:
                 await pull_peer(sid, info, None, None, count="full")
                 return
+            wall0, t0 = time.time(), time.perf_counter()
             local_shards = {
                 t: (n, d) for t, n, d in self.store.export_shard_digests()
             }
+            run.tick(
+                stages.RESYNC_DIGEST_LOCAL, stages.SPAN_DIGEST_LOCAL,
+                "digest_local_ms", wall0, time.perf_counter() - t0,
+                peer=sid, entries=sum(n for n, _ in local_shards.values()),
+            )
             matched = 0
             mismatched: List[int] = []
             for token, n, digest in res.shards:
@@ -2221,16 +2234,22 @@ class MochiReplica:
                     matched += 1
                 else:
                     mismatched.append(token)
-            if matched:
-                self.metrics.mark("replica.resync-shards-matched", matched)
+            run.count("shards_compared", matched + len(mismatched))
+            run.count("shards_matched", matched)
             if not mismatched:
                 return
             wanted = set(mismatched)
+            wall0, t0 = time.time(), time.perf_counter()
             local_keys = {
                 key: d
                 for key, token, d in self.store._iter_digests()
                 if token in wanted
             }
+            run.tick(
+                stages.RESYNC_DIGEST_LOCAL, stages.SPAN_DIGEST_LOCAL,
+                "digest_local_ms", wall0, time.perf_counter() - t0,
+                peer=sid, entries=len(local_keys),
+            )
             delta: List[str] = []
             keys_matched = 0
             after: Optional[str] = None
@@ -2259,8 +2278,8 @@ class MochiReplica:
                 if len(res.keys) < 4096:
                     break
                 after = res.keys[-1][0]
-            if keys_matched:
-                self.metrics.mark("replica.resync-keys-matched", keys_matched)
+            run.count("keys_compared", keys_matched + len(delta))
+            run.count("keys_matched", keys_matched)
             for i in range(0, len(delta), page):
                 if sid in abandoned:
                     return
@@ -2551,8 +2570,6 @@ class MochiReplica:
         served = self.metrics.timers.get(stages.SYNC_SERVE)
         st["anti_entropy"] = {
             "digest_pages": c.get("replica.resync-digest-pages", 0),
-            "shards_matched": c.get("replica.resync-shards-matched", 0),
-            "keys_matched": c.get("replica.resync-keys-matched", 0),
             "delta_keys_pulled": c.get("replica.resync-delta-keys", 0),
             "full_keys_pulled": c.get("replica.resync-full-keys", 0),
             "applied": c.get("replica.resync-applied", 0),

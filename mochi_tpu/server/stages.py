@@ -23,6 +23,7 @@ from ..obs import trace as obs_trace
 RESYNC = "replica.resync"  # one whole run, up to its flush
 RESYNC_CONFIG = "replica.resync-config"  # the two _CONFIG_ passes of a run: one tick a run that made them
 RESYNC_DIGEST = "replica.resync-digest"  # one digest round trip (shard rollups, or a page of key digests)
+RESYNC_DIGEST_LOCAL = "replica.resync-digest-local"  # one walk of this replica's OWN digests: its shard rollups, or its key digests of the shards that differ (one each a peer)
 RESYNC_PULL = "replica.resync-pull"  # one entry-page round trip, request sent -> page decoded
 RESYNC_VERIFY = "replica.resync-verify"  # one page: this pull's awaits of its certificates' verdicts
 RESYNC_APPLY = "replica.resync-apply"  # one page: store.apply_sync_entry over its verified entries
@@ -35,16 +36,23 @@ SYNC_ENTRIES_SERVED = "replica.sync-entries-served"
 # ---- spans: constants, all under one prefix
 SPAN_PREFIX = "mochi.replica.resync."
 SPAN_DIGEST = "mochi.replica.resync.digest"  # peer, entries (digests in the page)
+SPAN_DIGEST_LOCAL = "mochi.replica.resync.digest-local"  # peer, entries (own digests walked)
 SPAN_PULL = "mochi.replica.resync.pull"  # peer, entries
 SPAN_VERIFY = "mochi.replica.resync.verify"  # peer, entries
 SPAN_APPLY = "mochi.replica.resync.apply"  # peer, entries
 SPAN_FLUSH = "mochi.replica.resync.flush"
 
 # ---- the report's stage keys (milliseconds)
-STAGE_KEYS = ("config_ms", "digest_ms", "pull_ms", "verify_ms", "verify_wait_ms",
-              "apply_ms", "flush_ms")
+STAGE_KEYS = ("config_ms", "digest_ms", "digest_local_ms", "pull_ms", "verify_ms",
+              "verify_wait_ms", "apply_ms", "flush_ms")
+# what the digest stage decided, summed over the peers: owned shards a peer has a
+# rollup for, and how many of them equal this replica's; owned keys of the shards
+# that differ that the peer named, and how many of them equal this replica's (the
+# rest, ``keys_compared`` - ``keys_matched``, are asked for: ``entries_pulled``)
+DIGEST_KEYS = ("shards_compared", "shards_matched", "keys_compared", "keys_matched")
 COUNTER_KEYS = ("pages", "digest_pages", "entries_pulled", "entries_adopted",
-                "entries_redundant", "entries_unowned", "bad_certificates", "bytes_pulled")
+                "entries_redundant", "entries_unowned", "bad_certificates", "bytes_pulled",
+                *DIGEST_KEYS)
 PEER_KEYS = ("pages", "entries", "adopted", "abandoned")
 
 
@@ -75,7 +83,11 @@ class ResyncRun:
             )
         self.ctx = ctx
         self.report: Dict[str, object] = {
-            "full": full, "complete": False, "ms": 0.0,
+            "full": full, "complete": False,
+            # the run's start on the ``obs/trace.py`` clock: a complete run has
+            # caught up with what a quorum of the peers held THEN, no later
+            "began_epoch_us": time.time_ns() // 1000,
+            "ms": 0.0,
             **{k: 0.0 for k in STAGE_KEYS}, **{k: 0 for k in COUNTER_KEYS},
             "peers": 0, "by_peer": {},
         }

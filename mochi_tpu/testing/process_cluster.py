@@ -27,6 +27,9 @@ Lifecycle contract with ``server/__main__.py``:
 * drain — ``close()`` SIGTERMs the children, which stop accepting, finish
   admitted work, flush coalesced writes, snapshot (if configured) and exit
   0; non-zero exits are collected in ``returncodes`` for tests to assert;
+* no orphans — every child is started so that the kernel SIGKILLs it when
+  this process ends, however it ends (``die_with_parent``, Linux): a harness
+  killed from outside never reaches ``close()``;
 * crash detection — ``check_alive()`` raises if any child exited early,
   and ``kill_replica(sid)`` SIGKILLs the process hosting ``sid`` for
   fault-injection tests (with process-per-replica, exactly one replica).
@@ -35,6 +38,8 @@ Lifecycle contract with ``server/__main__.py``:
 from __future__ import annotations
 
 import asyncio
+import ctypes
+import functools
 import hashlib
 import os
 import signal
@@ -61,6 +66,42 @@ def _free_tcp_ports(n: int) -> List[int]:
     finally:
         for s in socks:
             s.close()
+
+
+_PR_SET_PDEATHSIG = 1  # <linux/prctl.h>
+
+
+@functools.cache
+def _load_prctl():
+    """libc's ``prctl`` (Linux), or None where there is none to be had.  Loaded
+    once, in the parent: a forked child must not import or resolve symbols."""
+    if not sys.platform.startswith("linux"):
+        return None
+    try:
+        prctl = ctypes.CDLL(None, use_errno=True).prctl
+    except (OSError, AttributeError):
+        return None
+    prctl.argtypes = [ctypes.c_int, ctypes.c_ulong, ctypes.c_ulong, ctypes.c_ulong, ctypes.c_ulong]
+    prctl.restype = ctypes.c_int
+    return prctl
+
+
+def die_with_parent(parent_pid: int):
+    """A ``preexec_fn``: the child asks the kernel for SIGKILL when the thread
+    that started it ends (``PR_SET_PDEATHSIG``; it survives the exec), then
+    looks whether that has happened already.  A harness that is killed, or ends
+    without its ``close()``, leaves no replica and no service behind: five
+    replicas and the process that holds the chip outlived a killed
+    ``perf/run.py`` before.  None where ``prctl`` is not to be had."""
+    prctl = _load_prctl()
+    if prctl is None:
+        return None
+
+    def in_child() -> None:
+        if prctl(_PR_SET_PDEATHSIG, signal.SIGKILL, 0, 0, 0) != 0 or os.getppid() != parent_pid:
+            os._exit(127)  # the parent went between the fork and here
+
+    return in_child
 
 
 class _ServerProcess:
@@ -364,12 +405,15 @@ class ProcessCluster:
     @staticmethod
     async def _spawn(sp: _ServerProcess, env: Optional[Dict[str, str]]) -> None:
         """(Re)launch ``sp.argv``: stdout piped for the READY lines, stderr
-        appended to the process's log."""
+        appended to the process's log.  The child, replica or service, cannot
+        outlive this process (``die_with_parent``; the fork is made on the
+        loop's thread, which lives as long as the cluster's owner does)."""
         loop = asyncio.get_running_loop()
         log = await loop.run_in_executor(None, open, sp.log_path, "ab")
         try:
             sp.proc = await asyncio.create_subprocess_exec(
                 *sp.argv, env=env, stdout=asyncio.subprocess.PIPE, stderr=log,
+                preexec_fn=die_with_parent(os.getpid()),
             )
         finally:
             log.close()  # child holds its own descriptor now
@@ -482,8 +526,11 @@ class ProcessCluster:
         state from its own WAL + snapshot before READY (verified replay);
         without one it boots empty, the posture the resync protocol covers.
         ``resync=True`` adds ``--resync-on-boot`` to THIS spawn alone (the
-        runbook's answer to a lost disk or a replaced node, docs/OPERATIONS.md
-        §3): READY then means re-hydrated from the peers.
+        runbook's restart of a crashed replica, and with its directory
+        emptied its answer to a lost disk or a replaced node,
+        docs/OPERATIONS.md §3): READY then comes after the replay AND one
+        resync pass, and means caught up with what the peers held when
+        that pass began.
         The cross-process twin of ``VirtualCluster.restart_replica``."""
         sp = self.host_process[server_id]
         assert sp.proc is not None and sp.argv, "cluster not started"

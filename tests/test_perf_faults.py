@@ -23,14 +23,16 @@ globals().update({k: v for k, v in vars(_mod).items() if not k.startswith("_")})
 
 
 def test_the_shipped_mixes_load_and_only_one_has_a_schedule(tmp_path):  # noqa: F811
-    """Since PR 33 two shipped mixes have a schedule (``ycsb-a-kill1`` and
-    ``ycsb-a-kill1-rehydrate``); ``perf/tests/test_schedule.py`` is a benchmark
-    file and still says one, so tier-1 holds the rest of what it held here."""
+    """Since PR 37 three shipped mixes have a schedule (``ycsb-a-kill1``,
+    ``ycsb-a-kill1-rehydrate``, ``ycsb-a-kill1-resync``) and the verbs' files
+    are four; ``perf/tests/test_schedule.py`` is a benchmark file and still
+    says one, so tier-1 holds the rest of what it held here."""
     m = _mod
     assert len(m.schedule.validate(m.ycsb.load_traffic(m.mix_file(tmp_path, m.KILL1))["faults"], m.FAULTS)) == 2
     shipped = {n[:-5]: m.ycsb.load_traffic(os.path.join(PERF, "traffic", n))
                for n in os.listdir(os.path.join(PERF, "traffic"))}
-    assert {k for k, v in shipped.items() if "faults" in v} == {"ycsb-a-kill1", "ycsb-a-kill1-rehydrate"}
+    assert {k for k, v in shipped.items() if "faults" in v} == {
+        "ycsb-a-kill1", "ycsb-a-kill1-rehydrate", "ycsb-a-kill1-resync"}
     # a verb is a file: no verb without one, no file without a cell that runs it
-    assert {n[:-3] for n in os.listdir(m.FAULTS) if n.endswith(".py")} == \
-        {ev["do"] for v in shipped.values() for ev in v.get("faults", ())}
+    verbs = {n[:-3] for n in os.listdir(m.FAULTS) if n.endswith(".py")}
+    assert verbs == {ev["do"] for v in shipped.values() for ev in v.get("faults", ())} and len(verbs) == 4

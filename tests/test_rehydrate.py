@@ -131,7 +131,8 @@ def test_the_rehydrated_store_equals_the_reference_key_for_key(tmp_path, scenari
         assert report["by_peer"][PEER]["adopted"] == 0 and report["complete"]
     elif scenario == "silent":
         # three pulls of it (two config passes, the shard digests) ended on a page that failed twice
-        assert report["by_peer"][PEER] == {"pages": 0, "entries": 0, "adopted": 0, "abandoned": 3}
+        # (since PR 37 the digest stage's counters stand beside them: nothing was compared with it)
+        assert report["by_peer"][PEER] == dict.fromkeys(stages.PEER_KEYS, 0) | {"abandoned": 3}
         assert report["complete"] and report["bad_certificates"] == 0   # f=1 of its shards' other owners
         assert sum(p["abandoned"] for s, p in report["by_peer"].items() if s != PEER) == 0
     else:
@@ -158,9 +159,10 @@ def test_each_stage_ticks_once_a_run_a_round_trip_or_a_page_and_the_spans_are_na
     assert all(s["sync_serve_ms"] > 0 for s in facts.served.values())
     # spans: the stages' constants and nothing else under the prefix, one a tick, forced (no sampling)
     names = [ev["name"] for ev in facts.spans]
-    assert set(names) == {stages.SPAN_DIGEST, stages.SPAN_PULL, stages.SPAN_VERIFY,
+    assert set(names) == {stages.SPAN_DIGEST, stages.SPAN_DIGEST_LOCAL, stages.SPAN_PULL, stages.SPAN_VERIFY,
                           stages.SPAN_APPLY, stages.SPAN_FLUSH}
     for span, timer in ((stages.SPAN_DIGEST, stages.RESYNC_DIGEST), (stages.SPAN_PULL, stages.RESYNC_PULL),
+                        (stages.SPAN_DIGEST_LOCAL, stages.RESYNC_DIGEST_LOCAL),
                         (stages.SPAN_VERIFY, stages.RESYNC_VERIFY), (stages.SPAN_APPLY, stages.RESYNC_APPLY),
                         (stages.SPAN_FLUSH, stages.RESYNC_FLUSH)):
         assert names.count(span) == t[timer]
@@ -169,7 +171,8 @@ def test_each_stage_ticks_once_a_run_a_round_trip_or_a_page_and_the_spans_are_na
     assert sum(a["entries"] for a in pulls) == report["entries_pulled"]
     assert len({ev["args"]["trace_id"] for ev in facts.spans}) == 1   # one run, one trace
     # every key of the report is one that stages.py names
-    assert set(report) == {"full", "complete", "ms", "peers", "by_peer", *stages.STAGE_KEYS, *stages.COUNTER_KEYS}
+    assert set(report) == {"full", "complete", "began_epoch_us", "ms", "peers", "by_peer",
+                           *stages.STAGE_KEYS, *stages.COUNTER_KEYS}
     assert all(set(p) == set(stages.PEER_KEYS) for p in report["by_peer"].values())
 
 
