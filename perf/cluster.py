@@ -128,6 +128,41 @@ def replica_counters(statuses: list) -> dict:
     }
 
 
+def replica_pace(statuses: list) -> dict:
+    """Per replica, what tells a slow one from its peers: transport drains and
+    the frames in them, the seconds the drains took, log fsyncs and their
+    milliseconds, snapshots taken, log entries."""
+    out = {}
+    for r in statuses:
+        b, st = r["batching"], r["storage"]
+        drains, lat, fsync = (b.get(k, {}) for k in
+                              ("transport.drain-frames", "transport.drain-latency", "storage-fsync-ms"))
+        out[r["server_id"]] = {
+            "drains": int(drains.get("count", 0)), "frames": float(drains.get("sum", 0.0)),
+            "drain_s": float(lat.get("sum", 0.0)),
+            "fsyncs": int(fsync.get("count", 0)), "fsync_ms": float(fsync.get("sum", 0.0)),
+            "snapshots": int(st.get("snapshots", 0)), "wal_entries": int(st.get("wal_entries", 0)),
+        }
+    return out
+
+
+def pace_delta(before: dict, after: dict) -> dict:
+    """``replica_pace`` over a window; a replica that was started again inside
+    it (its counters began anew) reports what it counted since."""
+    out = {}
+    for sid, b in after.items():
+        a = before.get(sid, {})
+        if any(b[k] < a.get(k, 0) for k in b):
+            a = {}
+        d = {k: b[k] - a.get(k, 0) for k in b}
+        out[sid] = {"frames": int(d["frames"]), "drains": d["drains"],
+                    "drain_ms": round(1e3 * d["drain_s"] / d["drains"], 3) if d["drains"] else None,
+                    "fsyncs": d["fsyncs"],
+                    "fsync_ms": round(d["fsync_ms"] / d["fsyncs"], 3) if d["fsyncs"] else None,
+                    "snapshots": d["snapshots"], "wal_entries": d["wal_entries"]}
+    return out
+
+
 # the counters of ``replica_counters`` that add up over replicas and time
 ADDITIVE = ("fallback_batches", "remote_batches", "fsyncs", "drain_count", "drain_frames")
 

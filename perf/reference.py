@@ -193,6 +193,25 @@ def summarize(ops, seconds: float, t_end: float) -> dict:
     }
 
 
+def by_second(ops, t_start: float, seconds: float) -> dict:
+    """The window second by second: operations answered in each second, and
+    the median latency of the reads issued in it (ms; None where none was).
+    A level that sets in with a fault shows here, where the window's own
+    numbers show only the mix."""
+    n = int(math.ceil(seconds))
+    answered = [0] * n
+    reads = [[] for _ in range(n)]
+    for op in ops:
+        done = op[T_DONE] - t_start
+        if op[OK] and 0 <= done <= seconds:  # as the rate counts it
+            answered[min(int(done), n - 1)] += 1
+        issued = int(op[T_ISSUE] - t_start)
+        if op[KIND] == READ and op[OK] and 0 <= issued < n:
+            reads[issued].append((op[T_DONE] - op[T_ISSUE]) * 1e3)
+    return {"answered": answered,
+            "read_p50_ms": [round(percentile(r, 50), 2) if r else None for r in reads]}
+
+
 # what a configuration file states -> what a replica's /status reports for it
 ENGINE_REPORTED = {"wal": "durable", "paged": "paged"}
 

@@ -35,6 +35,7 @@ T_PROCESS_START = time.monotonic()
 
 import argparse
 import asyncio
+import collections
 import json
 import math
 import os
@@ -50,6 +51,7 @@ for _p in (REPO, PERF):
         sys.path.insert(0, _p)
 
 import layer_reader  # noqa: E402
+import treestate  # noqa: E402
 
 # the profiler's share of the window: its end, or from the command of a
 # scheduled verb that brings an end-to-end metric (``trace_from_s``)
@@ -57,6 +59,10 @@ TRACE_SECONDS = 5.0
 BAD_WRITE2S = 4
 WARM_HEADROOM = 2  # flushes pile up: the largest seen was 1.4 x threads x quorum items
 READY_TIMEOUT_S = 1150.0  # a first run compiles; the contract allows it 1200 s
+# a run still going at 1200 s is ended from outside and leaves no word of where
+# it stood (the commentary is on standard output, which nobody keeps): it ends
+# itself before that, with the last it said on standard error
+RUN_LIMIT_S = 1080.0
 FAILED_LATENCY_MS = 1e9  # printed where a percentile falls on a failed operation
 # an update acknowledged this long before a kill is in the killed replica's log
 # (reference.check_direct); the longest update of any run so far took 0.9 s
@@ -67,8 +73,22 @@ class RunFailure(Exception):
     """The run cannot produce a result (no chip, a child died, load failed)."""
 
 
+SAID = collections.deque(maxlen=8)  # the commentary's last lines, for a run that fails
+
+
 def say(*parts) -> None:
+    SAID.append(" ".join(map(str, parts))[:400])
     print("[perf]", *parts, flush=True)
+
+
+async def within(work, limit_s: float):
+    """``await work``, given up after ``limit_s``: the run's own ``finally``
+    stops what it started, and the failure says how far the run had come."""
+    try:
+        return await asyncio.wait_for(work, limit_s)
+    except asyncio.TimeoutError:
+        raise RunFailure(f"still running {time.monotonic() - T_PROCESS_START:.0f}s after the "
+                         f"process began (the limit is {RUN_LIMIT_S:.0f}s)") from None
 
 
 # ------------------------------------------------------------------ the data
@@ -366,7 +386,8 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         del os.environ[k]  # the product as shipped: nothing tuned from outside
     if removed:
         say("dropped from the environment:", sorted(removed))
-    uds = len(tempfile.gettempdir()) < 60  # AF_UNIX paths hold ~100 characters
+    tmp_dir = tempfile.gettempdir()
+    uds = len(tmp_dir) < 60  # AF_UNIX paths hold ~100 characters
     say(f"cell {cell['name']}: n={n} rf={rf} records={records} threads={threads} "
         f"generator_processes={gen_procs} transport={'uds' if uds else 'tcp'} "
         f"seed={seed} seconds={seconds} trace={args.trace} rehearse={rehearse}")
@@ -470,14 +491,19 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
             raise RunFailure(f"load failed: {[l['load_failed'] for l in loaded]}")
 
         # ---- the window
-        def snapshot() -> dict:
+        def snapshot(name: str) -> dict:
             cpu = pc.cpu_seconds()
             status = pc.service_status()
+            statuses = pc.replica_statuses()
+            if args.keep:  # every replica's whole /status, as it stood
+                with open(os.path.join(out_dir, f"status-{name}.json"), "w") as fh:
+                    json.dump({"service": status, "replicas": statuses}, fh)
             return {
                 "service": cl.service_counters(status),
                 # the stage timers and histograms (verifier/stages.py), as they are
                 "service_stages": status.get("stages"),
-                "replicas": cl.replica_counters(pc.replica_statuses()),
+                "replicas": cl.replica_counters(statuses),
+                "pace": cl.replica_pace(statuses),
                 "replica_cpu": sum(v for k, v in cpu.items() if k.startswith("proc-")),
                 "service_cpu": cpu.get("verifier-service", 0.0),
                 "cache_entries": cl.cache_entries(cache_dir),
@@ -497,7 +523,16 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
                     "replica": cl.replica_view(pc.replica_status(server_id, 10.0) if up else None),
                     "process_cpu": sp.cpu_seconds() if up else None}
 
-        before = snapshot()
+        def pids() -> dict:  # asked at either end: a restarted replica has another
+            return {"harness": os.getpid(), "service": pc.service_process.proc.pid,
+                    **{f"replicas-{sp.index}": sp.proc.pid for sp in pc.processes},
+                    **{f"generator-{i}": p.pid for i, p in enumerate(workers.procs)}}
+
+        facts = treestate.static_facts(REPO, out_dir, tmp_dir, "uds" if uds else "tcp",
+                                       args.natives_before)
+        say("tree:", json.dumps(facts))
+        before = snapshot("before")
+        placed = treestate.places(pids())
         t_start = time.monotonic() + 1.0
         t_end = t_start + seconds
         workers.go(t_start, seconds)
@@ -515,6 +550,8 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
                 t_start)
         await asyncio.sleep(max(0.0, t_end - time.monotonic()))
         done = await workers.lines(timeout_s=ycsb.SDK_TIMEOUT_S * 3)
+        moved = treestate.window_delta(placed, treestate.places(pids()))
+        say("processes over the window:", json.dumps(moved))
         try:
             fault_records = await fault_task if fault_task is not None else []
         except Exception as exc:
@@ -522,7 +559,7 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         for rec in fault_records:
             say(f"fault {rec['do']} {rec['server_id']}: started {rec['started_s']:.3f}s into the "
                 f"window, took {rec['seconds']:.3f}s, timed {json.dumps(rec['timed'])}")
-        after = snapshot()
+        after = snapshot("after")
         for rec in fault_records:
             # a killed process took its counters with it: what it had counted
             # before the kill stays counted in the window's deltas
@@ -530,9 +567,21 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
                 after["replica_cpu"] += rec["before"]["process_cpu"] or 0.0
                 for key, value in rec["before"]["replica"]["counters"].items():
                     after["replicas"][key] += value
+        say("replicas over the window:", json.dumps(cl.pace_delta(before["pace"], after["pace"])))
         gen = workers.results()
         ops = [op for g in gen for op in g["ops"]]
         say(f"window closed: {sum(d['done'] for d in done)} operations recorded")
+        gained, callers, marks = collections.Counter(), collections.Counter(), collections.defaultdict(list)
+        for g in gen:
+            sdk = g.get("sdk_counters", {})  # a worker of a control may keep none
+            gained.update(sdk.get("sum", {}))
+            callers.update(sdk.get("callers", {}))
+            for sid, per_caller in sdk.get("marks", {}).items():
+                marks[sid] += per_caller
+        say("SDK counters gained in the window [sum, callers moved]:",
+            json.dumps({k: [v, callers[k]] for k, v in sorted(gained.items())}))
+        say("marks of suspicion a caller gained against a replica, by replica, sorted:",
+            json.dumps({sid: sorted(v) for sid, v in sorted(marks.items())}))
         for g in gen:
             if g["errors"]:
                 say(f"generator {g['worker']} failed operations:", json.dumps(g["errors"]))
@@ -581,7 +630,7 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         bad = await probe.bad_write2_probe(pc, seed, touched_keys, BAD_WRITE2S)
         say(f"bad Write2 probe: {json.dumps(bad)}")
 
-        final = snapshot()
+        final = snapshot("final")
         errors = cl.log_errors(pc.log_paths())
         if errors:
             say("ERROR records in the children's logs:", json.dumps(errors))
@@ -637,6 +686,7 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
             if lat[kind]:
                 say(f"{name}: n={len(lat[kind])} p50={ref.percentile(lat[kind], 50):.3f}ms "
                     f"p95={ref.percentile(lat[kind], 95):.3f}ms p99={ref.percentile(lat[kind], 99):.3f}ms")
+        say("the window second by second:", json.dumps(ref.by_second(ops, t_start, seconds)))
         late = max(g["busy_until"] for g in gen) - t_end
         say(f"ops_s={summary['ops_s']:.3f} attempted={summary['attempted']} "
             f"failed={summary['failed']} drain_after_window={late:.3f}s setup_s={setup_s:.1f} "
@@ -664,6 +714,7 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
             e2e["update_p95_ms"] = pct(lat[ref.UPDATE], 95)
         if lat[ref.READ]:
             e2e["read_p95_ms"] = pct(lat[ref.READ], 95)
+            e2e["read_p50_ms"] = pct(lat[ref.READ], 50)
         bench = data["bench"]
         if not args.trace:
             result["metrics"] = {
@@ -727,6 +778,7 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         if rehearse:
             result["device"].pop("memory_peak_bytes")
             result["rehearsal"] = True
+        result["tree"] = treestate.result_object(facts, moved)
         # every number compared beside its limit: the result's last key, and
         # the last lines on standard error
         result["checks"] = {c.name: {"value": c.value, "limit": c.limit,
@@ -763,13 +815,16 @@ def main(argv=None, launcher=None, worker_script=None, faults_dir=None) -> int:
     try:
         gate(args.rehearse)
         data = load_cell(args.root, args.workload, faults_dir)
+        args.natives_before = treestate.native_modules(REPO)
         build_native()
-        result = asyncio.run(run_cell(
+        result = asyncio.run(within(run_cell(
             args, data,
             launcher or os.path.join(PERF, "service_launch.py"),
             worker_script or os.path.join(PERF, "ycsb.py"),
-        ))
+        ), RUN_LIMIT_S - (time.monotonic() - T_PROCESS_START)))
     except RunFailure as exc:
+        for line in SAID:  # how far the run had come
+            print("[perf] said:", line, file=sys.stderr)
         print(f"[perf] no result: {exc}", file=sys.stderr)
         return 3
     print(json.dumps(result), flush=True)

@@ -126,3 +126,15 @@ def test_summary_counts_all_work_of_the_window():
     assert s["ops_s"] == pytest.approx(0.2)
     assert sorted(s["latency_ms"][ref.UPDATE]) == pytest.approx([500.0, 500.0])
     assert s["latency_ms"][ref.READ][1] == math.inf
+
+
+def test_the_window_second_by_second_counts_what_was_answered_and_takes_the_reads_median():
+    # [kind, record, t_issue, t_done, ok, ...]: a read and an update in the first
+    # second, the update answered in the second one; a read that failed, and one
+    # issued in the last second and answered after the window
+    ops = [[ref.READ, 1, 10.2, 10.21, 1, 0, 0, 0, 3], [ref.UPDATE, 1, 10.5, 11.4, 1, 0, 0, 0, 0],
+           [ref.READ, 2, 11.95, 11.96, 0, -1, -1, 0, 0], [ref.READ, 2, 12.4, 12.6, 1, 0, 0, 0, 3]]
+    got = ref.by_second(ops, 10.0, 2.5)
+    assert got["answered"] == [1, 1, 0]
+    assert got["read_p50_ms"][0] == pytest.approx(10.0) and got["read_p50_ms"][1] is None
+    assert got["read_p50_ms"][2] == pytest.approx(200.0)

@@ -86,7 +86,10 @@ def test_the_cell_reports_ops_and_setup_end_to_end_and_is_on_no_list_that_was_th
                     "chips": 1, "why": cell["why"]} and len(cell["why"]) <= 200
     assert [m["name"] for m in bench["end_to_end"] if run.metric_applies(m, CELL)] == ["ops_s", "setup_s"]
     keyed = [m for m in bench["per_layer"] if CELL in m.get("workloads", ())]
-    assert [m["name"] for m in keyed] == READERS == [m["name"] for m in bench["per_layer"][-5:]]
+    # in the order PR 33 appended them (later PRs append their own after them)
+    assert [m["name"] for m in keyed] == READERS
+    first = [m["name"] for m in bench["per_layer"]].index(READERS[0])
+    assert [m["name"] for m in bench["per_layer"][first:first + len(READERS)]] == READERS
     assert all(m["workloads"] == [CELL] and m["moves"] == "ops_s" for m in keyed)
     # the readers that apply unkeyed: the eleven that move ops_s, as in rf4-50k-recover
     unkeyed = [m["name"] for m in bench["per_layer"] if "workloads" not in m and m["moves"] == "ops_s"]

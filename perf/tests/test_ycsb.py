@@ -117,3 +117,32 @@ def test_a_failed_attempt_is_made_again_and_the_last_failure_is_raised(monkeypat
     with pytest.raises(TimeoutError):
         asyncio.run(ycsb.with_retries(down, random.Random(1), retried))
     assert len(calls) == ycsb.OP_ATTEMPTS and sum(retried.values()) == ycsb.OP_ATTEMPTS - 1
+
+
+def test_the_sdk_counters_are_taken_over_the_window_by_caller_and_marks_by_replica():
+    class Timer:
+        def __init__(self, n):
+            self.total_count = n
+
+    class Metrics:
+        def __init__(self, counters, timers):
+            self.counters, self.timers = counters, {k: Timer(v) for k, v in timers.items()}
+
+    class Client:
+        def __init__(self, counters, timers):
+            self.metrics = Metrics(counters, timers)
+
+    clients = [Client({"suspect.no-response.server-1": 1, "client.checkpoints": 5}, {"read-transactions": 10}),
+               Client({"client.checkpoints": 7}, {"read-transactions": 20})]
+    before = ycsb._counters(clients)
+    clients[0].metrics.counters.update({"suspect.no-response.server-1": 3, "fanout.straggler-timeout.server-1": 2,
+                                        "suspect.tally-outvoted.server-0": 1, "client.checkpoints": 6})
+    clients[0].metrics.timers["read-transactions"].total_count = 14
+    clients[1].metrics.counters["suspect.grant-conflict.server-1"] = 4
+    got = ycsb._counter_deltas(clients, before)
+    assert got["sum"] == {"suspect.no-response.server-1": 2, "fanout.straggler-timeout.server-1": 2,
+                          "suspect.tally-outvoted.server-0": 1, "client.checkpoints": 1,
+                          "calls.read-transactions": 4, "suspect.grant-conflict.server-1": 4}
+    assert got["callers"]["client.checkpoints"] == 1 and got["callers"]["calls.read-transactions"] == 1
+    # what a caller's routing score of a replica adds up, caller by caller
+    assert {k: sorted(v) for k, v in got["marks"].items()} == {"server-1": [4, 4], "server-0": [1]}

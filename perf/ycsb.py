@@ -216,6 +216,43 @@ def _timer_samples(clients, names, before: dict) -> dict:
 STAGE_TIMERS = ("write1-phase", "write2-fanout-wait")
 
 
+# what the SDK's routing score of a replica adds up (client.py ``_suspicion_score``)
+SUSPICION_COUNTERS = ("suspect.", "fanout.straggler-timeout.")
+
+
+def _counters(clients) -> list:
+    """Each caller's SDK counters, and beside them how often each of its timers
+    ran (``calls.<timer>``: a read that fell back to the full fan-out ran
+    ``read-transactions`` twice)."""
+    return [dict(c.metrics.counters, **{f"calls.{n}": t.total_count for n, t in c.metrics.timers.items()})
+            for c in clients]
+
+
+def _counter_deltas(clients, before: list) -> dict:
+    """What the SDK's counters gained over the window: summed over this
+    worker's callers (``sum``), and for each counter how many callers it moved
+    in (``callers``).  A caller keeps its own view of every replica (marks of
+    suspicion, failed handshakes, straggler time-outs), and that view routes
+    its reads for a minute (``marks``: caller by caller, what that score adds
+    up against each replica)."""
+    total: dict = {}
+    moved: dict = {}
+    marks: dict = {}  # replica -> each caller's marks of suspicion against it, where it has any
+    for now, c0 in zip(_counters(clients), before):
+        mine: dict = {}
+        for name, value in now.items():
+            gained = value - c0.get(name, 0)
+            if gained:
+                total[name] = total.get(name, 0) + gained
+                moved[name] = moved.get(name, 0) + 1
+                if name.startswith(SUSPICION_COUNTERS):
+                    sid = name.rsplit(".", 1)[1]
+                    mine[sid] = mine.get(sid, 0) + gained
+        for sid, n in mine.items():
+            marks.setdefault(sid, []).append(n)
+    return {"sum": total, "callers": moved, "marks": marks}
+
+
 def _count(errors: dict, exc: Exception) -> None:
     what = f"{type(exc).__name__}: {exc}"[:120]
     errors[what] = errors.get(what, 0) + 1
@@ -294,6 +331,7 @@ async def worker(spec: dict) -> None:
     errors: dict = {}  # what the failed operations raised, by type and message
     retried: dict = {}  # what the attempts raised that were made again
     timers0 = _timer_counts(clients, STAGE_TIMERS)
+    counters0 = _counters(clients)
 
     async def caller_loop(client, cid):
         stream = OpStream(mix, records, random.Random(f"ops:{seed}:{cid}"))
@@ -341,6 +379,7 @@ async def worker(spec: dict) -> None:
         "cpu_seconds": cpu1 - cpu0,
         "busy_until": time.monotonic(),
         "stage_seconds": _timer_samples(clients, STAGE_TIMERS, timers0),
+        "sdk_counters": _counter_deltas(clients, counters0),
         "jax_loaded": "jax" in sys.modules,
     }
     _write_json(spec["result_path"], result)
