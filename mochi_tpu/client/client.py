@@ -44,6 +44,7 @@ from ..protocol import (
     MultiGrant,
     NudgeSyncToServer,
     Operation,
+    OperationResult,
     Action,
     ReadFromServer,
     ReadToServer,
@@ -61,6 +62,7 @@ from ..protocol import (
     Write2AnsFromServer,
     Write2ToServer,
     WriteCertificate,
+    certificates_deferred,
     transaction_hash,
 )
 from ..obs import trace as obs_trace
@@ -102,6 +104,7 @@ SUSPECT_KINDS = (
     "bad-grant",        # grant failed signature/hash/configstamp validation
     "grant-conflict",   # grant dropped from the timestamp-consistent subset
     "tally-outvoted",   # answer disagreed with the 2f+1 winning fingerprint
+    "bad-certificate",  # agreeing answer whose certificate tree does not build
 )
 
 # A peer becomes a read-routing suspect past this score: a couple of
@@ -687,6 +690,7 @@ class MochiDBClient:
         )
         out: Dict[str, object] = {}
         stale_sessions = []
+        received = 0  # certificates these replies carry, none built yet
         for sid, res in results.items():
             if isinstance(res, Exception):
                 LOG.debug("no response from %s: %s", sid, res)
@@ -719,6 +723,9 @@ class MochiDBClient:
                 stale_sessions.append(sid)
                 continue
             out[sid] = payload
+            received += certificates_deferred(payload)
+        if received:
+            self.metrics.mark("client.certificates-received", received)
         if stale_sessions and _retry:
             for sid in stale_sessions:
                 self._sessions.pop(sid, None)
@@ -881,6 +888,7 @@ class MochiDBClient:
             n_ops = len(transaction.operations)
             final: List = []
             outvoted: set = set()
+            malformed: set = set()
             for i in range(n_ops):
                 # Coalesce per-op results, ignoring WRONG_SHARD fillers
                 # (ref: MochiDBClient.java:148-175).  Only servers in the
@@ -888,7 +896,7 @@ class MochiDBClient:
                 # 3f+1) holds per set, so out-of-set responders — reached via
                 # the multi-key fan-out union — must not tip the tally.
                 rset = set(self.config.replica_set_for_key(transaction.operations[i].key))
-                tallies: Dict[bytes, Tuple[int, object]] = {}
+                tallies: Dict[tuple, List[Tuple[str, OperationResult]]] = {}
                 votes: Dict[str, tuple] = {}
                 for sid, p in reads.items():
                     if sid not in rset or i >= len(p.result.operations):
@@ -898,13 +906,12 @@ class MochiDBClient:
                         continue
                     fp = (bytes(op_res.value or b""), op_res.existed)
                     votes[sid] = fp
-                    count, _ = tallies.get(fp, (0, None))
-                    tallies[fp] = (count + 1, op_res)
-                best = max(tallies.values(), key=lambda t: t[0], default=(0, None))
-                if best[0] < self.config.quorum:
-                    responders = sum(t[0] for t in tallies.values())
+                    tallies.setdefault(fp, []).append((sid, op_res))
+                best = max(tallies.values(), key=len, default=[])
+                responders = sum(len(t) for t in tallies.values())
+                if len(best) < self.config.quorum:
                     raise InconsistentRead(
-                        f"op {i}: best agreement {best[0]} < quorum "
+                        f"op {i}: best agreement {len(best)} < quorum "
                         f"{self.config.quorum} ({responders} responders)",
                         responders=responders,
                     )
@@ -914,10 +921,46 @@ class MochiDBClient:
                 outvoted.update(
                     sid for sid, fp in votes.items() if fp != winning_fp
                 )
-                final.append(best[1])
-            for sid in outvoted:
-                self._suspect(sid, "tally-outvoted")
+                chosen = self._first_built(best, malformed)
+                if chosen is None:
+                    self._mark_tally_suspects(outvoted, malformed)
+                    raise InconsistentRead(
+                        f"op {i}: no agreeing answer's certificate builds "
+                        f"({responders} responders)",
+                        responders=responders,
+                    )
+                final.append(chosen)
+            self._mark_tally_suspects(outvoted, malformed)
             return TransactionResult(tuple(final))
+
+    def _first_built(
+        self, agreeing: List[Tuple[str, OperationResult]], malformed: set
+    ) -> Optional[OperationResult]:
+        """The answer a tally returns for one operation: the first of the
+        agreeing ones whose certificate BUILDS (``messages._Deferred``: a
+        reply's certificate is the codec's tree until somebody reads it, and
+        this is the one read a transaction makes), built here so that no
+        caller meets a decode error on attribute access.  The vote was on
+        the value alone and stays so; an agreeing answer with a certificate
+        tree that does not build is never the one returned and its sender
+        lands in ``malformed``.  None when no agreeing answer builds."""
+        for sid, op_res in agreeing:
+            pending = certificates_deferred(op_res)
+            try:
+                op_res.current_certificate
+            except ValueError:
+                malformed.add(sid)
+                continue
+            if pending:
+                self.metrics.mark("client.certificates-built")
+            return op_res
+        return None
+
+    def _mark_tally_suspects(self, outvoted: set, malformed: set) -> None:
+        for sid in outvoted:
+            self._suspect(sid, "tally-outvoted")
+        for sid in malformed:
+            self._suspect(sid, "bad-certificate")
 
     # -------------------------------------------------------- reconfiguration
 
@@ -1506,11 +1549,12 @@ class MochiDBClient:
         n_ops = len(transaction.operations)
         final: List = []
         outvoted: set = set()
+        malformed: set = set()
         for i in range(n_ops):
             # Per-op votes restricted to the key's replica set (same
             # out-of-set exclusion as the read path).
             rset = set(self.config.replica_set_for_key(transaction.operations[i].key))
-            tallies: Dict[Tuple, Tuple[int, object]] = {}
+            tallies: Dict[Tuple, List[Tuple[str, OperationResult]]] = {}
             votes: Dict[str, Tuple] = {}
             for sid, p in responses.items():
                 if sid not in rset or not isinstance(p, Write2AnsFromServer):
@@ -1522,15 +1566,14 @@ class MochiDBClient:
                     continue
                 fp = (bytes(op_res.value or b""), op_res.status)
                 votes[sid] = fp
-                count, _ = tallies.get(fp, (0, None))
-                tallies[fp] = (count + 1, op_res)
-            best = max(tallies.values(), key=lambda t: t[0], default=(0, None))
-            if best[0] < self.config.quorum:
+                tallies.setdefault(fp, []).append((sid, op_res))
+            best = max(tallies.values(), key=len, default=[])
+            if len(best) < self.config.quorum:
                 # ref: per-op 2f+1 tally (MochiDBClient.java:355-382).
                 # Flag certificate rejections: those are retryable with
                 # fresh grants (see execute_write_transaction).
                 raise InconsistentWrite(
-                    f"op {i}: best agreement {best[0]} < quorum {self.config.quorum}",
+                    f"op {i}: best agreement {len(best)} < quorum {self.config.quorum}",
                     bad_certificate=any(
                         isinstance(p, RequestFailedFromServer)
                         and p.fail_type == FailType.BAD_CERTIFICATE
@@ -1539,7 +1582,14 @@ class MochiDBClient:
                 )
             winning_fp = next(fp for fp, t in tallies.items() if t is best)
             outvoted.update(sid for sid, fp in votes.items() if fp != winning_fp)
-            final.append(best[1])
-        for sid in outvoted:
-            self._suspect(sid, "tally-outvoted")
+            chosen = self._first_built(best, malformed)
+            if chosen is None:
+                # only a stale Write2's answer echoes a certificate (the
+                # replica's current one), and every agreeing one came malformed
+                self._mark_tally_suspects(outvoted, malformed)
+                raise InconsistentWrite(
+                    f"op {i}: no agreeing answer's certificate builds"
+                )
+            final.append(chosen)
+        self._mark_tally_suspects(outvoted, malformed)
         return TransactionResult(tuple(final))

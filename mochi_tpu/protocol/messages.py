@@ -237,9 +237,58 @@ class WriteCertificate:
         return cls({sid: MultiGrant.from_obj(mg) for sid, mg in obj.items()})
 
 
+class _Deferred:
+    """A certificate field of a reply only a CLIENT decodes, built on first read.
+
+    The SDK's tallies vote on ``(value, existed)`` / ``(value, status)`` and
+    return ONE answer per operation; at n=64 a trimmed read brings 43 answers,
+    each with a 43-grant certificate, and building all of them into
+    ``WriteCertificate`` / ``MultiGrant`` / ``Grant`` objects was ~80% of a
+    reply's decode, 42 of 43 thrown away unread.  ``from_obj`` therefore keeps
+    the codec's tree under ``raw`` and this (non-data) descriptor builds the
+    field from it on first attribute access, once: the built value lands in
+    the instance ``__dict__`` under the field's own name, which is where the
+    constructor (the replica's side) puts it to begin with, so a constructed
+    instance never comes through here and no caller sees another type.
+    ``to_obj``, ``==`` and ``repr`` read the attribute and so build it.
+
+    A tree that does not build raises ``ValueError`` on EVERY read (the same
+    taxonomy as a failed decode); the SDK builds the certificate of the
+    answer it returns before handing it out (``client.py`` ``_first_built``),
+    so its callers never meet that on attribute access.  Requests, ``SyncEntry``
+    and ``Write2ToServer`` stay eager: a replica verifies what it receives.
+    """
+
+    def __init__(self, name: str, build) -> None:
+        self.name = name
+        self.raw = "_raw_" + name
+        self.build = build
+
+    def __get__(self, obj: Any, owner: Optional[type] = None) -> Any:
+        if obj is None:
+            return self
+        d = obj.__dict__
+        try:
+            value = self.build(d[self.raw])
+        except (ValueError, TypeError, AttributeError, KeyError) as exc:
+            raise ValueError(f"malformed {self.name}: {exc!r}") from None
+        d[self.name] = value
+        del d[self.raw]
+        return value
+
+
+def _build_certificates(trees: Any) -> "Mapping":
+    return MappingProxyType(
+        {k: WriteCertificate.from_obj(c) for k, c in trees.items()}
+    )
+
+
 @dataclass(frozen=True)
 class OperationResult:
-    """Per-operation outcome (ref: ``MochiProtocol.proto:45-56``)."""
+    """Per-operation outcome (ref: ``MochiProtocol.proto:45-56``).
+
+    Decoded instances build ``current_certificate`` on first read
+    (:class:`_Deferred`)."""
 
     value: Optional[bytes] = None
     current_certificate: Optional[WriteCertificate] = None
@@ -255,11 +304,17 @@ class OperationResult:
         value, cc, existed, st = obj
         res = object.__new__(cls)
         res.__dict__.update(
-            value=value,
-            current_certificate=WriteCertificate.from_obj(cc) if cc is not None else None,
-            existed=existed, status=_enum(_STATUSES, st, Status),
+            value=value, existed=existed, status=_enum(_STATUSES, st, Status),
         )
+        if cc is None:
+            res.__dict__["current_certificate"] = None
+        else:
+            res.__dict__[_CERTIFICATE.raw] = cc
         return res
+
+
+_CERTIFICATE = _Deferred("current_certificate", WriteCertificate.from_obj)
+OperationResult.current_certificate = _CERTIFICATE
 
 
 @dataclass(frozen=True)
@@ -352,7 +407,11 @@ class Write1OkFromServer:
     @classmethod
     def from_obj(cls, obj: Any) -> "Write1OkFromServer":
         mg, ccs = obj
-        return cls(MultiGrant.from_obj(mg), {k: WriteCertificate.from_obj(c) for k, c in ccs.items()})
+        ok = object.__new__(cls)
+        ok.__dict__.update(
+            {"multi_grant": MultiGrant.from_obj(mg), _CERTIFICATES.raw: ccs}
+        )
+        return ok
 
 
 @dataclass(frozen=True)
@@ -379,11 +438,17 @@ class Write1RefusedFromServer:
     @classmethod
     def from_obj(cls, obj: Any) -> "Write1RefusedFromServer":
         mg, ccs, cid = obj
-        return cls(
-            MultiGrant.from_obj(mg),
-            {k: WriteCertificate.from_obj(c) for k, c in ccs.items()},
-            cid,
+        refused = object.__new__(cls)
+        refused.__dict__.update(
+            {"multi_grant": MultiGrant.from_obj(mg), "client_id": cid,
+             _CERTIFICATES.raw: ccs}
         )
+        return refused
+
+
+_CERTIFICATES = _Deferred("current_certificates", _build_certificates)
+Write1OkFromServer.current_certificates = _CERTIFICATES
+Write1RefusedFromServer.current_certificates = _CERTIFICATES
 
 
 @dataclass(frozen=True)
@@ -416,6 +481,19 @@ class Write2AnsFromServer:
     def from_obj(cls, obj: Any) -> "Write2AnsFromServer":
         res, rid = obj
         return cls(TransactionResult.from_obj(res), rid)
+
+
+def certificates_deferred(msg: Any) -> int:
+    """How many certificates of a decoded reply (or of one of its operation
+    results) are still the codec's tree: received, and read by nobody yet."""
+    if isinstance(msg, OperationResult):
+        return int(_CERTIFICATE.raw in msg.__dict__)
+    if isinstance(msg, (ReadFromServer, Write2AnsFromServer)):
+        return sum(_CERTIFICATE.raw in op.__dict__ for op in msg.result.operations)
+    if isinstance(msg, (Write1OkFromServer, Write1RefusedFromServer)):
+        trees = msg.__dict__.get(_CERTIFICATES.raw)
+        return len(trees) if isinstance(trees, dict) else 0
+    return 0
 
 
 @dataclass(frozen=True)

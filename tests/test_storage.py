@@ -157,7 +157,18 @@ def _freeze_storage(td: str, server_id: str) -> str:
     snapshot + truncate it)."""
     src = os.path.join(td, server_id)
     dst = src + ".crash"
-    shutil.copytree(src, dst)
+    # The replica is live: its group tick may snapshot and truncate the log
+    # under the copy.  A ".snap-*" file is an atomic write still in flight
+    # (renamed away any moment, read by no boot); a segment that vanished
+    # mid-copy means the image is of no one instant, so it is taken again.
+    for attempt in range(5):
+        try:
+            shutil.copytree(src, dst, ignore=shutil.ignore_patterns(".snap-*"))
+            break
+        except shutil.Error:
+            shutil.rmtree(dst)
+            if attempt == 4:
+                raise
     return dst
 
 

@@ -81,7 +81,18 @@ async def _flush_to_pages(replica) -> None:
 def _freeze_storage(td: str, server_id: str) -> str:
     src = os.path.join(td, server_id)
     dst = src + ".crash"
-    shutil.copytree(src, dst)
+    # The replica is live: its group tick may snapshot and truncate the log
+    # under the copy.  A ".snap-*" file is an atomic write still in flight
+    # (renamed away any moment, read by no boot); a segment that vanished
+    # mid-copy means the image is of no one instant, so it is taken again.
+    for attempt in range(5):
+        try:
+            shutil.copytree(src, dst, ignore=shutil.ignore_patterns(".snap-*"))
+            break
+        except shutil.Error:
+            shutil.rmtree(dst)
+            if attempt == 4:
+                raise
     return dst
 
 
@@ -314,17 +325,17 @@ def test_compaction_drops_superseded_and_reverifies():
         vc, client = await _populated(td, n=10)
         try:
             victim = vc.replica("server-1")
+            # No snapshot may arm the background compaction (debt ratio 0.5):
+            # the group tick takes snapshots of its own, and a compact() it
+            # starts inside this test's awaits leaves this one nothing to
+            # rewrite, or two compactions of the same victims.
+            victim.storage.compact_debt_ratio = float("inf")
             await _flush_to_pages(victim)
             for i in range(10):
                 await client.execute_write_transaction(
                     TransactionBuilder().write(f"pk{i}", b"w%d" % i).build()
                 )
             await _flush_to_pages(victim)
-            # That flush armed the background compaction (debt ratio 0.5);
-            # disarm it in the same loop turn, or the group tick may start
-            # its own compact() inside this one's awaits: two compactions of
-            # the same victims, the second's page adopted with no live entry.
-            victim.storage._compact_due = False
             st0 = victim.storage.stats()
             assert st0["pages"]["count"] >= 2, st0
             assert st0["compaction"]["debt"] > 0, st0
