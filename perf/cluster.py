@@ -39,12 +39,22 @@ class PerfCluster(ProcessCluster):
                        *rest, *self.service_argv]
         await ProcessCluster._spawn(sp, env)
 
-    def replica_statuses(self) -> list:
-        return [
-            http_json(ADMIN_BASE_PORT + sp.index * self.n_servers + j)
-            for sp in self.processes
-            for j in range(len(sp.server_ids))
-        ]
+    def replica_statuses(self, strategy_counters: bool = True) -> list:
+        """Every replica's ``/status`` and, with ``strategy_counters``, beside it
+        what its ``/metrics`` counts of a strategy of its own
+        (``byzantine_report``; a second request a replica, which builds every
+        timer's percentiles: ~1 s a look at 64 replicas)."""
+        out = []
+        for sp in self.processes:
+            for j in range(len(sp.server_ids)):
+                port = ADMIN_BASE_PORT + sp.index * self.n_servers + j
+                status = http_json(port)
+                if strategy_counters:
+                    counters = http_json(port, "/metrics").get("counters", {})
+                    status["strategy_counters"] = {
+                        k: v for k, v in counters.items() if k.startswith(STRATEGY_COUNTERS)}
+                out.append(status)
+        return out
 
     def replica_status(self, server_id: str, wait_s: float = 0.0):
         """One replica's ``/status``, None where it does not answer within
@@ -108,6 +118,29 @@ def service_counters(status: dict) -> dict:
     }
 
 
+# what a replica that runs a Byzantine strategy counts of it in its own
+# registry (``testing/byzantine.py`` ``ByzantineReplica``: responses it changed
+# and signed again, requests it swallowed); an honest replica has neither key
+STRATEGY_COUNTERS = "byzantine."
+
+
+def byzantine_report(status: dict) -> dict:
+    """A replica's ``/status`` ``byzantine`` section (the evidence it holds
+    against its peers: ``equivocations`` and ``bad_grants`` by peer,
+    ``resync_bad_certificates``) and, beside it, what it says of ITSELF:
+    ``strategy`` (None: it runs none), ``mutated_responses``,
+    ``dropped_requests``.  Where the section names the strategy, that stands;
+    today's does not, and a replica is taken to run one (``True``: unnamed)
+    where its ``/metrics`` has a strategy's counters."""
+    report = dict(status.get("byzantine") or {})
+    if "strategy" not in report:
+        acts = status.get("strategy_counters") or {}
+        report["strategy"] = True if acts else None
+        report["mutated_responses"] = acts.get(STRATEGY_COUNTERS + "mutated-responses", 0)
+        report["dropped_requests"] = acts.get(STRATEGY_COUNTERS + "dropped-requests", 0)
+    return report
+
+
 def replica_counters(statuses: list) -> dict:
     """Sums over the replicas of what the checks and metrics read."""
     drain = [r["batching"].get("transport.drain-frames", {}) for r in statuses]
@@ -125,6 +158,7 @@ def replica_counters(statuses: list) -> dict:
         # per replica: what its last boot replayed, and its own verifier chain
         "replay": {r["server_id"]: r["storage"].get("replay") for r in statuses},
         "verifier_chains": {r["server_id"]: r["verifier"] for r in statuses},
+        "byzantine": {r["server_id"]: byzantine_report(r) for r in statuses},
     }
 
 

@@ -19,7 +19,11 @@ configuration states:
   update or one concurrent with it (same rule, the read issued after the
   window), again with at least ``quorum`` grants;
 * every bad Write2 of the probe was refused by every replica it was sent to
-  and left its key's record unchanged.
+  and left its key's record unchanged;
+* the replicas that run a Byzantine strategy are the ones the configuration
+  states, and where it states any: each lied inside the window, the callers
+  caught each, and no honest replica was accused by evidence that only a lie
+  produces, while every rule above held.
 
 Each number it compares is returned beside its limit; an exact comparison has
 the limit 0.
@@ -220,7 +224,9 @@ def check_deployment(config: dict, replicas: dict) -> list:
     """The deployment the configuration STATES against what the replicas
     report they run: ``replicas`` has the sorted distinct values over all
     replicas of the storage engine, the fsync policy and admission control.
-    Each number is how many distinct values differ from the stated one."""
+    Each number is how many distinct values differ from the stated one; the
+    last, how many replicas run another strategy than the stated one (none,
+    where the configuration states no ``byzantine`` map)."""
     want = {
         "storage_engines": ENGINE_REPORTED.get(config["storage_engine"], config["storage_engine"]),
         "fsync_policies": config["wal_fsync"],
@@ -229,6 +235,102 @@ def check_deployment(config: dict, replicas: dict) -> list:
     return [
         Check(f"replicas_reporting_other_{key}", sum(1 for v in replicas[key] if v != stated), 0)
         for key, stated in want.items()
+    ] + [Check("replicas_whose_strategy_differs_from_what_the_configuration_states",
+               strategy_differs(config.get("byzantine") or {}, replicas.get("byzantine") or {}), 0)]
+
+
+def strategy_differs(stated: dict, reported: dict) -> int:
+    """``stated``: {server id: strategy}, the configuration's ``byzantine`` map
+    (absent: every member is honest).  ``reported``: {server id: what that
+    replica says of itself}, with ``strategy`` (None where it runs none, True
+    where it runs one and does not name it) among its keys.  A stated member
+    that runs no strategy, a replica that runs one and is not stated, and a
+    named strategy that is not the stated one each count; a replica that says
+    nothing of itself runs none."""
+    differ = 0
+    for sid in set(stated) | set(reported):
+        runs = (reported.get(sid) or {}).get("strategy")
+        want = stated.get(sid)
+        differ += (want is None) != (runs is None) or (isinstance(runs, str) and runs != want)
+    return differ
+
+
+# the marks a caller's SDK keeps against a replica (``suspect.<kind>.<sid>``)
+# that only a lie produces: a grant whose signature or transaction hash is
+# wrong, an agreeing answer whose certificate does not build.  An honest
+# replica earns ``grant-conflict`` and ``tally-outvoted`` under contention on
+# the zipfian's head (7-25 of each a replica a window with five honest
+# members), ``no-response`` and the straggler time-outs by being slow.
+LIE_KINDS = ("bad-grant", "bad-certificate")
+# the kinds by which callers catch each strategy's lies; a strategy that is
+# not here is caught by a mark of any kind
+CAUGHT_AS = {"forge-cert": ("bad-grant",)}
+
+
+def acts_gained(replicas_before: dict, replicas_after: dict, sid: str):
+    """How often replica ``sid``'s own strategy acted between two looks
+    (``cluster.replica_counters`` at either end), by its own count: responses
+    it changed plus requests it swallowed.  None where it reports no strategy
+    at the second look; a replica that was started again in between (its
+    counters began anew) reports what it counted since."""
+    def acts(replicas):
+        own = (replicas.get("byzantine") or {}).get(sid) or {}
+        if own.get("strategy") is None:
+            return None
+        return int(own.get("mutated_responses", 0)) + int(own.get("dropped_requests", 0))
+
+    a0, a1 = acts(replicas_before) or 0, acts(replicas_after)
+    if a1 is None:
+        return None
+    return a1 - a0 if a1 >= a0 else a1
+
+
+def _proofs(replicas: dict) -> dict:
+    """{accused server id: count}: what the replicas hold against their peers
+    as PROOF, summed over the replicas that report it: ``equivocations``, two
+    validly signed grants of one slot to two transactions.  Their
+    ``bad_grants`` are left out: a grant whose signature does not verify is
+    counted against the signer it CLAIMS, whoever carried the certificate
+    (``replica.py``: "evidence about the CARRIER of the certificate, not proof
+    against sid"), so the probe's altered Write2 earns an honest signer one in
+    every cell, and a member's tampered sync answer earns the honest signers
+    of the entry one each."""
+    out: dict = {}
+    for own in (replicas.get("byzantine") or {}).values():
+        for sid, count in ((own or {}).get("equivocations") or {}).items():
+            out[sid] = out.get(sid, 0) + int(count)
+    return out
+
+
+def check_byzantine(stated: dict, replicas_before: dict, replicas_after: dict,
+                    sdk_gained: dict) -> list:
+    """A cell whose configuration states Byzantine members (``stated``: {server
+    id: strategy}).  Each member acted inside the window, by its own count
+    (``replicas_*``: ``cluster.replica_counters`` at either end); the callers
+    caught each one lying at least once (``sdk_gained``: what the SDK's counters
+    gained over the window, summed over the callers); and nothing that only a
+    lie of the accused produces (the callers' ``LIE_KINDS``, the replicas'
+    ``_proofs``) was laid at an honest replica's door.  Every other check
+    holds the guarantees, with the members answering throughout."""
+    idle = sum(1 for sid in stated if not acts_gained(replicas_before, replicas_after, sid))
+    marks: dict = {}  # (kind, server id) -> marks the callers gained
+    for name, n in sdk_gained.items():
+        if name.startswith("suspect."):
+            _, kind, sid = name.split(".", 2)
+            marks[kind, sid] = marks.get((kind, sid), 0) + n
+
+    def caught(member, strategy):  # marks of the kinds that catch this strategy's lies
+        return sum(n for (kind, sid), n in marks.items()
+                   if sid == member and kind in CAUGHT_AS.get(strategy, (kind,)))
+
+    accused = {sid for (kind, sid), n in marks.items() if n > 0 and kind in LIE_KINDS}
+    held0, held1 = _proofs(replicas_before), _proofs(replicas_after)
+    accused |= {sid for sid, n in held1.items() if n > held0.get(sid, 0)}
+    return [
+        Check("stated_members_that_never_acted_in_the_window", idle, 0),
+        Check("lies_the_callers_caught", min((caught(*m) for m in stated.items()), default=0), 1,
+              at_least=True),
+        Check("honest_replicas_accused_by_typed_evidence", len(accused - set(stated)), 0),
     ]
 
 

@@ -10,7 +10,10 @@ A cell is an entry of ``workloads`` in ``BENCHMARK.json``: a configuration
 ``perf/layer_metrics/``.  A traffic mix may state a fault schedule
 (``perf/schedule.py``), each of whose verbs is a file in ``perf/faults/``; it
 runs inside the window, and a mix without one takes the control flow it always
-took.  Nothing here names a cell, a mix, a metric or a verb.
+took.  A configuration may state Byzantine members (``byzantine``: server id ->
+strategy of ``mochi_tpu/testing/byzantine.py``): they boot so, count against
+``f`` in the schedule, and the reference holds the run to it
+(``check_byzantine``).  Nothing here names a cell, a mix, a metric or a verb.
 
 Processes: replicas are ``python -m mochi_tpu.server`` children, ONE verifier
 service owns the chip (started through ``perf/service_launch.py``, which calls
@@ -341,6 +344,44 @@ def trace_from_s(events: list, seconds: float, trace_len: float) -> float:
     return float(min(timed)) if timed else seconds - trace_len
 
 
+def stated_members(shape: dict) -> dict:
+    """The configuration's ``byzantine`` map, {server id: strategy} (empty:
+    every member is honest), refused before anything boots where it names a
+    server the shape lacks or a strategy the product's catalog lacks."""
+    from mochi_tpu.testing.byzantine import make_strategy
+
+    members = shape.get("byzantine") or {}
+    if not isinstance(members, dict):
+        raise RunFailure(f"'byzantine' is a map of server id to strategy, not {members!r}")
+    servers = {f"server-{i}" for i in range(shape["replicas"])}
+    for sid, strategy in members.items():
+        if sid not in servers:
+            raise RunFailure(f"'byzantine' names {sid!r}: the configuration has "
+                             f"server-0 to server-{shape['replicas'] - 1}")
+        try:
+            make_strategy(strategy)
+        except (ValueError, TypeError) as exc:
+            raise RunFailure(f"'byzantine' gives {sid} {strategy!r}: {exc}") from exc
+    return dict(members)
+
+
+def sdk_counters(gen: list) -> dict:
+    """The generators' ``sdk_counters`` (``ycsb._counter_deltas``) added up
+    over the worker processes: what each SDK counter gained over the window
+    (``sum``), in how many callers it moved (``callers``), and each caller's
+    marks of suspicion against each replica (``marks``)."""
+    total, callers = collections.Counter(), collections.Counter()
+    marks = collections.defaultdict(list)
+    for g in gen:
+        sdk = g.get("sdk_counters", {})  # a worker of a control may keep none
+        total.update(sdk.get("sum", {}))
+        callers.update(sdk.get("callers", {}))
+        for sid, per_caller in sdk.get("marks", {}).items():
+            marks[sid] += per_caller
+    return {"sum": dict(total), "callers": dict(callers),
+            "marks": {sid: sorted(v) for sid, v in sorted(marks.items())}}
+
+
 def replica_processes(config: dict) -> int:
     want = config["replica_processes"]
     if want == "cores-2":
@@ -348,10 +389,12 @@ def replica_processes(config: dict) -> int:
     return max(1, min(int(want), config["replicas"]))
 
 
-async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
+async def run_cell(args, data: dict, launcher: str, worker_script: str, boot=None) -> dict:
     """Everything between process start and the result.  ``launcher`` and
     ``worker_script`` are the service launcher and the generator worker; the
-    tests under ``perf/tests`` put broken ones in their place."""
+    tests under ``perf/tests`` put broken ones in their place, and with
+    ``boot`` a control starts the cluster otherwise than the configuration
+    states (keywords of ``PerfCluster`` that replace the stated ones)."""
     import cluster as cl
     import probe
     import reference as ref
@@ -366,6 +409,7 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
     threads = shape["threads"]
     gen_procs = min(shape["generator_processes"], threads)
     seed, seconds = args.seed, float(args.seconds)
+    members = stated_members(shape)
     events = []
     if "faults" in traffic:
         if rehearse:  # the tiny shape packs its replicas, and a kill takes a whole process
@@ -373,7 +417,7 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         procs = replica_processes(shape)
         try:
             events = schedule.bind(traffic["faults"], data["verbs"], seed, seconds, n, shape["f"],
-                                   {f"server-{i}": i % procs for i in range(n)})
+                                   {f"server-{i}": i % procs for i in range(n)}, members)
         except schedule.ScheduleError as exc:
             raise RunFailure(f"traffic {cell['traffic']!r}: {exc}") from exc
 
@@ -392,14 +436,17 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         f"generator_processes={gen_procs} transport={'uds' if uds else 'tcp'} "
         f"seed={seed} seconds={seconds} trace={args.trace} rehearse={rehearse}")
 
+    # what the configuration states, applied (and compared with what the
+    # replicas report, below); the shipped files state the product's defaults
+    stated = {"storage_engine": shape["storage_engine"], "wal_fsync": shape["wal_fsync"],
+              "admission": shape["admission"] == "on", "byzantine": members or None}
+    if boot:
+        say(f"CONTROL: the cluster boots with {boot}, whatever the configuration states")
     pc = cl.PerfCluster(
         n_servers=n, rf=rf, n_processes=replica_processes(shape),
         uds=uds, verifier="service", service_backend="tpu",
         admin_base_port=cl.ADMIN_BASE_PORT, storage_dir=os.path.join(out_dir, "storage"),
-        # what the configuration states, applied (and compared with what the
-        # replicas report, below); the shipped files state the product's defaults
-        storage_engine=shape["storage_engine"], wal_fsync=shape["wal_fsync"],
-        admission=shape["admission"] == "on", byzantine=shape.get("byzantine"),
+        **dict(stated, **(boot or {})),
         seed=seed, ready_timeout_s=READY_TIMEOUT_S,
         # every program the service builds is cached, however quick its
         # compile, so "the window added no cache entry" is exact
@@ -494,7 +541,9 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         def snapshot(name: str) -> dict:
             cpu = pc.cpu_seconds()
             status = pc.service_status()
-            statuses = pc.replica_statuses()
+            # whether a replica runs a strategy of its own is asked once, after
+            # everything; how often it acted, at the window's ends where one is stated
+            statuses = pc.replica_statuses(strategy_counters=bool(members) or name == "final")
             if args.keep:  # every replica's whole /status, as it stood
                 with open(os.path.join(out_dir, f"status-{name}.json"), "w") as fh:
                     json.dump({"service": status, "replicas": statuses}, fh)
@@ -571,17 +620,11 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         gen = workers.results()
         ops = [op for g in gen for op in g["ops"]]
         say(f"window closed: {sum(d['done'] for d in done)} operations recorded")
-        gained, callers, marks = collections.Counter(), collections.Counter(), collections.defaultdict(list)
-        for g in gen:
-            sdk = g.get("sdk_counters", {})  # a worker of a control may keep none
-            gained.update(sdk.get("sum", {}))
-            callers.update(sdk.get("callers", {}))
-            for sid, per_caller in sdk.get("marks", {}).items():
-                marks[sid] += per_caller
+        sdk = sdk_counters(gen)
         say("SDK counters gained in the window [sum, callers moved]:",
-            json.dumps({k: [v, callers[k]] for k, v in sorted(gained.items())}))
+            json.dumps({k: [v, sdk["callers"][k]] for k, v in sorted(sdk["sum"].items())}))
         say("marks of suspicion a caller gained against a replica, by replica, sorted:",
-            json.dumps({sid: sorted(v) for sid, v in sorted(marks.items())}))
+            json.dumps(sdk["marks"]))
         for g in gen:
             if g["errors"]:
                 say(f"generator {g['worker']} failed operations:", json.dumps(g["errors"]))
@@ -645,6 +688,8 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
         checks += ref.check_readback(readback, hist, quorum)
         checks += ref.check_probe(bad)
         checks += ref.check_deployment(shape, final["replicas"])
+        if members:
+            checks += ref.check_byzantine(members, before["replicas"], after["replicas"], sdk["sum"])
         if events:
             checks += ref.check_recovery(fault_records)
             direct_checks, direct_counts = ref.check_direct(
@@ -752,13 +797,15 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
                 "generator": {
                     "processes": len(gen),
                     "cpu_seconds": sum(g["cpu_seconds"] for g in gen),
+                    "sdk_counters": sdk,
                     "stage_seconds": {
                         name: [s for g in gen for s in g["stage_seconds"].get(name, [])]
                         for name in ycsb.STAGE_TIMERS
                     },
                 },
                 "trace": reduced, "host_spans": host_spans,
-                "cluster": {"replicas": n, "rf": rf, "f": shape["f"], "quorum": quorum},
+                "cluster": {"replicas": n, "rf": rf, "f": shape["f"], "quorum": quorum,
+                            "byzantine": members},
                 "faults": fault_records, "end_to_end": e2e,
             }
             result["metrics"] = read_layer_metrics(data["layer_dir"], bench, cell["name"], snap)
@@ -799,7 +846,7 @@ async def run_cell(args, data: dict, launcher: str, worker_script: str) -> dict:
     return result
 
 
-def main(argv=None, launcher=None, worker_script=None, faults_dir=None) -> int:
+def main(argv=None, launcher=None, worker_script=None, faults_dir=None, boot=None) -> int:
     parser = argparse.ArgumentParser(description=__doc__.split("\n\n")[0])
     parser.add_argument("--workload", required=True)
     parser.add_argument("--seed", type=int, required=True)
@@ -820,7 +867,7 @@ def main(argv=None, launcher=None, worker_script=None, faults_dir=None) -> int:
         result = asyncio.run(within(run_cell(
             args, data,
             launcher or os.path.join(PERF, "service_launch.py"),
-            worker_script or os.path.join(PERF, "ycsb.py"),
+            worker_script or os.path.join(PERF, "ycsb.py"), boot,
         ), RUN_LIMIT_S - (time.monotonic() - T_PROCESS_START)))
     except RunFailure as exc:
         for line in SAID:  # how far the run had come

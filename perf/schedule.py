@@ -88,10 +88,13 @@ def validate(faults, faults_dir: str) -> list:
 
 
 def bind(faults: list, verbs: list, seed: int, seconds: float, n: int, f: int,
-         process_of: dict) -> list:
+         process_of: dict, members=()) -> list:
     """The schedule of THIS run: each event with its replica's id, refused
     where the cell cannot carry it.  ``process_of``: server id -> the index
-    of the process that hosts it (a kill takes the whole process)."""
+    of the process that hosts it (a kill takes the whole process).
+    ``members``: the replicas the configuration states as Byzantine; one of
+    them is faulty whether it is up or down, so it counts against ``f``
+    beside the replicas that are down."""
     rng = random.Random(f"faults:{seed}")
     hosted = {}
     for sid, proc in process_of.items():
@@ -120,6 +123,12 @@ def bind(faults: list, verbs: list, seed: int, seconds: float, n: int, f: int,
             if len(dead) > f:
                 raise ScheduleError(f"fault {i}: {len(dead)} replicas down at once, the "
                                     f"configuration tolerates f={f}")
+            if len(dead | set(members)) > f:
+                raise ScheduleError(
+                    f"fault {i}: {sid} down beside the stated Byzantine "
+                    f"{sorted(set(members) - dead)}: {len(dead | set(members))} faulty replicas "
+                    f"at once, the configuration tolerates f={f} and no quorum is left to the "
+                    "sets that hold them")
         events.append(dict(ev, index=i, server_id=sid, verb=mod))
     return events
 

@@ -51,7 +51,8 @@ def test_a_window_without_reads_reports_nothing(cell):
 def test_the_read_median_is_keyed_to_the_two_cells_that_fall_into_levels():
     bench = json.load(open(os.path.join(REPO, "BENCHMARK.json")))
     entry = next(m for m in bench["per_layer"] if m["name"] == NAME)
-    assert entry["workloads"] == ["rf4-ycsb-a", "rf4-recover"]
+    # (PR 45 added its cell, the same cluster and mix with a Byzantine member)
+    assert entry["workloads"] == ["rf4-ycsb-a", "rf4-recover", "rf4-byz1-ycsb-a"]
     snap = dict(SNAP, latency=dict(SNAP["latency"], read_p50_ms=5.0))
     for cell in (w["name"] for w in bench["workloads"]):
         assert (NAME in read(cell, snap)) == (cell in entry["workloads"]), cell

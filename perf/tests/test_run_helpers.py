@@ -186,7 +186,7 @@ def test_a_run_past_its_limit_ends_itself_stops_what_it_started_and_says_how_far
 
 
 def test_a_run_that_fails_puts_its_last_commentary_on_standard_error(monkeypatch, capsys):
-    async def stuck(args, data, launcher, worker_script):
+    async def stuck(args, data, launcher, worker_script, boot):
         run.say("cluster READY in 1.0s")
         await asyncio.sleep(60)
 
@@ -204,5 +204,6 @@ def test_a_run_that_fails_puts_its_last_commentary_on_standard_error(monkeypatch
 # tier-1 collects this file through ``tests/test_perf_run_helpers.py``; PR 43's
 # tests (the read median's reader, the tree's facts) have no shim of their own
 # there (a benchmark PR adds no file outside ``perf/``) and ride along with it
+from test_byzantine_cell import *  # noqa: E402,F401,F403  (PR 45's likewise)
 from test_read_p50_reader import *  # noqa: E402,F401,F403
 from test_treestate import *  # noqa: E402,F401,F403
