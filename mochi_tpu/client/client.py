@@ -782,11 +782,13 @@ class MochiDBClient:
     ) -> TransactionResult:
         try:
             try:
+                self.metrics.mark("client.trimmed-reads")
                 return await self._read_once(transaction, trim=True, tt=tt)
             except InconsistentRead:
                 # The quorum-sized fan-out can miss when a chosen replica
                 # lags a fresh commit or times out — the full union is the
                 # authoritative attempt.
+                self.metrics.mark("client.trimmed-read-fallbacks")
                 return await self._read_once(transaction, trim=False, tt=tt)
         except InconsistentRead as failure:
             if transaction.keys == (CONFIG_CLUSTER_KEY,):
@@ -1138,6 +1140,32 @@ class MochiDBClient:
             self.metrics.mark("client.cert-audit-convictions", len(bad))
         return bad
 
+    def _count_grants(
+        self, carried: int, refused: int, valid: int,
+        subset: Optional[List[MultiGrant]],
+    ) -> None:
+        """What one Write1 round did with the MultiGrants its answers
+        brought (counters beside ``client.certificates-*``).  Every round:
+        ``client.grants-received`` = ``-voting`` (in the timestamp-consistent
+        subset the certificate is cut from) + ``-dropped-signature`` (an OK
+        grant that failed :meth:`_grant_ok`, or named another signer) +
+        ``-dropped-timestamp`` (valid, outside the subset) + ``-refused``
+        (a signed refusal) + ``-unused`` (valid grants of a round that found
+        no subset and went round again)."""
+        mark = self.metrics.mark
+        mark("client.grants-received", carried + refused)
+        if refused:
+            mark("client.grants-refused", refused)
+        if carried > valid:
+            mark("client.grants-dropped-signature", carried - valid)
+        if subset is None:
+            if valid:
+                mark("client.grants-unused", valid)
+            return
+        mark("client.grants-voting", len(subset))
+        if valid > len(subset):
+            mark("client.grants-dropped-timestamp", valid - len(subset))
+
     @staticmethod
     def _write1_transaction(transaction: Transaction) -> Transaction:
         """Value-less WRITE ops for every operation — grants are value-blind
@@ -1297,13 +1325,17 @@ class MochiDBClient:
                         arrived=w1_arrived,
                     )
                 oks: List[MultiGrant] = []
+                carried = refused = 0  # MultiGrants this round's answers brought
                 for sid, p in responses.items():
-                    if (
-                        isinstance(p, Write1OkFromServer)
-                        and p.multi_grant.server_id == sid
-                        and self._grant_ok(p.multi_grant, txn_hash)
-                    ):
-                        oks.append(p.multi_grant)
+                    if isinstance(p, Write1OkFromServer):
+                        carried += 1
+                        if (
+                            p.multi_grant.server_id == sid
+                            and self._grant_ok(p.multi_grant, txn_hash)
+                        ):
+                            oks.append(p.multi_grant)
+                    elif isinstance(p, Write1RefusedFromServer):
+                        refused += 1
                 # Proceed as soon as a timestamp-consistent 2f+1 subset
                 # exists; refusals/outliers from up to f servers (contention,
                 # lag, Byzantine skew) must not block an honest quorum.
@@ -1311,6 +1343,7 @@ class MochiDBClient:
                 # the assembler fired (authoritative; the assembler is a
                 # liveness signal — see client/txn.py).
                 chosen = self._quorum_grant_subset(transaction, oks)
+                self._count_grants(carried, refused, len(oks), chosen)
                 if chosen is not None:
                     # Suspicion accounting: a validated grant that still
                     # fell out of the timestamp-consistent subset voted a

@@ -2585,7 +2585,11 @@ class MochiReplica:
     def byzantine_stats(self) -> Dict[str, object]:
         """Per-peer misbehavior evidence for the admin surfaces (/status
         "byzantine", ``mochi_byzantine`` prom family): proven equivocations
-        plus bad-grant and resync-rejection attribution counters."""
+        plus bad-grant and resync-rejection attribution counters.  Beside
+        the evidence against its peers, what this replica says of ITSELF:
+        the fault-injection ``strategy`` it runs (None: none; only a
+        ``testing/byzantine.ByzantineReplica`` names one) and how often
+        that strategy acted (``mutated_responses``, ``dropped_requests``)."""
         prefix = "replica.bad-grant."
         bad_grants = {
             name[len(prefix):]: n
@@ -2597,6 +2601,13 @@ class MochiReplica:
             "bad_grants": bad_grants,
             "resync_bad_certificates": self.metrics.counters.get(
                 "replica.resync-bad-certificate", 0
+            ),
+            "strategy": None,
+            "mutated_responses": self.metrics.counters.get(
+                "byzantine.mutated-responses", 0
+            ),
+            "dropped_requests": self.metrics.counters.get(
+                "byzantine.dropped-requests", 0
             ),
         }
 

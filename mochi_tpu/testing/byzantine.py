@@ -485,6 +485,14 @@ class ByzantineReplica(MochiReplica):
             self._attack_task = None
         await super().close()
 
+    def byzantine_stats(self) -> Dict[str, object]:
+        """The honest section, naming the strategy this replica runs (an
+        honest passthrough names none)."""
+        stats = super().byzantine_stats()
+        if type(self.strategy) is not AttackStrategy:
+            stats["strategy"] = self.strategy.name
+        return stats
+
     # ---------------------------------------------------------- batch seams
 
     def _corrupt(self, env: Envelope, response: Optional[Envelope]) -> Optional[Envelope]:
